@@ -145,13 +145,9 @@ def run_parity_2d(device_counts: list[int], crypto: str) -> dict:
     and the psum'd per-message vote counts must equal the host tally of
     valid verdicts."""
     from smartbft_tpu.crypto.provider import JaxVerifyEngine
-    from smartbft_tpu.parallel import QuorumMeshVerifyEngine, shard_map_available
+    from smartbft_tpu.parallel import QuorumMeshVerifyEngine
 
     scheme = _scheme(crypto)
-    if not shard_map_available():
-        return {"metric": "mesh_parity_2d", "crypto": crypto,
-                "devices_checked": [], "items": 0, "match": None,
-                "counts_match": None, "note": "no shard_map in this build"}
     items, expect = _mixed_wave(scheme)
     base = JaxVerifyEngine(pad_sizes=(16, 64), scheme=scheme).verify(items)
     match = base == expect
@@ -198,6 +194,7 @@ def build_cluster(tmp, devices: int, args, scheme, hold: float):
         return dataclasses.replace(
             sharded_config(i, depth=args.pipeline),
             verify_mesh_devices=devices,
+            verify_mesh_topology=args.topology,
             verify_flush_hold=hold,
             wal_group_commit=True,
             request_batch_max_count=args.batch,
@@ -224,9 +221,12 @@ def build_cluster(tmp, devices: int, args, scheme, hold: float):
     )
 
 
-async def _run_cluster_point(devices: int, args, hold: float) -> dict:
+async def run_cluster_point(devices: int, args, hold: float,
+                            on_engine=None) -> dict:
     """One fixed-workload cluster run at ``devices`` width with the
-    given flush-hold knob; returns the raw measurement dict."""
+    given flush-hold knob; returns the raw measurement dict.
+    ``on_engine``: called with the graduated, prewarmed engine before any
+    request is submitted (chip_smoke.py checks its verdicts there)."""
     from smartbft_tpu.crypto.provider import (
         VerifyStats,
         prewarm_verify_engine,
@@ -243,10 +243,12 @@ async def _run_cluster_point(devices: int, args, hold: float) -> dict:
         await cluster.start()
         engine = cluster.coalescer.engine
         got_devices = int(getattr(engine, "devices", 0))
-        if got_devices != devices:
+        if got_devices != devices \
+                or getattr(engine, "topology", "1d") != args.topology:
             raise RuntimeError(
-                f"knob wiring failed: wanted a {devices}-device mesh, "
-                f"coalescer runs {type(engine).__name__} ({got_devices})"
+                f"knob wiring failed: wanted a {devices}-device "
+                f"{args.topology} mesh, coalescer runs "
+                f"{type(engine).__name__} ({got_devices})"
             )
         if abs(cluster.coalescer.hold - hold) > 1e-9:
             raise RuntimeError(
@@ -262,6 +264,8 @@ async def _run_cluster_point(devices: int, args, hold: float) -> dict:
         for _ in range(3):
             engine.verify([item])
         launch_probe_ms = 1e3 * (time.perf_counter() - t0) / 3
+        if on_engine is not None:
+            on_engine(engine)
         engine.stats = type(engine.stats)(
             devices=got_devices, metrics=engine.stats.metrics
         ) if hasattr(engine.stats, "devices") else VerifyStats()
@@ -325,9 +329,9 @@ async def run_sweep_point(devices: int, args) -> dict:
     as ``*_ungated`` so fill/launch deltas are in every row.  With
     ``--hold 0`` the two runs would be identical, so the control is
     reused instead of paying a second cluster for a no-op comparison."""
-    control = await _run_cluster_point(devices, args, 0.0)
+    control = await run_cluster_point(devices, args, 0.0)
     gated = control if args.hold <= 0 \
-        else await _run_cluster_point(devices, args, args.hold)
+        else await run_cluster_point(devices, args, args.hold)
     mesh_block = gated["mesh"]
     return {
         "bench": "mesh",
@@ -374,6 +378,9 @@ def main() -> None:
                     help="decisions committed per shard per point")
     ap.add_argument("--pipeline", type=int, default=8)
     ap.add_argument("--crypto", choices=("toy", "p256"), default="toy")
+    ap.add_argument("--topology", choices=("1d", "2d"), default="1d",
+                    help="the mesh shape the sweep's clusters graduate "
+                         "onto (Configuration.verify_mesh_topology)")
     ap.add_argument("--per-device-lanes", default="4,8,12,16",
                     help="pad-ladder lanes contributed by EACH device — "
                          "per-launch capacity = lanes x devices (a denser "
@@ -396,8 +403,8 @@ def main() -> None:
         force_cpu(virtual_devices=max(sweep))
     else:
         # device rigs: persist compiled mesh shapes across bench
-        # subprocesses (SMARTBFT_JAX_CACHE_DIR overrides the location) —
-        # the 2-3 min per-process compile tax must not poison every row
+        # subprocesses — the 2-3 min per-process compile tax must not
+        # poison every row
         from smartbft_tpu.utils.jaxenv import enable_compile_cache
 
         enable_compile_cache()

@@ -255,9 +255,7 @@ class Configuration:
     #   "2d" graduates onto the seq x vote QuorumMeshVerifyEngine, whose
     #   per-sequence quorum counts psum across the 'vote' mesh axis —
     #   quorum counting itself rides the device collective — while
-    #   per-item verdicts stay bit-identical to the 1D engine.  A build
-    #   with no usable shard_map downgrades loudly like an unbuildable
-    #   mesh.
+    #   per-item verdicts stay bit-identical to the 1D engine.
     # - verify_flush_hold: occupancy-aware flush gating (wall-clock
     #   seconds; 0 disables).  A coalescer flush whose wave sits below a
     #   pad-ladder rung may HOLD up to this hard deadline while per-tag

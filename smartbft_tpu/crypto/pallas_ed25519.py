@@ -44,6 +44,7 @@ from .pallas_comb import (
     STRIDE,
     TEETH,
     TSIZE,
+    VMEM_LIMIT_BYTES,
     CombKeyRegistry,
     CombVerifier,
     _comb_digits,
@@ -271,6 +272,8 @@ def eddsa_verify_comb(s, h, rx, ry, ok, kidx, btab, qtab, tile: int = 128,
         out_specs=pl.BlockSpec((1, tile), lambda i: (0, i)),
         scratch_shapes=[pltpu.VMEM((2 * STRIDE, tile), jnp.int32)],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(*args, ok, kidx, btab, qtab)
     return out[0, :bsz]
 

@@ -1,22 +1,24 @@
 """Native (C++) runtime helpers, loaded via ctypes with Python fallbacks.
 
 The reference is pure Go; the TPU-native rebuild keeps its runtime plane
-(WAL framing, hashing) native where throughput demands it.  Libraries are
-compiled on first import with ``g++`` into this directory and cached; any
-build failure falls back to the pure-Python implementations so the framework
-never hard-depends on a toolchain at runtime.
+(WAL framing, hashing) native where throughput demands it.  The library is
+compiled on first use with ``g++`` from the ``.cc`` files beside this module,
+into this directory, under a name that carries the hash of those sources;
+any build failure falls back to the pure-Python implementations so the
+framework never hard-depends on a toolchain at runtime.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
 from typing import Optional
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
-_LIB_NAME = "libsmartbft_native.so"
 _SOURCES = ["crc32c.cc", "wal_frame.cc", "bls381.cc", "ed25519_fp.cc"]
 
 _lock = threading.Lock()
@@ -24,36 +26,42 @@ _lib: Optional[ctypes.CDLL] = None
 _load_attempted = False
 
 
+def _lib_path() -> Optional[str]:
+    """Where the library built from THESE sources lives: the name carries a
+    hash of their contents, so a library is never stale by construction —
+    a checkout, a copy or an edit that changes a source changes the name,
+    whatever the files' mtimes say.  None when a source is missing."""
+    h = hashlib.sha256()
+    try:
+        for s in _SOURCES:
+            with open(os.path.join(_DIR, s), "rb") as f:
+                h.update(f.read())
+    except OSError:
+        return None
+    return os.path.join(_DIR, f"libsmartbft_native.{h.hexdigest()[:16]}.so")
+
+
 def _build_lib(lib_path: str) -> bool:
     srcs = [os.path.join(_DIR, s) for s in _SOURCES]
-    if not all(os.path.exists(s) for s in srcs):
-        return False
     tmp = lib_path + f".tmp.{os.getpid()}"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-o", tmp, *srcs]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, lib_path)
-        return True
     except Exception:
         try:
             os.unlink(tmp)
         except OSError:
             pass
         return False
-
-
-def _stale(lib_path: str) -> bool:
-    try:
-        lib_mtime = os.path.getmtime(lib_path)
-    except OSError:
-        return True
-    for s in _SOURCES:
-        try:
-            if os.path.getmtime(os.path.join(_DIR, s)) > lib_mtime:
-                return True
-        except OSError:
-            pass  # source pruned from the deploy — the built lib stands
-    return False
+    # libraries of earlier source contents are dead weight now
+    for old in glob.glob(os.path.join(_DIR, "libsmartbft_native*.so")):
+        if old != lib_path:
+            try:
+                os.unlink(old)
+            except OSError:
+                pass
+    return True
 
 
 def load() -> Optional[ctypes.CDLL]:
@@ -67,8 +75,10 @@ def load() -> Optional[ctypes.CDLL]:
         _load_attempted = True
         if os.environ.get("SMARTBFT_NO_NATIVE"):
             return None
-        lib_path = os.path.join(_DIR, _LIB_NAME)
-        if _stale(lib_path) and not _build_lib(lib_path):
+        lib_path = _lib_path()
+        if lib_path is None:
+            return None
+        if not os.path.exists(lib_path) and not _build_lib(lib_path):
             return None
         try:
             lib = ctypes.CDLL(lib_path, use_errno=True)
@@ -91,25 +101,15 @@ def load() -> Optional[ctypes.CDLL]:
             sz = ctypes.c_size_t
             for name in ("smartbft_bls_g1_mul", "smartbft_bls_g1_mul_glv",
                          "smartbft_bls_g2_mul"):
-                # a prebuilt .so from an older source snapshot (the
-                # source-pruned deploy _stale() supports) may lack newer
-                # symbols — degrade just that entry point, never the
-                # whole native plane
-                try:
-                    fn = getattr(lib, name)
-                except AttributeError:
-                    continue
+                fn = getattr(lib, name)
                 fn.restype = ctypes.c_int
                 fn.argtypes = [buf, sz, buf, ctypes.c_char_p]
             for name in ("smartbft_bls_g1_sum", "smartbft_bls_g2_sum"):
                 fn = getattr(lib, name)
                 fn.restype = ctypes.c_int
                 fn.argtypes = [buf, sz, ctypes.c_char_p]
-            try:
-                lib.smartbft_ed_decompress.restype = ctypes.c_int
-                lib.smartbft_ed_decompress.argtypes = [buf, ctypes.c_char_p]
-            except AttributeError:
-                pass  # older prebuilt .so: ed decompress degrades to Python
+            lib.smartbft_ed_decompress.restype = ctypes.c_int
+            lib.smartbft_ed_decompress.argtypes = [buf, ctypes.c_char_p]
             _lib = lib
         except (OSError, AttributeError):
             _lib = None
@@ -192,8 +192,7 @@ def wal_append(fd: int, payload: bytes, crc: int, update_crc: bool,
 # ---------------------------------------------------------------------------
 
 def bls_available() -> bool:
-    lib = load()
-    return lib is not None and hasattr(lib, "smartbft_bls_g1_mul")
+    return load() is not None
 
 
 def _g1_bytes(pt) -> bytes:
@@ -238,11 +237,8 @@ def bls_g1_mul_torsion(k: int, pt) -> Optional[tuple]:
     """GLV-accelerated k * P — ONLY for P in the r-torsion subgroup (e.g.
     a hash-to-curve output or a validated key).  The endomorphism identity
     phi(P) = lambda*P fails off the subgroup, so subgroup checks and
-    cofactor clearing must call :func:`bls_g1_mul` instead.  Falls back to
-    the generic ladder when the loaded library predates the GLV symbol."""
+    cofactor clearing must call :func:`bls_g1_mul` instead."""
     lib = load()
-    if not hasattr(lib, "smartbft_bls_g1_mul_glv"):
-        return bls_g1_mul(k, pt)
     scalar = k.to_bytes(max(1, (k.bit_length() + 7) // 8), "big")
     out = ctypes.create_string_buffer(96)
     rc = lib.smartbft_bls_g1_mul_glv(scalar, len(scalar), _g1_bytes(pt), out)
@@ -284,8 +280,7 @@ def bls_g2_sum(points) -> Optional[tuple]:
 # ---------------------------------------------------------------------------
 
 def ed_available() -> bool:
-    lib = load()
-    return lib is not None and hasattr(lib, "smartbft_ed_decompress")
+    return load() is not None
 
 
 def ed_decompress(comp: bytes) -> Optional[tuple]:

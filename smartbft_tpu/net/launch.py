@@ -1435,6 +1435,7 @@ class ControlServer:
         self.addr = addr
         self.stop_evt = stop_evt
         self._server: Optional[asyncio.AbstractServer] = None
+        self._writers: set = set()
 
     async def start(self) -> None:
         scheme, hostpath, port = parse_addr(self.addr)
@@ -1450,6 +1451,11 @@ class ControlServer:
     async def close(self) -> None:
         if self._server is not None:
             self._server.close()
+            # since Python 3.12.1 Server.wait_closed() waits for every
+            # accepted connection, and a client may keep its pooled one
+            # open: close them from this side first
+            for writer in list(self._writers):
+                writer.close()
             await self._server.wait_closed()
             self._server = None
             scheme, hostpath, _ = parse_addr(self.addr)
@@ -1461,6 +1467,7 @@ class ControlServer:
 
     async def _serve(self, reader: asyncio.StreamReader,
                      writer: asyncio.StreamWriter) -> None:
+        self._writers.add(writer)
         try:
             while True:
                 line = await reader.readline()
@@ -1476,6 +1483,7 @@ class ControlServer:
         except (ConnectionError, OSError):
             pass
         finally:
+            self._writers.discard(writer)
             writer.close()
 
     async def _handle(self, req: dict) -> dict:

@@ -376,9 +376,7 @@ class SocketComm(Comm):
         self._closing = True
         self._closed_evt.set()
         if self._server is not None:
-            self._server.close()
-            await self._server.wait_closed()
-            self._server = None
+            self._server.close()  # stop accepting; waited for below
         # senders: wake them so each drains its outbox once and exits
         for peer in self._peers.values():
             if peer.wake is not None:
@@ -399,6 +397,12 @@ class SocketComm(Comm):
             with contextlib.suppress(Exception):
                 await writer.wait_closed()
         self._inbound_writers.clear()
+        if self._server is not None:
+            # only now: since Python 3.12.1 Server.wait_closed() waits for
+            # every accepted connection to close, so awaiting it before
+            # the inbound connections above are closed never returns
+            await self._server.wait_closed()
+            self._server = None
         for fut in self._sync_waiters.values():
             if not fut.done():
                 fut.cancel()

@@ -30,9 +30,7 @@ from .engine import (
     QuorumMeshVerifyEngine,
     ShardedVerifyEngine,
     build_mesh,
-    mesh_device_count,
     quorum_decide,
-    shard_map_available,
 )
 
 __all__ = [
@@ -41,7 +39,5 @@ __all__ = [
     "QuorumMeshVerifyEngine",
     "ShardedVerifyEngine",
     "build_mesh",
-    "mesh_device_count",
     "quorum_decide",
-    "shard_map_available",
 ]

@@ -25,69 +25,6 @@ from ..crypto import p256
 from ..crypto.provider import JaxVerifyEngine, MeshVerifyStats
 
 
-#: one-shot memo for the shard_map probe: [wrapper-or-None] once resolved.
-#: The fallback-import dance (attr walk + jax.experimental import attempt)
-#: used to re-run on EVERY engine construction; the answer is a property
-#: of the jax build and cannot change within a process, so it is cached —
-#: and exported into the metrics ``mesh`` block (shard_map_available) so
-#: bench rows record which path actually ran.
-_SHARD_MAP_MEMO: list = []
-
-
-def _probe_shard_map():
-    """The raw probe (see :func:`resolve_shard_map`); runs at most once."""
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        try:
-            from jax.experimental.shard_map import shard_map as sm
-        except Exception:
-            return None
-
-    def call(f, *, mesh, in_specs, out_specs):
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:  # older spelling
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-
-    return call
-
-
-def resolve_shard_map(required: bool = False):
-    """The usable shard_map entry point of this jax build, or None.
-
-    jax graduated ``jax.experimental.shard_map.shard_map`` (replication
-    check spelled ``check_rep``) to top-level ``jax.shard_map``
-    (``check_vma``); container images pin various points of that timeline.
-    Returns a uniform ``call(f, mesh=, in_specs=, out_specs=)`` wrapper
-    with the replication/varying-manual-axes check disabled (the bignum
-    carry-chain scans initialize carries from unvarying constants, which
-    the checker rejects).  When neither API exists: returns None, or with
-    ``required=True`` raises the capability error — callers either gate on
-    :func:`shard_map_available` or demand it outright.
-
-    Memoized: the probe runs once per process (the answer is fixed by the
-    jax build); repeated engine constructions reuse the cached wrapper.
-    """
-    if not _SHARD_MAP_MEMO:
-        _SHARD_MAP_MEMO.append(_probe_shard_map())
-    call = _SHARD_MAP_MEMO[0]
-    if call is None and required:
-        raise RuntimeError(
-            "no usable shard_map API in this jax build (neither "
-            "jax.shard_map nor jax.experimental.shard_map)"
-        )
-    return call
-
-
-def shard_map_available() -> bool:
-    """Capability probe for the mesh quorum step (tests skip-gate on it)."""
-    return resolve_shard_map() is not None
-
-
 def build_mesh(shape: Optional[tuple[int, ...]] = None,
                axis_names: tuple[str, ...] = ("lane",),
                devices=None):
@@ -108,6 +45,11 @@ def build_mesh(shape: Optional[tuple[int, ...]] = None,
     from jax.sharding import Mesh
 
     return Mesh(np.asarray(devices[:n]).reshape(shape), axis_names)
+
+
+def _device_span(arrays) -> int:
+    """Fewest distinct devices any of ``arrays`` is laid out over."""
+    return min(len(a.sharding.device_set) for a in arrays)
 
 
 class ShardedVerifyEngine(JaxVerifyEngine):
@@ -148,16 +90,6 @@ class MeshUnavailable(RuntimeError):
     (``CryptoProvider.configure_verify_mesh``) catches this and constructs
     the single-device engine LOUDLY with a counted downgrade — a
     mis-provisioned host degrades to reduced width instead of dying."""
-
-
-def mesh_device_count() -> int:
-    """Visible device count (0 when jax cannot initialize a backend)."""
-    import jax
-
-    try:
-        return len(jax.devices())
-    except Exception:  # noqa: BLE001 — capability probe, never fatal
-        return 0
 
 
 #: default per-device lane ladder for the graduated mesh engine: each
@@ -253,11 +185,15 @@ class MeshVerifyEngine(ShardedVerifyEngine):
             out[rows] = a
             return self._place(out)
 
-        mask = np.asarray(self._kernel(*(scatter(a) for a in arrays)))
+        placed = [scatter(a) for a in arrays]
+        out = self._kernel(*placed)
+        io_devices = (_device_span(placed), _device_span([out]))
+        mask = np.asarray(out)
         dt = time.perf_counter() - t0
         counts = [len(range(d, n, d_count)) for d in range(d_count)]
         with self._lock:
-            self.stats.record(n, size, dt, per_device=counts)
+            self.stats.record(n, size, dt, per_device=counts,
+                              io_devices=io_devices)
         return [bool(v) for v in mask[rows]]
 
 
@@ -283,8 +219,7 @@ class QuorumMeshVerifyEngine(JaxVerifyEngine):
     ``CryptoProvider.configure_verify_mesh`` seam as the 1D engine —
     construction from a ``devices`` count builds the (seq × vote) mesh
     (vote axis 2-wide on even widths), raises :class:`MeshUnavailable`
-    on narrower hosts OR when this jax build has no usable shard_map
-    (both downgrade loudly at the seam), and the PR 3
+    on narrower hosts (a loud counted downgrade at the seam), and the PR 3
     deadline/retry/breaker/canary contract wraps ``verify`` per mesh
     launch exactly like the 1D engine's.
     """
@@ -310,11 +245,6 @@ class QuorumMeshVerifyEngine(JaxVerifyEngine):
                               devices=avail[:want])
         if tuple(mesh.axis_names) != ("seq", "vote"):
             raise ValueError("QuorumMeshVerifyEngine wants a ('seq','vote') mesh")
-        if resolve_shard_map() is None:
-            raise MeshUnavailable(
-                "2d verify mesh needs a shard_map API (neither jax.shard_map "
-                "nor jax.experimental.shard_map is usable in this build)"
-            )
         self.mesh = mesh
         seq_par, vote_par = (int(x) for x in mesh.devices.shape)
         self._seq_par, self._vote_par = seq_par, vote_par
@@ -344,12 +274,14 @@ class QuorumMeshVerifyEngine(JaxVerifyEngine):
         return out
 
     def _build_step(self, ranks: tuple[int, ...]):
-        """One jitted shard_map step per input-rank tuple: kernel inputs
+        """One jitted shard_map step per input-rank tuple, with the
+        shardings its (weights, *inputs) are placed with: kernel inputs
         may be per-vote vectors (rank 3 as a quorum block) or per-vote
         scalars (rank 2, e.g. the toy scheme's key column) — specs are
         derived from the actual ranks like :func:`quorum_decide`."""
         import jax
         import jax.numpy as jnp
+        from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
         scheme = self.scheme
@@ -363,10 +295,13 @@ class QuorumMeshVerifyEngine(JaxVerifyEngine):
             P("seq", "vote", None) if r == 3 else P("seq", "vote")
             for r in ranks
         )
-        shard_map = resolve_shard_map(required=True)
-        sharded = shard_map(step, mesh=self.mesh, in_specs=in_specs,
-                            out_specs=(P("seq", "vote"), P("seq")))
-        return jax.jit(sharded)
+        shardings = [NamedSharding(self.mesh, s) for s in in_specs]
+        # check_vma off: the bignum carry-chain scans initialize carries
+        # from unvarying constants, which the checker rejects
+        sharded = jax.shard_map(step, mesh=self.mesh, in_specs=in_specs,
+                                out_specs=(P("seq", "vote"), P("seq")),
+                                check_vma=False)
+        return jax.jit(sharded), shardings
 
     def _probe_item(self):
         sk, pub = self.scheme.keygen(b"quorum-mesh-probe")
@@ -376,8 +311,6 @@ class QuorumMeshVerifyEngine(JaxVerifyEngine):
         if not items:
             return []
         import time as _time
-
-        import jax.numpy as jnp
 
         # group the flush into rows by message; rows with more votes than
         # the tile split across rows (verdicts stay exact; the split rows'
@@ -435,14 +368,17 @@ class QuorumMeshVerifyEngine(JaxVerifyEngine):
                         flat.append(fill)
             arrays = self.scheme.verify_inputs(flat)
             shape = (self.seq_tile, self.vote_tile)
-            blocks = tuple(
-                jnp.asarray(a.reshape(shape + a.shape[1:])) for a in arrays
-            )
+            blocks = tuple(a.reshape(shape + a.shape[1:]) for a in arrays)
             ranks = tuple(b.ndim for b in blocks)
-            fn = self._steps.get(ranks)
-            if fn is None:
-                fn = self._steps[ranks] = self._build_step(ranks)
-            mask2d, counts = fn(jnp.asarray(weights), *blocks)
+            step = self._steps.get(ranks)
+            if step is None:
+                step = self._steps[ranks] = self._build_step(ranks)
+            fn, shardings = step
+            # host -> each device's tile directly, not via device 0
+            placed = [self._jax.device_put(a, s)
+                      for a, s in zip((weights,) + blocks, shardings)]
+            mask2d, counts = fn(*placed)
+            io_devices = (_device_span(placed), _device_span([mask2d]))
             mask2d = np.asarray(mask2d)
             counts = np.asarray(counts)
             self.psum_steps += 1
@@ -459,7 +395,7 @@ class QuorumMeshVerifyEngine(JaxVerifyEngine):
             m: c >= self.quorum for m, c in self.last_counts.items()
         }
         self.stats.record(len(items), lanes, _time.perf_counter() - t0,
-                          per_device=dev_counts)
+                          per_device=dev_counts, io_devices=io_devices)
         return out
 
 
@@ -496,8 +432,8 @@ def quorum_decide(mesh, quorum: int, scheme=p256):
         specs = tuple(
             P("seq", "vote", None) if r == 3 else P("seq", "vote") for r in ranks
         )
-        shard_map = resolve_shard_map(required=True)
-        sharded = shard_map(step, mesh=mesh, in_specs=specs, out_specs=P("seq"))
+        sharded = jax.shard_map(step, mesh=mesh, in_specs=specs,
+                                out_specs=P("seq"), check_vma=False)
         return jax.jit(sharded)
 
     def decide(*arrays):

@@ -121,7 +121,7 @@ class ChaosEvent:
 
     - ``engine_hang``: verify launches block until released (the coalescer
       deadline abandons them); ``engine_fail`` (× ``count``): transient
-      tunnel-class errors; ``engine_slow`` (``fraction`` seconds of added
+      runtime errors; ``engine_slow`` (``fraction`` seconds of added
       latency); ``engine_permanent``: compile-class error, trips the
       breaker immediately; ``engine_heal``: clear all device faults.
     - ``engine_device_down`` / ``engine_device_restore`` (``count`` =

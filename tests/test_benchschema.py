@@ -92,7 +92,7 @@ def mesh_child_rows():
                 "launches_ungated": 6, "batch_fill_ungated_pct": 25.0,
                 "tx_per_sec_ungated": tx * 0.9,
                 "mesh": {"devices": d, "topology": "1d",
-                         "shard_map_available": True, "downgrades": 0,
+                         "downgrades": 0,
                          "hold": {}}}
 
     return [

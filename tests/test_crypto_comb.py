@@ -153,11 +153,12 @@ def test_engine_comb_path_and_fallback(monkeypatch):
     out = eng.verify(items)
     assert out == expect
     assert calls["comb"] == 1
+    assert eng.stats.launches_by_kernel["comb"] == 1
 
-    # registry full -> CombVerifier.verify returns None -> generic kernel
+    # registry full -> CombVerifier.verify returns None -> generic kernel,
+    # and the launch is counted under ITS name
     eng2 = JaxVerifyEngine(pad_sizes=(8,), scheme=p256)
     eng2._comb.registry = pc.CombKeyRegistry(cap=0)
-    eng2._comb_state["enabled"] = True
 
     def generic_stub(*arrays):
         calls["generic"] += 1
@@ -166,10 +167,12 @@ def test_engine_comb_path_and_fallback(monkeypatch):
         mask[: len(items)] = [p256.verify_item(it) for it in items]
         return mask
 
-    monkeypatch.setattr(eng2, "_kernel", generic_stub)
+    monkeypatch.setattr(eng2, "_pallas_kernel", generic_stub)
     out2 = eng2.verify(items)
     assert out2 == expect
     assert calls["generic"] == 1
+    assert eng2.stats.launches_by_kernel["comb"] == 0
+    assert eng2.stats.launches_by_kernel["pallas"] == 1
 
 
 def test_concurrent_registration_binds_keys_consistently(monkeypatch):
@@ -224,9 +227,8 @@ def test_concurrent_registration_binds_keys_consistently(monkeypatch):
 
 def test_registry_full_mid_drain_warns_and_continues(monkeypatch, caplog):
     """A CombRegistryFull raised while draining pending prewarm keys must
-    neither escape verify() (the engine's failure guard would misread it
-    as a kernel transient and burn a strike toward permanently disabling
-    the comb path) nor degrade the current chunk when its signers are all
+    neither escape verify() (the engine would misread it as a kernel
+    failure) nor degrade the current chunk when its signers are all
     registered.  Scenario: shared long-lived engine — this provider's
     prewarm passed the cap check at construction, then OTHER providers'
     first-use registrations filled the registry before our first verify."""

@@ -8,7 +8,7 @@ collective, never the host) while per-item verdicts stay BIT-IDENTICAL
 to the 1D engine.  Tier-1 pins:
 
 - engine shape: devices-count construction, (seq, vote) mesh axes,
-  MeshUnavailable on narrow hosts AND on builds without shard_map,
+  MeshUnavailable on narrow hosts (a counted downgrade at the seam),
   MeshVerifyStats accounting, the ``topology`` marker;
 - THE parity gate: randomized mixed-tag waves with forged votes, pad
   slots and duplicate votes verify bit-identically through the 2D
@@ -46,13 +46,12 @@ from smartbft_tpu.parallel import (
     MeshVerifyEngine,
     QuorumMeshVerifyEngine,
 )
-from smartbft_tpu.parallel import engine as parallel_engine
 from smartbft_tpu.testing import toy_scheme
 from smartbft_tpu.testing.app import wait_for
 from smartbft_tpu.testing.engine_faults import FaultyEngine
 from smartbft_tpu.testing.sharded import ShardedCluster, sharded_config
 
-from tests.conftest import require_shard_map, tight_verify_policy as tight_policy
+from tests.conftest import tight_verify_policy as tight_policy
 
 
 def toy_wave(rng, count, n_signers=3, forge_p=0.3, dup_p=0.2):
@@ -81,7 +80,6 @@ def toy_wave(rng, count, n_signers=3, forge_p=0.3, dup_p=0.2):
 # --------------------------------------------------------------- engine shape
 
 def test_quorum_mesh_engine_shape_and_accounting():
-    require_shard_map()
     eng = QuorumMeshVerifyEngine(devices=8, scheme=toy_scheme, quorum=3)
     assert eng.devices == 8 and eng.topology == "2d"
     assert tuple(eng.mesh.axis_names) == ("seq", "vote")
@@ -107,20 +105,19 @@ def test_quorum_mesh_unavailable_on_narrow_host():
         QuorumMeshVerifyEngine(devices=64, scheme=toy_scheme)
 
 
-def test_quorum_mesh_unavailable_without_shard_map(monkeypatch):
-    """A build with no usable shard_map cannot run the psum step — the
-    engine must refuse at CONSTRUCTION so the wiring seam downgrades
-    loudly instead of dying at first verify."""
-    monkeypatch.setattr(parallel_engine, "_SHARD_MAP_MEMO", [None])
-    with pytest.raises(MeshUnavailable, match="shard_map"):
-        QuorumMeshVerifyEngine(devices=2, scheme=toy_scheme)
-    # ...and the seam turns that into a counted downgrade
+def test_quorum_mesh_too_wide_downgrades_at_the_seam():
+    """A 2D mesh wider than the host refuses at CONSTRUCTION, so the
+    wiring seam downgrades loudly (and counts it) instead of dying at
+    first verify — and a run can refuse the downgraded plane."""
     rings = Keyring.generate([1, 2], seed=b"nosm", scheme=toy_scheme)
     prov = toy_scheme.ToyCryptoProvider(rings[1])
     before = prov.coalescer.engine
-    prov.configure_verify_mesh(2, topology="2d")
+    prov.configure_verify_mesh(64, topology="2d")
     assert prov.coalescer.engine is before
     assert prov.coalescer.mesh_downgrades == 1
+    snap = prov.coalescer.mesh_snapshot()
+    assert snap["downgrades"] == 1 and snap["configured_devices"] == 64
+    assert snap["enabled"] is False
 
 
 # ------------------------------------------------------------- THE parity gate
@@ -131,7 +128,6 @@ def test_2d_verdicts_bit_identical_to_1d_and_single_device():
     to BIT-IDENTICAL verdict vectors on the 2D quorum mesh, the 1D
     batch mesh, and the single-device engine; the psum'd per-message
     counts equal the host tally of DISTINCT valid votes."""
-    require_shard_map()
     rng = random.Random(0x2D)
     single = JaxVerifyEngine(pad_sizes=(64,), scheme=toy_scheme)
     mesh_1d = MeshVerifyEngine(devices=8, pad_sizes=(64,),
@@ -165,7 +161,6 @@ def test_2d_parity_p256_production_curve():
     """One real P-256 wave through a small-tile 2D mesh — the
     production curve's verdicts match the single-device engine bit for
     bit."""
-    require_shard_map()
     rng = random.Random(7)
     keys = [p256.keygen(b"p2d-%d" % t) for t in range(2)]
     pool = []
@@ -188,7 +183,6 @@ def test_2d_parity_p256_production_curve():
 
 
 def test_2d_coalescer_slices_tagged_submitters_exactly():
-    require_shard_map()
     eng = QuorumMeshVerifyEngine(devices=8, scheme=toy_scheme, quorum=2)
     co = AsyncBatchCoalescer(eng, window=0.01)
     rng = random.Random(3)
@@ -220,7 +214,6 @@ def test_topology_knob_validation_and_mirror():
 
 
 def test_configure_verify_mesh_2d_graduates_and_switches_topologies():
-    require_shard_map()
     rings = Keyring.generate([1, 2, 3, 4], seed=b"2dwire",
                              scheme=toy_scheme)
     prov = toy_scheme.ToyCryptoProvider(rings[1])
@@ -247,7 +240,6 @@ def test_configure_verify_mesh_2d_graduates_and_switches_topologies():
 def test_configure_verify_mesh_2d_inside_fault_wrapper():
     """Graduating to the 2D engine inside a FaultyEngine wrapper keeps
     chaos injection connected and delegates the topology marker."""
-    require_shard_map()
     wrapped = FaultyEngine(JaxVerifyEngine(pad_sizes=(8,),
                                            scheme=toy_scheme))
     rings = Keyring.generate([1, 2], seed=b"2dwrap", scheme=toy_scheme)
@@ -267,7 +259,6 @@ def test_sharded_consensus_commits_through_2d_quorum_mesh(tmp_path):
     selected by Configuration ALONE: both shards commit through the 2D
     engine, psum steps ran, and the ``mesh`` block says which topology
     served."""
-    require_shard_map()
 
     def cfg(s, i):
         return dataclasses.replace(
@@ -309,7 +300,6 @@ def test_2d_mesh_launch_fault_contract_deadline_retry_breaker_canary():
     """The PR 3 contract metrics-asserted per 2D MESH launch: a hung 2D
     launch is deadline-abandoned, retried, trips the breaker to the
     host fallback, and the canary closes back ONTO the quorum mesh."""
-    require_shard_map()
     from smartbft_tpu.metrics import InMemoryProvider, TPUCryptoMetrics
 
     mem = InMemoryProvider()

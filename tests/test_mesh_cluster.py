@@ -9,7 +9,6 @@ partitioned across the mesh — not just the bare ``quorum_decide`` kernel.
 import numpy as np
 import pytest
 
-from tests.conftest import require_shard_map
 
 import __graft_entry__ as graft
 from smartbft_tpu.crypto import p256
@@ -41,18 +40,15 @@ def test_consensus_cluster_commits_on_mesh():
     under live consensus — the scenario the round-4 review flagged as
     exercised only by the bare kernel.
 
-    slow-marked: ~4 min of XLA compiles on the CPU rig (it used to FAIL
-    tier-1 outright when jax.shard_map was missing; the resolve_shard_map
-    shim made it runnable, and the engine-level mesh tests below keep the
-    kernel correctness in tier-1).  Run explicitly or via -m slow."""
-    require_shard_map()
+    slow-marked: ~4 min of XLA compiles on the CPU rig (the engine-level
+    mesh tests below keep the kernel correctness in tier-1).  Run
+    explicitly or via -m slow."""
     graft._dryrun_cluster_on_mesh(8)
 
 
 def test_quorum_mesh_engine_counts_match_verdicts():
     """The psum'd per-sequence counts equal the host-side tally of valid
     votes — forged votes excluded, padding lanes never counted."""
-    require_shard_map()
     from smartbft_tpu.parallel import QuorumMeshVerifyEngine
 
     mesh = build_mesh((4, 2), ("seq", "vote"))

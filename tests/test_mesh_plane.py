@@ -34,6 +34,7 @@ import pytest
 from smartbft_tpu.config import ConfigError, Configuration
 from smartbft_tpu.crypto import p256
 from smartbft_tpu.crypto.provider import (
+    KERNELS,
     AsyncBatchCoalescer,
     HostVerifyEngine,
     JaxVerifyEngine,
@@ -42,7 +43,6 @@ from smartbft_tpu.crypto.provider import (
 )
 from smartbft_tpu.metrics import InMemoryProvider, TPUCryptoMetrics
 from smartbft_tpu.parallel import MeshUnavailable, MeshVerifyEngine
-from smartbft_tpu.parallel import engine as parallel_engine
 from smartbft_tpu.testing import toy_scheme
 from smartbft_tpu.testing.app import wait_for
 from smartbft_tpu.testing.engine_faults import FaultyEngine, always_valid_engine
@@ -104,15 +104,23 @@ def test_mesh_unavailable_raises_cleanly():
         MeshVerifyEngine(devices=64, scheme=p256)
 
 
-def test_resolve_shard_map_is_memoized(monkeypatch):
-    first = parallel_engine.resolve_shard_map()
-
-    def boom():  # pragma: no cover — must never run
-        raise AssertionError("shard_map probe re-ran after memoization")
-
-    monkeypatch.setattr(parallel_engine, "_probe_shard_map", boom)
-    assert parallel_engine.resolve_shard_map() is first
-    assert parallel_engine.shard_map_available() is (first is not None)
+def test_launches_are_counted_per_kernel():
+    """Every launch is counted under the kernel that served it, so a run
+    can refuse a result its expected kernel did not produce: the XLA
+    kernel on this backend (single-device and mesh), host code for the
+    host engine, and nothing under the Pallas names."""
+    items, expect = toy_items(5)
+    single = JaxVerifyEngine(pad_sizes=(8,), scheme=toy_scheme)
+    mesh = MeshVerifyEngine(devices=2, scheme=toy_scheme)
+    host = HostVerifyEngine(scheme=toy_scheme)
+    for eng in (single, mesh, host):
+        assert eng.stats.launches_by_kernel == dict.fromkeys(KERNELS, 0)
+        assert eng.verify(items) == expect
+        assert eng.verify(items[:3]) == expect[:3]
+    for eng, kernel in ((single, "xla"), (mesh, "xla"), (host, "host")):
+        assert eng.stats.launches_by_kernel == {
+            **dict.fromkeys(KERNELS, 0), kernel: 2}
+        assert sum(eng.stats.launches_by_kernel.values()) == eng.stats.launches
 
 
 # ------------------------------------------------------------ verdict parity
@@ -223,7 +231,6 @@ def test_configure_verify_mesh_graduates_idempotently_and_downgrades():
     snap = co.mesh_snapshot()
     assert snap["configured_devices"] == 999 and snap["devices"] == 8
     assert snap["downgrades"] == 1
-    assert snap["shard_map_available"] in (True, False)
 
 
 def test_configure_verify_mesh_respects_fault_wrapped_mesh():
@@ -446,17 +453,31 @@ def test_faulty_engine_mesh_device_faults_are_transient_class():
 
 # --------------------------------------------- compile-cache persistence
 
-def test_compile_cache_dir_env_override(monkeypatch):
-    """ISSUE 11 satellite: SMARTBFT_JAX_CACHE_DIR points the persistent
-    XLA compilation cache at durable storage on device rigs, so the 2-3
-    min per-process mesh compile is paid once per shape, not per bench
-    subprocess; unset, the fingerprinted default applies."""
+def test_compile_cache_rule(monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set the program sets no cache
+    directory in code (jax reads the variable itself); unset, the cache
+    is at ONE fixed path inside the checkout — the path is part of the
+    cache key, so nothing of it may come from a temp name, pid or time."""
+    import pathlib
+
+    import jax
+
     from smartbft_tpu.utils import jaxenv
 
-    monkeypatch.setenv("SMARTBFT_JAX_CACHE_DIR", "/tmp/rig-cache")
-    assert jaxenv.cache_dir() == "/tmp/rig-cache"
-    monkeypatch.delenv("SMARTBFT_JAX_CACHE_DIR")
-    assert "smartbft_jax_cache" in jaxenv.cache_dir()
+    repo = pathlib.Path(__file__).resolve().parent.parent
+    seen = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: seen.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    jaxenv.enable_compile_cache()
+    assert seen == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    jaxenv.enable_compile_cache()
+    jaxenv.enable_compile_cache()
+    assert seen == [("jax_compilation_cache_dir", jaxenv.cache_dir())] * 2
+    fixed = pathlib.Path(jaxenv.cache_dir())
+    assert fixed.parent == repo / ".jax_cache"
+    assert ".jax_cache/" in (repo / ".gitignore").read_text().splitlines()
 
 
 def test_prewarm_verify_engine_compiles_every_rung():
@@ -488,7 +509,6 @@ def _synthetic_mesh_rows():
             "tx_per_sec_ungated": 110.0 * d,
             "mesh": {"enabled": True, "devices": d, "configured_devices": d,
                      "downgrades": 0, "topology": "1d",
-                     "shard_map_available": True,
                      "hold": {"hold_s": 0.25, "waves_held": 2,
                               "held_ms": 350.0, "depth_gain_items": 240,
                               "deadline_expired": 1, "breaker_bypass": 0},
@@ -532,13 +552,12 @@ def test_assemble_mesh_row_schema_pinned():
     for key in ("fixed_shards", "crypto", "sweep", "capacity_scaling",
                 "items_per_launch_ratio", "tx_ratio", "verdict_parity",
                 "verdict_parity_2d", "gating", "topology",
-                "shard_map_available", "downgrades", "top"):
+                "downgrades", "top"):
         assert key in mesh, mesh.keys()
     assert mesh["capacity_scaling"] == 8.0
     assert mesh["verdict_parity"]["match"] is True
     assert mesh["verdict_parity_2d"]["match"] is True
     assert mesh["verdict_parity_2d"]["counts_match"] is True
-    assert mesh["shard_map_available"] is True
     assert mesh["topology"] == "1d"
     # the ISSUE 11 wave-deepening claim rides the row: gated fill and a
     # strict launch reduction vs the ungated control, hold decisions in

@@ -7,7 +7,6 @@ and the 2D (seq x vote) quorum step with its psum reduction.
 
 import numpy as np
 
-from tests.conftest import require_shard_map
 
 from smartbft_tpu.crypto import p256
 from smartbft_tpu.crypto.provider import Keyring, P256CryptoProvider
@@ -73,7 +72,6 @@ def _place_quorum_block(mesh, args):
 
 
 def test_quorum_decide_2d_mesh():
-    require_shard_map()
     mesh = build_mesh((4, 2), ("seq", "vote"))
     n_seq, n_votes = 4, 4
     quorum = 3
@@ -99,7 +97,6 @@ def test_quorum_decide_2d_mesh():
 def test_quorum_decide_scheme_generic_ed25519():
     """ed25519's trailing host-validity mask is a rank-2 quorum input; the
     per-rank partition specs must handle it."""
-    require_shard_map()
     from smartbft_tpu.crypto import ed25519 as ed
 
     mesh = build_mesh((2, 2), ("seq", "vote"))
