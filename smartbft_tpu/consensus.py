@@ -99,13 +99,15 @@ class Consensus:
         self.metrics = metrics or MetricsBundle()
         self.scheduler = scheduler if scheduler is not None else Scheduler()
         # flight recorder (ISSUE 12): the embedder passes an
-        # obs.TraceRecorder to trace this replica; the default nop
-        # recorder keeps every instrumentation site at one attribute
-        # read.  The VC phase tracker rides the SAME injectable clock as
-        # every other timer and outlives reconfig-rebuilt components.
-        from .obs import NOP_RECORDER, ViewChangePhaseTracker
+        # obs.TraceRecorder to trace this replica; the default is a
+        # disabled one of this replica's own, which keeps every
+        # instrumentation site at one attribute read until a profiler
+        # session switches it on.  The VC phase tracker rides the SAME
+        # injectable clock as every other timer and outlives
+        # reconfig-rebuilt components.
+        from .obs import ViewChangePhaseTracker, standby
 
-        self.recorder = recorder if recorder is not None else NOP_RECORDER
+        self.recorder = standby(recorder, node=f"n{config.self_id}")
         self.vc_phases = ViewChangePhaseTracker(
             clock=self.scheduler.now, node=f"n{config.self_id}",
             recorder=self.recorder, metrics=self.metrics.view_change,

@@ -58,10 +58,6 @@ class ControlLoop:
         self.arbiter = arbiter or TransitionArbiter()
         if recorder is None:
             recorder = cluster._recorder_for("ctl")
-        if recorder is None:  # trace=False clusters hand out None
-            from ..obs import NOP_RECORDER
-
-            recorder = NOP_RECORDER
         self.recorder = recorder
         self.logger = logger or logging.getLogger("smartbft.control")
         self.executed: List[Dict[str, Any]] = []

@@ -46,6 +46,7 @@ from ..messages import (
     ViewMetadata,
 )
 from ..metrics import ConsensusMetrics, ViewMetrics
+from ..obs.recorder import close_for_await
 from ..types import (
     Checkpoint,
     Proposal,
@@ -163,11 +164,11 @@ class Controller(RequestTimeoutHandler):
         self.view_sequences = view_sequences
         self.metrics_view = metrics_view
         self.metrics_consensus = metrics_consensus
-        #: flight recorder (obs.TraceRecorder; the nop singleton when
-        #: tracing is off — every hot-path site guards on .enabled)
-        from ..obs.recorder import NOP_RECORDER
+        #: flight recorder (obs.TraceRecorder, disabled unless tracing —
+        #: every hot-path site guards on .enabled)
+        from ..obs.recorder import standby
 
-        self.recorder = recorder if recorder is not None else NOP_RECORDER
+        self.recorder = standby(recorder)
         #: obs.ViewChangePhaseTracker — the first delivery in a new view
         #: closes an open view-change round's `first_commit` phase
         self.vc_phases = vc_phases
@@ -511,15 +512,21 @@ class Controller(RequestTimeoutHandler):
         if view is None:
             return
         rec = self.recorder
-        if rec.enabled:
-            rec.record("wave.ingest", view=self.curr_view_number,
-                       extra={"count": len(run)})
-        ingest = getattr(view, "ingest_batch", None)
-        if ingest is not None:
-            ingest(run)
-        else:
-            for sender, m in run:
-                view.handle_message(sender, m)
+        # busy span: one per drained wave — the synchronous part of the
+        # view's processing of it (WindowedView registers the wave here;
+        # View queues it and registers in its own view.ingest span)
+        span = rec.begin("view.ingest", view=self.curr_view_number,
+                         extra={"count": len(run)}) if rec.enabled else None
+        try:
+            ingest = getattr(view, "ingest_batch", None)
+            if ingest is not None:
+                ingest(run)
+            else:
+                for sender, m in run:
+                    view.handle_message(sender, m)
+        finally:
+            if span is not None:
+                rec.end(span)
 
     def _finish_view_run(self, run: list) -> None:
         """Shared tail of both flush paths: view-change evidence fan-out +
@@ -738,11 +745,13 @@ class Controller(RequestTimeoutHandler):
         self._assembly_task = create_logged_task(
             self._assemble_and_propose(view, window_has_room),
             name=f"controller-assemble-{self.id}", logger=self.logger,
+            busy=(self.recorder, "batch.cut"),
         )
 
     async def _assemble_and_propose(self, view, window_has_room) -> None:
         """One batch-form + assemble + propose cycle, running in the shadow
-        of the in-flight wave's verify launch.  Every controller-state
+        of the in-flight wave's verify launch (its task's steps are the
+        ``batch.cut`` busy spans: batcher + assemble + propose).  Every controller-state
         mutation here is loop-synchronous (no awaits between the post-batch
         guard and the propose), so the event loop never observes a half
         -proposed state."""
@@ -836,6 +845,29 @@ class Controller(RequestTimeoutHandler):
 
     async def _decide(self, d: _Decision) -> None:
         """controller.go:528-558."""
+        rec = self.recorder
+        # busy span: controller deliver + app.deliver + pool removal (an
+        # application whose deliver blocks closes it at the executor hop)
+        span = rec.begin("deliver") if rec.enabled else None
+        try:
+            md = await self._deliver_and_mark(d)
+        finally:
+            if span is not None:
+                rec.end(span)
+        if md is None:  # stopped meanwhile
+            return
+        if self._check_if_rotate(list(md.black_list)):
+            self.logger.debugf("Restarting view to rotate the leader")
+            await self._change_view(
+                self.curr_view_number, md.latest_sequence + 1, self.curr_decisions_in_view
+            )
+            self.request_pool.restart_timers()
+        self.maybe_prune_revoked_requests()
+        if self.i_am_the_leader()[0]:
+            self._acquire_leader_token()
+
+    async def _deliver_and_mark(self, d: _Decision):
+        """-> the decision's ViewMetadata, or None if stopped meanwhile."""
         reconfig = await self.deliver.deliver(d.proposal, d.signatures)
         if reconfig.in_latest_decision:
             self._reconfig = reconfig
@@ -849,7 +881,7 @@ class Controller(RequestTimeoutHandler):
         if not d.done.done():
             d.done.set_result(None)
         if self._stopped:
-            return
+            return None
         self.curr_decisions_in_view += 1
         now = self._clock()
         if self._last_commit_t is not None:
@@ -866,21 +898,19 @@ class Controller(RequestTimeoutHandler):
             vp.decision(md.view_id, backlog=self.request_pool.size())
         rec = self.recorder
         if rec.enabled:
+            # the replica that proposed this decision (still the leader
+            # here: a rotation comes after the mark) counts it and alone
+            # records its per-request deliver marks — all critpath reads
+            mine = self.i_am_the_leader()[0]
             rec.record("decision.deliver", view=md.view_id,
                        seq=md.latest_sequence,
-                       extra={"count": len(d.requests)})
-            for info in d.requests:
-                rec.record("req.deliver", key=str(info), view=md.view_id,
-                           seq=md.latest_sequence)
-        if self._check_if_rotate(list(md.black_list)):
-            self.logger.debugf("Restarting view to rotate the leader")
-            await self._change_view(
-                self.curr_view_number, md.latest_sequence + 1, self.curr_decisions_in_view
-            )
-            self.request_pool.restart_timers()
-        self.maybe_prune_revoked_requests()
-        if self.i_am_the_leader()[0]:
-            self._acquire_leader_token()
+                       extra={"count": len(d.requests), "proposer": True}
+                       if mine else {"count": len(d.requests)})
+            if mine:
+                for info in d.requests:
+                    rec.record("req.deliver", key=str(info),
+                               view=md.view_id, seq=md.latest_sequence)
+        return md
 
     def _check_if_rotate(self, blacklist: list[int]) -> bool:
         """controller.go:560-574 (called after increment).
@@ -1084,7 +1114,8 @@ class Controller(RequestTimeoutHandler):
         self.curr_decisions_in_view = start_decisions_in_view
         self._start_view(start_proposal_sequence)
         self._task = create_logged_task(
-            self._run(), name=f"controller-{self.id}", logger=self.logger
+            self._run(), name=f"controller-{self.id}", logger=self.logger,
+            busy=(self.recorder, "ctl.run"),
         )
 
     def close(self) -> None:
@@ -1159,6 +1190,9 @@ class MutuallyExclusiveDeliver:
 
     async def deliver(self, proposal: Proposal, signatures: list) -> Reconfig:
         pending_md = decode(ViewMetadata, proposal.metadata)
+        traced = self.c.recorder.enabled
+        if traced and self.c._sync_lock.locked():
+            close_for_await()  # the controller's deliver span ends here
         async with self.c._sync_lock:
             latest = self.c.latest_seq()
             if latest != 0 and latest >= pending_md.latest_sequence:
@@ -1167,6 +1201,8 @@ class MutuallyExclusiveDeliver:
                     "already synced to seq %d, returning result from sync",
                     pending_md.latest_sequence, latest,
                 )
+                if traced:
+                    close_for_await()
                 sync_result = await asyncio.get_running_loop().run_in_executor(
                     None, self.c.synchronizer.sync
                 )
@@ -1192,6 +1228,8 @@ class MutuallyExclusiveDeliver:
             # delivers themselves, measured ~0.1 ms x n x decisions per
             # n=64 bench run.
             if getattr(self.c.application, "blocking_deliver", True):
+                if traced:
+                    close_for_await()
                 result = await asyncio.get_running_loop().run_in_executor(
                     None, self.c.application.deliver, proposal, signatures
                 )

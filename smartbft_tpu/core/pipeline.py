@@ -244,11 +244,11 @@ class WindowedView:
         self.in_flight = in_flight
         self.metrics = metrics_view
         # flight recorder: per-slot quorum-completion + WAL-persist marks
-        # for the critical-path decomposition (obs.critpath); the nop
-        # singleton keeps every site at one attribute read when off
-        from ..obs.recorder import NOP_RECORDER
+        # for the critical-path decomposition (obs.critpath); disabled,
+        # it keeps every site at one attribute read
+        from ..obs.recorder import standby
 
-        self.recorder = recorder if recorder is not None else NOP_RECORDER
+        self.recorder = standby(recorder)
         #: one dense signer-id index shared by every slot's vote sets
         self._signer_index = SignerIndex(nodes_list)
         #: called (no args) when propose capacity re-opens WITHOUT a
@@ -344,7 +344,7 @@ class WindowedView:
     def start(self) -> None:
         self._task = create_logged_task(
             self._run(), name=f"wview-{self.self_id}-{self.number}",
-            logger=self.logger,
+            logger=self.logger, busy=(self.recorder, "view.run"),
         )
 
     def stopped(self) -> bool:
@@ -933,7 +933,13 @@ class WindowedView:
                        "voters": len(slot.prepare_voters)},
             )
         prp_from = encode(PreparesFrom(ids=slot.prepare_voters))
-        sig = self.signer.sign_proposal(slot.proposal, prp_from)
+        span = rec.begin("vote.sign", view=self.number, seq=slot.seq) \
+            if rec.enabled else None
+        try:
+            sig = self.signer.sign_proposal(slot.proposal, prp_from)
+        finally:
+            if span is not None:
+                rec.end(span)
         slot.my_sig = sig
         commit = Commit(
             view=self.number,
@@ -941,15 +947,17 @@ class WindowedView:
             digest=slot.digest,
             signature=Signature(signer=sig.signer, value=sig.value, msg=sig.msg),
         )
+        t_save = rec.now() if rec.enabled else None
         fut = self._write_state(CommitRecord(commit=commit), truncate=False)
         self._commit_frontier = slot.seq
 
         def finalize() -> None:
             if rec.enabled:
                 # runs after the shared durability wave: the commit
-                # record is on disk (the WAL-first rule), so this is the
-                # wal_persist mark of the critical path
-                rec.record("wal.persist", view=self.number, seq=slot.seq)
+                # record is on disk (the WAL-first rule), so this wait is
+                # the wal_persist mark of the critical path
+                rec.wait("wal.persist", t_save, view=self.number,
+                         seq=slot.seq)
             if self.in_flight is not None:
                 self.in_flight.store_prepares_at(slot.seq)
             slot.commit_sent = replace(commit, assist=True)
@@ -1006,7 +1014,8 @@ class WindowedView:
                 self._work.set()
 
         t = create_logged_task(
-            run(), name=f"wview-verify-{self.self_id}-{seq}", logger=self.logger
+            run(), name=f"wview-verify-{self.self_id}-{seq}",
+            logger=self.logger, busy=(self.recorder, "view.run"),
         )
         self._verify_tasks.add(t)
         t.add_done_callback(self._verify_tasks.discard)

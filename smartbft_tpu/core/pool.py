@@ -26,6 +26,7 @@ from typing import Callable, Optional
 
 from ..api import Logger, RequestInspector
 from ..metrics import RequestPoolMetrics
+from ..obs.recorder import close_for_await
 from ..types import RequestInfo
 from ..utils.clock import Scheduler, TaskHandle
 
@@ -198,11 +199,11 @@ class Pool:
         self._scheduler = scheduler
         self._metrics = metrics
         self._on_submitted = on_submitted or (lambda: None)
-        # flight recorder (obs.TraceRecorder; nop singleton when tracing
-        # is off — submit's sites guard on .enabled, one attr read each)
-        from ..obs.recorder import NOP_RECORDER
+        # flight recorder (obs.TraceRecorder, disabled unless tracing —
+        # submit's sites guard on .enabled, one attr read each)
+        from ..obs.recorder import standby
 
-        self._recorder = recorder if recorder is not None else NOP_RECORDER
+        self._recorder = standby(recorder)
 
         self._items: "OrderedDict[RequestInfo, _Item]" = OrderedDict()
         # lazy timer wheel state: one FIFO deque of (deadline, info, gen)
@@ -351,6 +352,8 @@ class Pool:
                 ),
             )
             woken_clean = False
+            if rec.enabled:
+                close_for_await()  # the front door's busy span ends here
             try:
                 await fut
                 woken_clean = True
@@ -398,11 +401,12 @@ class Pool:
         if not self._stopped:
             self._arm(info, item, _STAGE_FWD, self._forward_timeout())
         self._size_bytes += len(request)
-        if rec.enabled:
-            # dur = time spent parked on space (0 for an immediate add)
+        if parked_at is not None and rec.enabled:
+            # only a submit that parked on space: dur = the time it was
+            # parked.  (An immediate add has its req.submit mark; critpath
+            # folds an absent pool mark into the next segment.)
             rec.record("req.pool", key=str(info),
-                       dur=(self._scheduler.now() - parked_at)
-                       if parked_at is not None else 0.0,
+                       dur=self._scheduler.now() - parked_at,
                        extra={"size": len(self._items)})
         if self._metrics:
             self._metrics.count_of_requests.set(len(self._items))

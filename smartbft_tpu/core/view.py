@@ -198,12 +198,12 @@ class View:
         self.metrics = metrics_view
         self.metrics_blacklist = metrics_blacklist
         self.in_msg_q_size = in_msg_q_size
-        # flight recorder (obs.TraceRecorder; nop singleton when tracing
-        # is off): quorum-completion + WAL-persist marks for the per-
+        # flight recorder (obs.TraceRecorder, disabled unless
+        # tracing): quorum-completion + WAL-persist marks for the per-
         # request critical-path decomposition (obs.critpath)
-        from ..obs.recorder import NOP_RECORDER
+        from ..obs.recorder import standby
 
-        self.recorder = recorder if recorder is not None else NOP_RECORDER
+        self.recorder = standby(recorder)
 
         self.phase = COMMITTED
         # runtime
@@ -279,7 +279,7 @@ class View:
     def start(self) -> None:
         self._task = create_logged_task(
             self._run(), name=f"view-{self.self_id}-{self.number}",
-            logger=self.logger,
+            logger=self.logger, busy=(self.recorder, "view.run"),
         )
 
     def stopped(self) -> bool:
@@ -429,11 +429,29 @@ class View:
         if item is _ABORT or self._aborted:
             raise ViewAborted()
         sender, msg = item
-        self._process_msg(sender, msg)
+        rec = self.recorder
+        span = rec.begin("view.ingest", view=self.number,
+                         seq=self.proposal_sequence) if rec.enabled else None
+        try:
+            self._process_msg(sender, msg)
+        finally:
+            if span is not None:
+                rec.end(span)
 
     def _drain_inbox(self) -> None:
         """Process everything already queued without awaiting — lets votes
         coalesce ahead of a batched verify."""
+        rec = self.recorder
+        span = rec.begin("view.ingest", view=self.number,
+                         seq=self.proposal_sequence) \
+            if rec.enabled and not self._inbox.empty() else None
+        try:
+            self._drain_queued()
+        finally:
+            if span is not None:
+                rec.end(span)
+
+    def _drain_queued(self) -> None:
         t0 = time.perf_counter()
         drained = False
         try:
@@ -653,9 +671,15 @@ class View:
         )
 
         prp_from = encode(PreparesFrom(ids=voter_ids))
-        self.my_proposal_sig = self.signer.sign_proposal(proposal, prp_from)
-
         seq = self.proposal_sequence
+        span = rec.begin("vote.sign", view=self.number, seq=seq) \
+            if rec.enabled else None
+        try:
+            self.my_proposal_sig = self.signer.sign_proposal(proposal, prp_from)
+        finally:
+            if span is not None:
+                rec.end(span)
+
         commit = Commit(
             view=self.number,
             seq=seq,
@@ -667,9 +691,12 @@ class View:
             ),
         )
         # Save our commit before broadcasting it (group-commit durability).
+        t_save = rec.now() if rec.enabled else None
         await self._save_state(CommitRecord(commit=commit))
         if rec.enabled:
-            rec.record("wal.persist", view=self.number, seq=seq)
+            # a wait: the commit record's durability wave, and the
+            # wal_persist mark of the decision's critical path
+            rec.wait("wal.persist", t_save, view=self.number, seq=seq)
         self._curr_commit_sent = replace(commit, assist=True)
         self.last_broadcast_sent = commit
         self.logger.infof("Processed prepares for proposal with seq %d", seq)

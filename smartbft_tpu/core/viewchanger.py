@@ -357,9 +357,9 @@ class ViewChanger:
         #: None = no decomposition (unit tests constructing a bare
         #: ViewChanger pay nothing)
         self.vc_phases = vc_phases
-        from ..obs.recorder import NOP_RECORDER
+        from ..obs.recorder import standby
 
-        self.recorder = recorder if recorder is not None else NOP_RECORDER
+        self.recorder = standby(recorder)
 
         # wired later by the Consensus facade (consensus.go:445-450,466-470)
         self.comm = None  # Controller (broadcast + send)

@@ -42,6 +42,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 
 from . import p256
+from ..obs.recorder import launch_span
 from .bignum import to_limbs
 from .p256 import B as CURVE_B, FP, GX, GY, N, NLIMBS, P
 from .pallas_ecdsa import (
@@ -546,6 +547,21 @@ class CombVerifier:
         # hash/pack and the launch run outside it, so concurrent flushes
         # only serialize on the (once-per-key) table builds, not on every
         # chunk's O(n) hashing.
+        with launch_span("verify.pack"):
+            packed = self._pack_chunk(items, pad_to)
+        if packed is None:
+            return None
+        arrays, ok, kidx, gtab, qtab = packed
+        with launch_span("verify.device"):
+            # dispatch to the mask's readback.  Read back BEFORE slicing:
+            # an eager slice of the device array would compile a tiny
+            # program for every new wave size
+            mask = np.asarray(self._launch(arrays, ok, kidx, gtab, qtab))
+        return mask[:len(items)]
+
+    def _pack_chunk(self, items, pad_to: int):
+        """Register, hash, pack and pad one chunk -> the launch's inputs,
+        or None when a key is unregistrable."""
         chunk_pubs = {it[-1] for it in items}
         with self._reg_lock:
             if self._pending_prewarm:
@@ -589,6 +605,4 @@ class CombVerifier:
             if ok is not None:
                 ok = np.concatenate([ok, np.zeros(pad_to - n, np.uint32)])
             kidx = np.concatenate([kidx, np.zeros(pad_to - n, np.int32)])
-        # read back BEFORE slicing: an eager slice of the device array
-        # would compile a tiny program for every new wave size
-        return np.asarray(self._launch(arrays, ok, kidx, gtab, qtab))[:n]
+        return arrays, ok, kidx, gtab, qtab

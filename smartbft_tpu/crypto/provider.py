@@ -38,6 +38,12 @@ import numpy as np
 
 from ..codec import decode, encode, wiremsg
 from ..messages import Proposal, Signature
+from ..obs.recorder import (
+    launch_span,
+    name_this_thread,
+    set_thread_launch,
+    standby,
+)
 from ..types import VerifyPlaneDown, proposal_digest
 from ..utils.memo import LruMemo
 from ..utils.tasks import create_logged_task
@@ -636,18 +642,22 @@ class JaxVerifyEngine:
             if mask is not None:
                 return "comb", mask
         n = len(items)
-        padded = [
-            self._place(np.concatenate(
-                [a, np.zeros((size - n,) + a.shape[1:], a.dtype)]
-            ))
-            for a in self.scheme.verify_inputs(items)
-        ]
+        with launch_span("verify.pack"):
+            padded = [
+                self._place(np.concatenate(
+                    [a, np.zeros((size - n,) + a.shape[1:], a.dtype)]
+                ))
+                for a in self.scheme.verify_inputs(items)
+            ]
         name, kernel = ("pallas", self._pallas_kernel) \
             if self._pallas_on and self._pallas_kernel is not None \
             else ("xla", self._kernel)
-        return name, self._guarded_launch(
-            name, size, lambda: np.asarray(kernel(*padded))
-        )
+
+        def launch():
+            with launch_span("verify.device"):
+                return np.asarray(kernel(*padded))
+
+        return name, self._guarded_launch(name, size, launch)
 
     def _verify_chunk(self, items) -> list[bool]:
         n = len(items)
@@ -757,13 +767,11 @@ class AsyncBatchCoalescer:
         #: is the ONE shared object in sharded mode — like the breaker)
         self.mesh_configured = 0   # Configuration.verify_mesh_devices wired
         self.mesh_downgrades = 0   # loud unbuildable-mesh downgrades
-        #: flight recorder (obs.TraceRecorder; nop singleton when tracing
-        #: is off) — verify enqueue/hold/launch spans + breaker
-        #: transitions, correlated by a per-coalescer launch id.  Shared
-        #: like the breaker: ONE recorder serves every colocated shard.
-        from ..obs.recorder import NOP_RECORDER
-
-        self.recorder = NOP_RECORDER
+        #: flight recorder (obs.TraceRecorder, disabled unless tracing) —
+        #: verify wait/hold/launch spans + breaker transitions,
+        #: correlated by a per-coalescer launch id.  Shared like the
+        #: breaker: ONE recorder serves every colocated shard.
+        self.recorder = standby(node="verify")
         self._launch_seq = 0
         self._pending: list[tuple] = []
         self._futures: list[tuple[asyncio.Future, int, int, object]] = []
@@ -813,11 +821,9 @@ class AsyncBatchCoalescer:
 
     def attach_recorder(self, recorder) -> None:
         """Point the verify plane's trace events at ``recorder`` (the
-        harness/embedder wires this when tracing is on; the default nop
-        recorder keeps the hot path at one attribute read per site)."""
-        from ..obs.recorder import NOP_RECORDER
-
-        self.recorder = recorder if recorder is not None else NOP_RECORDER
+        harness/embedder wires its own; the default disabled recorder
+        keeps the hot path at one attribute read per site)."""
+        self.recorder = standby(recorder, node="verify")
 
     def configure_hold(self, hold: Optional[float],
                        explicit: bool = False) -> None:
@@ -847,9 +853,6 @@ class AsyncBatchCoalescer:
             span if span is not None else self.FLIP_WARM_SPAN
         )
         self.flip_warms += 1
-        rec = self.recorder
-        if rec.enabled:
-            rec.record("verify.flip_warm", extra={"pending": len(self._pending)})
         if self._pending and not self._launch_inflight:
             # flush NOW even when a windowed flush is already parked in
             # its sleep: the immediate task swaps the batch out and the
@@ -863,7 +866,8 @@ class AsyncBatchCoalescer:
             except RuntimeError:
                 return
             create_logged_task(
-                self._flush_after(0.0), name="coalescer-flush-flip"
+                self._flush_after(0.0), name="coalescer-flush-flip",
+                busy=(self.recorder, "verify.flush"),
             )
             self._flush_scheduled = True
 
@@ -943,9 +947,7 @@ class AsyncBatchCoalescer:
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
         rec = self.recorder
-        if rec.enabled:
-            rec.record("verify.enqueue",
-                       extra={"items": len(items), "tag": str(tag)})
+        t_enqueue = rec.now() if rec.enabled else None
         self._tag_rates.note(tag, time.monotonic())
         async with self._lock:
             start = len(self._pending)
@@ -963,7 +965,8 @@ class AsyncBatchCoalescer:
                 pass
             elif len(self._pending) >= self.max_batch:
                 create_logged_task(
-                    self._flush_after(0.0), name="coalescer-flush-full"
+                    self._flush_after(0.0), name="coalescer-flush-full",
+                    busy=(self.recorder, "verify.flush"),
                 )
                 self._flush_scheduled = True
             elif not self._flush_scheduled:
@@ -973,9 +976,17 @@ class AsyncBatchCoalescer:
                 # launch at once
                 delay = 0.0 if self._flip_warm() else self.window
                 create_logged_task(
-                    self._flush_after(delay), name="coalescer-flush"
+                    self._flush_after(delay), name="coalescer-flush",
+                    busy=(self.recorder, "verify.flush"),
                 )
-        return await fut
+        if t_enqueue is None:
+            return await fut
+        try:
+            return await fut
+        finally:
+            # a wait: this submitter's enqueue -> its verdict resolved
+            rec.wait("verify.wait", t_enqueue,
+                     extra={"items": len(items), "tag": str(tag)})
 
     def _rung_exact(self, n: int) -> bool:
         """A wave sitting exactly on a pad-ladder rung has zero pad
@@ -997,6 +1008,8 @@ class AsyncBatchCoalescer:
             # the failover transient must not trade latency for depth
             self.flip_warm_bypasses += 1
             return
+        rec = self.recorder
+        t_hold = rec.now() if rec.enabled else None
         start = time.monotonic()
         start_depth: Optional[int] = None
         quantum = max(min(self.window, budget / 4.0), 0.001)
@@ -1036,10 +1049,9 @@ class AsyncBatchCoalescer:
                     and hasattr(self.metrics, "count_waves_held"):
                 self.metrics.count_waves_held.add(1)
                 self.metrics.count_hold_depth_gain.add(gain)
-            rec = self.recorder
             if rec.enabled:
-                rec.record("verify.hold", dur=held_s,
-                           extra={"depth_gain": gain, "expired": expired})
+                rec.wait("verify.hold", t_hold,
+                         extra={"depth_gain": gain, "expired": expired})
 
     async def _flush_after(self, delay: float) -> None:
         if delay:
@@ -1065,14 +1077,13 @@ class AsyncBatchCoalescer:
         self._launch_seq += 1
         launch_id = self._launch_seq
         rec = self.recorder
-        t_launch = time.monotonic() if rec.enabled else 0.0
+        t_launch = rec.now() if rec.enabled else None
         try:
             results = await self._launch_wave(pending)
         except Exception as exc:
             if rec.enabled:
-                rec.record("verify.launch", launch=launch_id,
-                           dur=time.monotonic() - t_launch,
-                           extra={"items": len(pending), "failed": True})
+                rec.wait("verify.launch", t_launch, launch=launch_id,
+                         extra={"items": len(pending), "failed": True})
             err = exc if isinstance(exc, VerifyPlaneDown) else RuntimeError(
                 f"batch verify failed: {exc!r}"
             )
@@ -1082,9 +1093,9 @@ class AsyncBatchCoalescer:
             await self._launch_done()
             return
         if rec.enabled:
-            rec.record("verify.launch", launch=launch_id,
-                       dur=time.monotonic() - t_launch,
-                       extra={"items": len(pending)})
+            # a wait: the wave's round trip through the launch's thread
+            rec.wait("verify.launch", t_launch, launch=launch_id,
+                     extra={"items": len(pending)})
         for fut, start, count, _tag in futures:
             if not fut.done():
                 fut.set_result(results[start : start + count])
@@ -1097,7 +1108,8 @@ class AsyncBatchCoalescer:
             if self._pending and not self._flush_scheduled:
                 self._flush_scheduled = True
                 create_logged_task(
-                    self._flush_after(0.0), name="coalescer-flush-drain"
+                    self._flush_after(0.0), name="coalescer-flush-drain",
+                    busy=(self.recorder, "verify.flush"),
                 )
 
     # -- the fault machinery -------------------------------------------------
@@ -1350,6 +1362,10 @@ class AsyncBatchCoalescer:
     def _verify_batch(self, pending: list, engine=None) -> list[bool]:
         """One engine call for the flushed batch, optionally deduplicated."""
         engine = self.engine if engine is None else engine
+        name_this_thread()
+        if self.recorder.enabled:
+            # the engine's pack / device spans on this thread carry it
+            set_thread_launch(self._launch_seq)
         if not self.dedupe:
             return self._engine_call(engine, pending)
         try:
@@ -1796,7 +1812,14 @@ class CryptoProvider:
         self, signatures: Sequence[Signature], proposal: Proposal
     ) -> list[Optional[bytes]]:
         """Async path the View prefers: coalesces with concurrent callers."""
-        auxes, items, idxs = self._collect(signatures, proposal)
+        # busy span: the loop's share of a quorum check (bind + pack items)
+        rec = self._coalescer.recorder
+        span = rec.begin("verify.collect") if rec.enabled else None
+        try:
+            auxes, items, idxs = self._collect(signatures, proposal)
+        finally:
+            if span is not None:
+                rec.end(span)
         return self._apply_mask(auxes, idxs,
                                 await self._verify_items_async(items),
                                 signatures)

@@ -252,6 +252,7 @@ def unmarshal_as(cls, data: bytes):
 from time import perf_counter as _perf_counter  # noqa: E402
 
 from .metrics import PROTOCOL_PLANE as _PLANE  # noqa: E402
+from .obs.recorder import PROCESS as _REC  # noqa: E402
 from .utils.memo import LruMemo  # noqa: E402
 
 _WIRE_MEMO_ATTR = "_wire_memo"
@@ -285,9 +286,13 @@ def wire_of(msg, plane=None) -> bytes:
     plane = _PLANE if plane is None else plane
     w = getattr(msg, _WIRE_MEMO_ATTR, None)
     if w is None:
+        # busy span: one per actual encode (once per broadcast)
+        span = _REC.begin("codec") if _REC.enabled else None
         t0 = _perf_counter()
         w = encode_tagged(msg)
         plane.codec_us += (_perf_counter() - t0) * 1e6
+        if span is not None:
+            _REC.end(span)
         plane.encodes += 1
         object.__setattr__(msg, _WIRE_MEMO_ATTR, w)
     else:
@@ -311,9 +316,16 @@ def unmarshal_interned(data: bytes, plane=None):
     if msg is not None:
         plane.decode_interned_hits += 1
         return msg
-    t0 = _perf_counter()
-    msg = decode_tagged(data)
-    plane.codec_us += (_perf_counter() - t0) * 1e6
+    # busy span: one per actual decode (an intern miss; a malformed
+    # payload raises through it)
+    span = _REC.begin("codec") if _REC.enabled else None
+    try:
+        t0 = _perf_counter()
+        msg = decode_tagged(data)
+        plane.codec_us += (_perf_counter() - t0) * 1e6
+    finally:
+        if span is not None:
+            _REC.end(span)
     plane.decodes += 1
     # the decoded object already knows its own encoding — assists and
     # forwards of an ingested message re-send without re-encoding

@@ -348,17 +348,15 @@ class ReplicaApp(Application, Assembler, Comm, Signer, Verifier,
         # flight recorder only when the spec asks (cmd=trace then serves
         # the per-replica timeline to SocketCluster / operators)
         from ..metrics import MetricsBundle, PrometheusProvider
-        from ..obs import NOP_RECORDER, TraceRecorder
+        from ..obs import TraceRecorder
 
         self.metrics_provider = PrometheusProvider()
         self.metrics = MetricsBundle(self.metrics_provider)
-        if spec.get("trace"):
-            self.recorder = TraceRecorder(
-                node=f"n{self.id}",
-                capacity=int(spec.get("trace_capacity", 2048)),
-            )
-        else:
-            self.recorder = NOP_RECORDER
+        self.recorder = TraceRecorder(
+            node=f"n{self.id}",
+            capacity=int(spec.get("trace_capacity", 2048)),
+            enabled=bool(spec.get("trace")),
+        )
         self.transport.recorder = self.recorder
         # cluster health plane (ISSUE 14): every replica judges itself
         # against the declarative SLO spec on a periodic tick; cmd=health

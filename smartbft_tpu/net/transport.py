@@ -238,10 +238,10 @@ class SocketComm(Comm):
         self.plane = PROTOCOL_PLANE if plane is None else plane
         self.metrics = TransportMetrics()
         # flight recorder for control-plane transitions (reconnects);
-        # the embedder swaps in a real obs.TraceRecorder when tracing
-        from ..obs.recorder import NOP_RECORDER
+        # the embedder swaps in its replica's recorder
+        from ..obs.recorder import standby
 
-        self.recorder = NOP_RECORDER
+        self.recorder = standby(node=f"n{self_id}")
         #: optional embedder hook mapping raw request bytes -> the request
         #: key ("client:rid") so FT_TRACE sidecars carry the SAME
         #: correlator the flight recorder stamps on req.submit/req.deliver

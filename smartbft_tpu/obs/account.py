@@ -1,0 +1,141 @@
+"""The account of one on-interval: where the host's time went.
+
+:func:`assemble_account` folds the recorders the profiler switched on
+into ONE plain dict — what :func:`smartbft_tpu.obs.last_summary` returns
+and every ``chipbench/layer_metrics`` reader of this account imports.
+Pure function over recorders' events and the running busy sums; touches
+no JAX.
+
+The interval is the switch's own: from the tick that first saw the
+profiler on to the last tick that still saw it on (``stop_trace`` blocks
+the loop thread while it writes, so the tick that sees it off comes
+late).  Busy spans that ended after that last tick are taken back out of
+the running sums, so the sums, the loop thread's CPU and the counts all
+cover the same span of time.
+
+=====================  ====================================================
+``interval``           ``t0``, ``t1`` (perf_counter), ``wall_s``, ``ticks``
+``loop``               the loop thread: ``thread``, ``cpu_s`` (``sys_s`` of it
+                       in the kernel), ``busy_self_s``
+``busy``               thread -> kind -> ``calls``, ``self_s``, ``dur_s``,
+                       ``cpu_s`` (thread CPU, where the span reads it);
+                       kind ``gc`` is the interpreter's collections
+``counters``           ``decisions`` (delivered by the replica that
+                       proposed them), ``requests_proposed``, ``launches``,
+                       ``signatures``, ``fsync_waves``
+``segments``           segment -> ms per decision (:func:`decision_rows`)
+``decisions``          the rows themselves: ``view``, ``seq``, ``node``,
+                       ``total_ms`` and one ms value per segment
+``waits``              wait kind -> ms per wait; ``pool.wait`` and
+                       ``req.total`` per request delivered by its proposer
+``durations``          ``wal.fsync`` -> ms per fsync (a busy span on the
+                       executor thread that ran the wave)
+``recorders``, ``recorded``, ``dropped``
+``refused``            kind -> busy spans dropped for containing an await
+=====================  ====================================================
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Sequence
+
+from .critpath import DECISION_SEGMENTS, decision_rows
+
+__all__ = ["assemble_account"]
+
+#: wait kinds recorded as such, taken as they are
+_WAIT_KINDS = ("verify.wait", "verify.hold", "wal.persist")
+
+
+def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
+                     t1: float, loop_cpu_s: float, loop_thread: str,
+                     loop_sys_s: float = 0.0, ticks: int = 0,
+                     refused: Optional[dict] = None,
+                     collections: Sequence = ()) -> dict:
+    """See the module docstring.  ``busy``: thread -> kind -> ``[calls,
+    self_s, dur_s, cpu_s]``, the running sums at the off edge;
+    ``collections``: ``(thread, end, seconds, generation)`` per garbage
+    collection while on."""
+    events = [e for r in recorders for e in r.events()]
+    busy = {th: {k: list(v) for k, v in kinds.items()}
+            for th, kinds in busy.items()}
+    inside = []
+    for e in events:
+        if e.t <= t1:
+            inside.append(e)
+        elif e.self_s >= 0.0:
+            acc = busy.get(e.thread, {}).get(e.kind)
+            if acc is not None:
+                acc[0] -= 1
+                acc[1] -= e.self_s
+                acc[2] -= e.dur
+                acc[3] -= ((e.extra or {}).get("cpu_ms", 0.0)) / 1e3
+    for thread, t_end, dur, _gen in collections:
+        if t_end <= t1:  # garbage collections: busy time of kind ``gc``
+            acc = busy.setdefault(thread, {}).setdefault(
+                "gc", [0, 0.0, 0.0, 0.0])
+            acc[0] += 1
+            acc[1] += dur
+            acc[2] += dur
+    rows = decision_rows(inside)
+    proposed = {(r["node"], r["view"], r["seq"]): r["t"] for r in rows}
+    counters = {"decisions": 0, "requests_proposed": 0, "launches": 0,
+                "signatures": 0, "fsync_waves": 0}
+    waits: dict = {k: [] for k in _WAIT_KINDS}
+    submits: dict = {}
+    delivered = []
+    fsync_ms: list = []
+    for e in inside:
+        kind = e.kind
+        if kind == "decision.deliver":
+            if (e.extra or {}).get("proposer"):
+                counters["decisions"] += 1
+        elif kind == "batch.propose":
+            counters["requests_proposed"] += (e.extra or {}).get("count", 0)
+        elif kind == "verify.device":
+            counters["launches"] += 1
+        elif kind == "vote.sign":
+            counters["signatures"] += 1
+        elif kind == "wal.fsync":
+            counters["fsync_waves"] += 1
+            fsync_ms.append(e.dur * 1e3)
+        elif kind == "req.submit":
+            submits.setdefault((e.node, e.key), e.t)
+        elif kind == "req.deliver":
+            delivered.append(e)
+        if kind in waits and e.dur >= 0.0:
+            waits[kind].append(e.dur * 1e3)
+    pool_wait, total = [], []
+    for e in delivered:  # the proposing replica's only
+        t_submit = submits.get((e.node, e.key))
+        t_propose = proposed.get((e.node, e.view, e.seq))
+        if t_submit is None or t_propose is None:
+            continue
+        pool_wait.append((t_propose - t_submit) * 1e3)
+        total.append((e.t - t_submit) * 1e3)
+    waits["pool.wait"] = pool_wait
+    waits["req.total"] = total
+    return {
+        "interval": {"t0": t0, "t1": t1, "wall_s": t1 - t0, "ticks": ticks},
+        "loop": {
+            "thread": loop_thread,
+            "cpu_s": loop_cpu_s,
+            "sys_s": loop_sys_s,
+            "busy_self_s": sum(v[1] for v in
+                               busy.get(loop_thread, {}).values()),
+        },
+        "busy": {th: {k: {"calls": v[0], "self_s": v[1], "dur_s": v[2],
+                          "cpu_s": v[3]}
+                      for k, v in sorted(kinds.items())}
+                 for th, kinds in sorted(busy.items())},
+        "counters": counters,
+        "segments": {seg: [r[seg] for r in rows]
+                     for seg in DECISION_SEGMENTS},
+        "decisions": rows,
+        "waits": waits,
+        "durations": {"wal.fsync": fsync_ms},
+        "recorders": len(recorders),
+        "recorded": sum(r.recorded for r in recorders),
+        "dropped": sum(r.dropped for r in recorders),
+        "refused": dict(refused or {}),
+    }

@@ -22,7 +22,7 @@ Mark vocabulary (flight-recorder event kinds):
 
 ==================  =====================================================
 ``req.submit``      front-door entry (pool.submit, pre-admission)
-``req.pool``        pooled (admission/park wait ended)
+``req.pool``        pooled after parking (absent for an immediate add)
 ``batch.propose``   the leader assembled the batch containing it
 ``quorum.prepare``  prepare quorum completed (extra.slowest_voter = the
                     node whose vote completed it)
@@ -43,7 +43,8 @@ from typing import Optional, Sequence
 
 from .recorder import pct as _pct
 
-__all__ = ["SEGMENTS", "assemble_critical_path_block"]
+__all__ = ["SEGMENTS", "DECISION_MARKS", "DECISION_SEGMENTS",
+           "assemble_critical_path_block", "decision_rows"]
 
 #: canonical mark order along the request pipeline
 _MARKS = ("submit", "pool", "propose", "prepare_quorum", "wal_persist",
@@ -306,3 +307,48 @@ def assemble_critical_path_block(
             for p, prows in by_phase.items()
         }
     return block
+
+
+# -- per decision, on the proposer's own timeline --------------------------------
+
+#: the per-decision marks along the commit path, in order, and the
+#: segment that ENDS at each (the interval since the previous mark)
+DECISION_MARKS = (
+    ("batch.propose", None),
+    ("quorum.prepare", "prepare_wave"),
+    ("wal.persist", "wal_persist"),
+    ("quorum.commit", "commit_wave"),
+    ("decision.deliver", "deliver"),
+)
+DECISION_SEGMENTS = tuple(seg for _, seg in DECISION_MARKS if seg)
+
+def decision_rows(events: Sequence) -> list[dict]:
+    """One row per decision from the marks of the replica that recorded
+    its ``batch.propose``: the segments are the deltas between consecutive
+    marks on that replica's own timeline, so they sum to ``total_ms``
+    (``batch.propose`` -> ``decision.deliver``) exactly.  A decision that
+    lacks a mark (proposed before the recorder came on, or not delivered
+    yet) has no row.  ``events``: objects with ``kind``, ``node``,
+    ``view``, ``seq``, ``t`` (:class:`~smartbft_tpu.obs.SpanEvent`)."""
+    kinds = {k for k, _ in DECISION_MARKS}
+    marks: dict = {}
+    for e in events:
+        if e.kind in kinds and e.seq >= 0:
+            # first of its kind wins: an assist or a re-broadcast after a
+            # view restart re-records a mark later, off the critical path
+            marks.setdefault((e.node, e.view, e.seq), {}).setdefault(
+                e.kind, e.t)
+    rows = []
+    for (node, view, seq), got in marks.items():
+        if len(got) != len(DECISION_MARKS):
+            continue
+        row = {"node": node, "view": view, "seq": seq,
+               "t": got["batch.propose"]}
+        prev = got["batch.propose"]
+        for kind, seg in DECISION_MARKS[1:]:
+            row[seg] = (got[kind] - prev) * 1e3
+            prev = got[kind]
+        row["total_ms"] = (prev - got["batch.propose"]) * 1e3
+        rows.append(row)
+    rows.sort(key=lambda r: r["t"])
+    return rows

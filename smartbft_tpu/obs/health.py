@@ -33,7 +33,7 @@ from __future__ import annotations
 import time
 from typing import Callable, Optional, Sequence
 
-from .recorder import NOP_RECORDER
+from .recorder import standby
 from .slo import (
     CRITICAL,
     DEGRADED,
@@ -306,7 +306,7 @@ class HealthMonitor:
         self._clock = clock if clock is not None else time.monotonic
         self.spec = spec if spec is not None else default_slo_spec()
         self.node = node
-        self.recorder = recorder if recorder is not None else NOP_RECORDER
+        self.recorder = standby(recorder)
         self.evaluator = SLOEvaluator(self.spec, clock=self._clock)
         self._sources: list[Callable[[], dict]] = []
         self.source_errors = 0

@@ -37,7 +37,7 @@ from collections import deque
 from typing import Optional, Sequence
 
 from ..metrics import LogScaleHistogram
-from .recorder import NOP_RECORDER, pct as _pct
+from .recorder import pct as _pct, standby
 
 __all__ = ["ViewChangePhaseTracker", "assemble_viewchange_block"]
 
@@ -64,7 +64,7 @@ class ViewChangePhaseTracker:
                  metrics=None, keep: int = 64):
         self._clock = clock if clock is not None else time.monotonic
         self.node = node
-        self.recorder = recorder if recorder is not None else NOP_RECORDER
+        self.recorder = standby(recorder)
         #: optional ViewChangeMetrics bundle — the time-in-view-change
         #: gauge and round counter feed it so Prometheus/statsd see VC
         #: health without the trace enabled

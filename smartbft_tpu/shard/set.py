@@ -72,6 +72,7 @@ from ..core.pool import (
     SubmitTimeoutError,
 )
 from ..metrics import CommitLatencyTracker
+from ..obs.recorder import close_for_await
 from ..utils.tasks import create_logged_task
 
 __all__ = ["ShardHandle", "ShardSet"]
@@ -270,10 +271,10 @@ class ShardSet:
         #: ``poll_committed`` resolves them against the combined stream
         self.latency = CommitLatencyTracker(clock=clock)
         #: flight recorder for control-plane transitions (reshard epochs);
-        #: the nop singleton when tracing is off (obs.recorder contract)
-        from ..obs.recorder import NOP_RECORDER
+        #: disabled unless tracing (obs.recorder contract)
+        from ..obs.recorder import standby
 
-        self.recorder = recorder if recorder is not None else NOP_RECORDER
+        self.recorder = standby(recorder)
         self._epoch = self.router.epoch
         self._next_epoch = self._epoch + 1
         self._transition: Optional[_Transition] = None
@@ -414,6 +415,18 @@ class ShardSet:
         # stamp keeps measuring from the first submit, and a failure of
         # THIS attempt must not erase it (the pending request still
         # commits) — dedup/shed handling below keys off `fresh`
+        # busy span: the router and the pool's submit up to the first
+        # await (a submit that parks closes it there, close_for_await)
+        rec = self.recorder
+        span = rec.begin("front.submit") if rec.enabled else None
+        try:
+            return await self._submit(client_id, raw_request, request_key)
+        finally:
+            if span is not None:
+                rec.end(span)
+
+    async def _submit(self, client_id, raw_request: bytes,
+                      request_key: Optional[str]) -> int:
         fresh = (self.latency.on_submitted(request_key)
                  if request_key is not None else False)
         try:
@@ -421,6 +434,7 @@ class ShardSet:
             if tr is not None and tr.moved(self.router, client_id):
                 tr.parked += 1
                 tr.parked_peak = max(tr.parked_peak, tr.parked)
+                close_for_await()
                 try:
                     await self._wait_for_flip(tr)
                 finally:
@@ -587,6 +601,15 @@ class ShardSet:
         by earlier polls are applied by contract; everything beyond the
         ``retention`` window below that watermark is dropped, so long
         soaks do not grow mux memory with history)."""
+        rec = self.recorder
+        span = rec.begin("front.poll") if rec.enabled else None
+        try:
+            return self._poll_committed()
+        finally:
+            if span is not None:
+                rec.end(span)
+
+    def _poll_committed(self) -> list:
         start = self.mux.total()
         for sid in sorted(self.shards):
             pos = self._chain_pos[sid]

@@ -60,6 +60,7 @@ from ..messages import (
     wire_of,
 )
 from ..metrics import PROTOCOL_PLANE, install_plane, reset_plane
+from ..obs.recorder import PROCESS as _REC
 from ..utils.tasks import create_logged_task
 
 INCOMING_BUFFER = 1000  # network.go:18-20
@@ -68,17 +69,25 @@ INCOMING_BUFFER = 1000  # network.go:18-20
 def _marshal_timed(msg: Message, plane) -> bytes:
     """Plain (un-memoized) encode with codec accounting — the naive plane's
     per-recipient cost, and the path mutated (per-target) copies take."""
+    span = _REC.begin("codec") if _REC.enabled else None
     t0 = perf_counter()
     w = marshal(msg)
     plane.codec_us += (perf_counter() - t0) * 1e6
+    if span is not None:
+        _REC.end(span)
     plane.encodes += 1
     return w
 
 
 def _unmarshal_timed(data: bytes, plane) -> Message:
-    t0 = perf_counter()
-    m = unmarshal(data)
-    plane.codec_us += (perf_counter() - t0) * 1e6
+    span = _REC.begin("codec") if _REC.enabled else None
+    try:
+        t0 = perf_counter()
+        m = unmarshal(data)
+        plane.codec_us += (perf_counter() - t0) * 1e6
+    finally:
+        if span is not None:
+            _REC.end(span)
     plane.decodes += 1
     return m
 
@@ -116,6 +125,8 @@ class Node:
             self._serve(),
             name=f"netnode-{self.id}" if self.group == 0
             else f"netnode-g{self.group}-{self.id}",
+            # each drained batch's decode + dispatch is one busy span
+            busy=(_REC, "net.ingest"),
         )
 
     async def stop(self) -> None:
@@ -419,6 +430,16 @@ class Network:
         plane.broadcasts += 1
         if src.muted:
             return  # outbound silence: nothing leaves, nothing encodes
+        # busy span: one per fan-out (the encode inside it is a codec span)
+        span = _REC.begin("net.route") if _REC.enabled else None
+        try:
+            self._fan_out(gmap, src, source, msg, targets, plane)
+        finally:
+            if span is not None:
+                _REC.end(span)
+
+    def _fan_out(self, gmap, src, source: int, msg: Message,
+                 targets: Optional[list[int]], plane) -> None:
         t0 = perf_counter()
         codec0 = plane.codec_us
         wire: Optional[bytes] = None

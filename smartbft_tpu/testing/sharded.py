@@ -387,21 +387,20 @@ class ShardedCluster:
         # flight recorder (ISSUE 12): one bounded TraceRecorder per
         # replica (keyed "s<shard>n<node>") plus one for the shared
         # verify plane and one for the set's control plane, all on the
-        # cluster's injectable clock.  trace=False keeps every component
-        # on the nop recorder — the hot path pays one attribute read.
+        # cluster's injectable clock.  trace=False builds them disabled —
+        # the hot path pays one attribute read — until a profiler session
+        # switches them on (obs.poll_profiler).
         self.trace = trace
         self._recorders: dict[str, object] = {}
 
         def recorder_for(label: str):
-            if not trace:
-                return None
             from ..obs import TraceRecorder
 
             rec = self._recorders.get(label)
             if rec is None:
                 rec = self._recorders[label] = TraceRecorder(
                     clock=self.scheduler.now, node=label,
-                    capacity=trace_capacity,
+                    capacity=trace_capacity, enabled=trace,
                 )
             return rec
 
@@ -482,8 +481,7 @@ class ShardedCluster:
         else:
             raise ValueError(f"unknown crypto mode {crypto!r}")
 
-        if trace:
-            self.coalescer.attach_recorder(recorder_for("verify"))
+        self.coalescer.attach_recorder(recorder_for("verify"))
         cfg = config_fn or (
             lambda s, i: sharded_config(i, depth=depth, rotation=rotation)
         )
@@ -712,9 +710,11 @@ class ShardedCluster:
     # -- flight recorder (ISSUE 12) ----------------------------------------
 
     def trace_recorders(self) -> list:
-        """Every live recorder (per-replica + shared-plane), or [] when
-        the cluster was built without ``trace=True``."""
-        return list(self._recorders.values())
+        """Every recorder (per-replica + shared-plane) that is on or has
+        recorded; [] for a cluster built without ``trace=True`` that no
+        profiler session switched on."""
+        return [r for r in self._recorders.values()
+                if r.enabled or r.recorded]
 
     def trace_block(self) -> dict:
         """The merged ``trace`` bench-row block (pure assemble helper)."""
@@ -763,4 +763,5 @@ class ShardedCluster:
         return [
             rec.dump_to(os.path.join(out_dir, f"flight-{label}.json"))
             for label, rec in sorted(self._recorders.items())
+            if rec.enabled or rec.recorded
         ]

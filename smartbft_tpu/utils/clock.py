@@ -20,6 +20,7 @@ import itertools
 import time
 from typing import Awaitable, Callable, Optional
 
+from ..obs.recorder import PROCESS as _REC, poll_profiler
 from .tasks import create_logged_task
 
 
@@ -75,17 +76,24 @@ class Scheduler:
         if t < self._now:
             t = self._now
         fired = 0
-        # advance logical time task-by-task so callbacks that reschedule
-        # (tickers) observe the correct "now" — a single large jump must
-        # fire a periodic task once per period, not once per jump
-        while self._heap and self._heap[0].deadline <= t:
-            task = heapq.heappop(self._heap)
-            if task.cancelled:
-                continue
-            if task.deadline > self._now:
-                self._now = task.deadline
-            fired += 1
-            task._callback()
+        span = _REC.begin("tick") if _REC.enabled and self._heap \
+            and self._heap[0].deadline <= t else None
+        try:
+            # advance logical time task-by-task so callbacks that
+            # reschedule (tickers) observe the correct "now" — a single
+            # large jump must fire a periodic task once per period, not
+            # once per jump
+            while self._heap and self._heap[0].deadline <= t:
+                task = heapq.heappop(self._heap)
+                if task.cancelled:
+                    continue
+                if task.deadline > self._now:
+                    self._now = task.deadline
+                fired += 1
+                task._callback()
+        finally:
+            if span is not None:
+                _REC.end(span)
         self._now = t
         return fired
 
@@ -164,6 +172,8 @@ class WallClockDriver:
                 await asyncio.wait_for(self._stop.wait(), timeout=self._tick_interval)
             except asyncio.TimeoutError:
                 pass
+            # the flight recorder's one switch: follow the profiler session
+            poll_profiler()
             self._scheduler.advance_to(base_logical + (time.monotonic() - base_wall))
 
     def start(self) -> None:

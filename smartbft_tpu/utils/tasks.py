@@ -17,8 +17,13 @@ import asyncio
 from typing import Optional
 
 
-def create_logged_task(coro, *, name: str, logger=None) -> asyncio.Task:
+def create_logged_task(coro, *, name: str, logger=None,
+                       busy: Optional[tuple] = None) -> asyncio.Task:
     """``loop.create_task`` + an exception-logging done-callback.
+
+    ``busy``: ``(recorder, kind)`` — account each step of the task as a
+    busy span of that kind while the recorder is on
+    (:class:`~smartbft_tpu.obs.recorder.busy_steps`).
 
     ``logger`` is any object with ``errorf`` (the project Logger SPI);
     None falls back to a module StdLogger so even logger-less contexts
@@ -32,6 +37,10 @@ def create_logged_task(coro, *, name: str, logger=None) -> asyncio.Task:
     (every task death is logged, auditable by tests/test_task_audit.py)
     is worth more than deduplicated error output.
     """
+    if busy is not None:
+        from ..obs.recorder import busy_steps
+
+        coro = busy_steps(coro, *busy)
     task = asyncio.get_running_loop().create_task(coro, name=name)
 
     def _observe(t: asyncio.Task) -> None:

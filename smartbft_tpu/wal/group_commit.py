@@ -33,6 +33,7 @@ import logging
 import weakref
 from typing import Protocol
 
+from ..obs.recorder import PROCESS as _REC
 from ..utils.tasks import create_logged_task
 
 
@@ -72,7 +73,11 @@ class GroupCommitScheduler:
         self._pending.setdefault(wal, []).append(fut)
         self.syncs_requested += 1
         if self._task is None or self._task.done():
-            self._task = create_logged_task(self._drain(), name="wal-group-commit")
+            self._task = create_logged_task(
+                self._drain(), name="wal-group-commit",
+                # the loop's side of a wave: hand-off and futures resolved
+                busy=(_REC, "wal.flush"),
+            )
         return fut
 
     async def _drain(self) -> None:

@@ -231,14 +231,14 @@ class WriteAheadLogFile(WriteAheadLog):
         self._log = logger or StdLogger("smartbft.wal")
         self._file_size_bytes = file_size_bytes
         self._metrics = metrics or WALMetrics()
-        # flight recorder (obs.TraceRecorder; nop singleton by default):
-        # wal.append / wal.fsync span events when the embedder's Consensus
-        # attaches its recorder (attach_recorder).  Record() under the GIL
-        # is safe from the group-commit executor thread; the ring tolerates
-        # interleaving (telemetry, never state).
-        from ..obs.recorder import NOP_RECORDER
+        # flight recorder (obs.TraceRecorder, disabled by default):
+        # wal.append / wal.fsync busy spans when the embedder's Consensus
+        # attaches its recorder (attach_recorder) and it is on.  Recording
+        # is safe from the group-commit executor thread (telemetry, never
+        # state).
+        from ..obs.recorder import standby
 
-        self._recorder = NOP_RECORDER
+        self._recorder = standby()
         self._lock = threading.RLock()
         self._f: Optional[BinaryIO] = None
         self._index = 0
@@ -349,7 +349,7 @@ class WriteAheadLogFile(WriteAheadLog):
 
     def attach_recorder(self, recorder) -> None:
         """Arm the persistence spans: wal.append / wal.fsync events land
-        in ``recorder`` (an obs.TraceRecorder; None keeps the nop)."""
+        in ``recorder`` (an obs.TraceRecorder; None keeps the WAL's own disabled one)."""
         if recorder is not None:
             self._recorder = recorder
 
@@ -367,17 +367,21 @@ class WriteAheadLogFile(WriteAheadLog):
         the fsync so the fd cannot rotate/close out from under it (loop-side
         contention is bounded by one ~100 us fsync — the price the inline
         path paid on every single append)."""
+        rec = self._recorder
         with self._lock:
             if self._closed or self._f is None or not self._dirty:
                 return  # already durable (rotation/close fsyncs before moving on)
-            t0 = perf_counter()
-            os.fsync(self._f.fileno())
-            self._dirty = False
-            dur = perf_counter() - t0
+            # busy span on the executor thread that runs the wave
+            span = rec.begin("wal.fsync") if rec.enabled else None
+            try:
+                t0 = perf_counter()
+                os.fsync(self._f.fileno())
+                self._dirty = False
+                dur = perf_counter() - t0
+            finally:
+                if span is not None:
+                    rec.end(span)
         self._metrics.fsync_hist.observe(dur)
-        rec = self._recorder
-        if rec.enabled:
-            rec.record("wal.fsync", dur=dur)
 
     def truncate_to(self) -> None:
         """Append a CONTROL record marking a truncation point
@@ -435,6 +439,20 @@ class WriteAheadLogFile(WriteAheadLog):
             return self._crc
 
     def _append_record(self, rec: LogRecord, sync: bool = True) -> None:
+        # one busy span per append op; a synchronous append's span
+        # INCLUDES its inline fsync (the native path fuses them), an async
+        # one is write-only — the deferred fsync lands as wal.fsync
+        recorder = self._recorder
+        span = recorder.begin(
+            "wal.append", extra={"sync": True} if sync else None,
+        ) if recorder.enabled else None
+        try:
+            self._append_timed(rec, sync)
+        finally:
+            if span is not None:
+                recorder.end(span)
+
+    def _append_timed(self, rec: LogRecord, sync: bool) -> None:
         t0 = perf_counter()
         with self._lock:
             if self._closed:
@@ -467,15 +485,7 @@ class WriteAheadLogFile(WriteAheadLog):
             # switch if this or the next (>=16B) record could overflow
             if self._f.tell() > self._file_size_bytes - 16:
                 self._switch_files()
-        dur = perf_counter() - t0
-        self._metrics.append_hist.observe(dur)
-        recorder = self._recorder
-        if recorder.enabled:
-            # one span per append op; a synchronous append's dur INCLUDES
-            # its inline fsync (the native path fuses them), an async one
-            # is write-only — the deferred fsync lands as wal.fsync
-            recorder.record("wal.append", dur=dur,
-                            extra={"sync": True} if sync else None)
+        self._metrics.append_hist.observe(perf_counter() - t0)
 
     def _write_anchor(self) -> None:
         """CRC_ANCHOR frame carrying the chain value (writeaheadlog.go:716-757)."""

@@ -58,6 +58,7 @@ def _compiles(fn, one_chip, shapes, **static):
             for shape, dtype in shapes]
     text = fn.lower(*args, **static).compile().as_text()
     assert "tpu_custom_call" in text  # the Mosaic kernel, not a fallback
+    return text
 
 
 def _lanes(lanes, operands):
@@ -77,7 +78,15 @@ def _tables(nkeys):
 ], ids=["n64-wave", "cap-keys"])
 def test_comb_p256_compiles_for_v5e(one_chip, lanes, nkeys):
     shapes = _lanes(lanes, 3) + [((lanes,), jnp.int32)] + _tables(nkeys)
-    _compiles(pallas_comb.ecdsa_verify_comb, one_chip, shapes, tile=128)
+    text = _compiles(pallas_comb.ecdsa_verify_comb, one_chip, shapes, tile=128)
+    # the two names the benchmark's device readers depend on: the XLA
+    # module's (comb_us_per_sig matches "comb" in it) and the custom
+    # call's (the ledger's device_ops breakdown, and the check that every
+    # such device event lies inside a tpubft.verify.device span)
+    module = text.split("\n", 1)[0]
+    assert module.startswith("HloModule") and "comb" in module, module
+    assert any("custom-call" in line and "ecdsa_verify_comb" in line
+               for line in text.splitlines())
 
 
 def test_comb_ed25519_compiles_for_v5e(one_chip):

@@ -23,7 +23,6 @@ from smartbft_tpu.net.framing import (
     encode_frame,
 )
 from smartbft_tpu.obs import (
-    NOP_RECORDER,
     TraceRecorder,
     ViewChangePhaseTracker,
     assemble_viewchange_block,
@@ -84,7 +83,7 @@ def test_events_since_survives_ring_wrap_and_future_cursor():
     assert [e.seq for e in events] == [6, 7, 8, 9] and cur == 10
     # a stale/future cursor stays put at "nothing new", never negatives
     assert rec.events_since(99) == ([], 99)
-    assert NOP_RECORDER.events_since(0) == ([], 0)
+    assert TraceRecorder(enabled=False).events_since(0) == ([], 0)
     # the exact-seqno contract: events carry their own all-time sequence,
     # so a snapshot racing a concurrent record can never skip or
     # double-ship (the WAL-executor-thread hazard)

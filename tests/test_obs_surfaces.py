@@ -220,7 +220,7 @@ def test_socket_cluster_trace_and_metrics_pull(tmp_path):
         resp = cluster.trace_pull(leader)
         assert resp["trace"]["enabled"] is True
         kinds = {e["kind"] for e in resp["events"]}
-        assert "req.pool" in kinds and "req.deliver" in kinds
+        assert "req.submit" in kinds and "req.deliver" in kinds
         tail = cluster.trace_pull(leader, last=2)["events"]
         assert len(tail) == 2
 
