@@ -1727,9 +1727,11 @@ class CryptoProvider:
         ``digest``: the proposal's digest if the caller already computed it
         — hashing a batch-sized proposal costs ~50 us, and quorum
         validation checks one proposal against dozens of signatures.  The
-        sig-msg decode is memoized: every replica sharing this provider's
-        process re-checks the same wire bytes (~42k decodes per n=64 bench
-        run before the memo)."""
+        sig-msg decode is memoized per provider (``_sig_msg_memo``, one
+        LRU of 8192 entries for each replica's own provider, nothing
+        shared between the replicas of a process): a replica re-checks
+        the same wire bytes on every path that validates a quorum (~42k
+        decodes per n=64 bench run before the memo)."""
         decoded = self._sig_msg_memo.get_or(
             signature.msg, lambda: decode(ConsenterSigMsg, signature.msg)
         )

@@ -20,6 +20,10 @@ cover the same span of time.
 ``busy``               thread -> kind -> ``calls``, ``self_s``, ``dur_s``,
                        ``cpu_s`` (thread CPU, where the span reads it);
                        kind ``gc`` is the interpreter's collections
+``collector``          ``passes``: ``count`` and ``seconds`` of the
+                       collections by generation (0, 1, 2; 2 is a full
+                       pass), ``frozen`` (``gc.get_freeze_count()``) and
+                       ``thresholds`` in force at the off edge
 ``counters``           ``decisions`` (delivered by the replica that
                        proposed them), ``requests_proposed``, ``launches``,
                        ``signatures``, ``fsync_waves``
@@ -51,11 +55,13 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
                      t1: float, loop_cpu_s: float, loop_thread: str,
                      loop_sys_s: float = 0.0, ticks: int = 0,
                      refused: Optional[dict] = None,
-                     collections: Sequence = ()) -> dict:
+                     collections: Sequence = (), frozen: int = 0,
+                     thresholds: Sequence = ()) -> dict:
     """See the module docstring.  ``busy``: thread -> kind -> ``[calls,
     self_s, dur_s, cpu_s]``, the running sums at the off edge;
     ``collections``: ``(thread, end, seconds, generation)`` per garbage
-    collection while on."""
+    collection while on; ``frozen`` and ``thresholds``: the collector's
+    freeze count and thresholds at the off edge."""
     events = [e for r in recorders for e in r.events()]
     busy = {th: {k: list(v) for k, v in kinds.items()}
             for th, kinds in busy.items()}
@@ -70,13 +76,16 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
                 acc[1] -= e.self_s
                 acc[2] -= e.dur
                 acc[3] -= ((e.extra or {}).get("cpu_ms", 0.0)) / 1e3
-    for thread, t_end, dur, _gen in collections:
+    passes = [{"count": 0, "seconds": 0.0} for _ in range(3)]
+    for thread, t_end, dur, gen in collections:
         if t_end <= t1:  # garbage collections: busy time of kind ``gc``
             acc = busy.setdefault(thread, {}).setdefault(
                 "gc", [0, 0.0, 0.0, 0.0])
             acc[0] += 1
             acc[1] += dur
             acc[2] += dur
+            passes[gen]["count"] += 1
+            passes[gen]["seconds"] += dur
     rows = decision_rows(inside)
     proposed = {(r["node"], r["view"], r["seq"]): r["t"] for r in rows}
     counters = {"decisions": 0, "requests_proposed": 0, "launches": 0,
@@ -128,6 +137,8 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
                           "cpu_s": v[3]}
                       for k, v in sorted(kinds.items())}
                  for th, kinds in sorted(busy.items())},
+        "collector": {"passes": passes, "frozen": frozen,
+                      "thresholds": list(thresholds)},
         "counters": counters,
         "segments": {seg: [r[seg] for r in rows]
                      for seg in DECISION_SEGMENTS},

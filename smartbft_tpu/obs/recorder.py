@@ -769,7 +769,8 @@ def _switch_off() -> None:
         loop_thread=sw.loop_thread, ticks=sw.ticks,
         refused=refused,
         collections=[(_name_of(st), t, dur, gen)
-                     for st, t, dur, gen in list(_gc_log)])
+                     for st, t, dur, gen in list(_gc_log)],
+        frozen=gc.get_freeze_count(), thresholds=gc.get_threshold())
 
 
 def last_summary() -> Optional[dict]:

@@ -73,6 +73,7 @@ from ..core.pool import (
 )
 from ..metrics import CommitLatencyTracker
 from ..obs.recorder import close_for_await
+from ..utils import gchold
 from ..utils.tasks import create_logged_task
 
 __all__ = ["ShardHandle", "ShardSet"]
@@ -277,6 +278,8 @@ class ShardSet:
         self.recorder = standby(recorder)
         self._epoch = self.router.epoch
         self._next_epoch = self._epoch + 1
+        #: True between start() and stop(): this host's gchold.hold()
+        self._gc_held = False
         self._transition: Optional[_Transition] = None
         self.reshard_stats: dict = {"transitions": 0, "aborts": 0,
                                     "last": None}
@@ -370,12 +373,22 @@ class ShardSet:
     async def start(self) -> None:
         for s in sorted(self.shards):
             await self.shards[s].start()
+        # the set-up heap is complete: take it out of the collector's
+        # sight for as long as this host runs (utils/gchold.py)
+        if not self._gc_held:
+            gchold.hold()
+            self._gc_held = True
 
     async def stop(self) -> None:
-        for s in sorted(self.shards):
-            await self.shards[s].stop()
-        if self.journal is not None:
-            self.journal.close()
+        try:
+            for s in sorted(self.shards):
+                await self.shards[s].stop()
+            if self.journal is not None:
+                self.journal.close()
+        finally:
+            if self._gc_held:
+                self._gc_held = False
+                gchold.release()
 
     # -- the front door ----------------------------------------------------
 
