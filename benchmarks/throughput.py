@@ -118,37 +118,9 @@ def build_engine(kind: str, pad_sizes, scheme, n_nodes: int = 4):
     raise ValueError(f"unknown engine {kind}")
 
 
-def auto_pad_sizes(n: int, scheme_name: str = "p256",
-                   pipeline: int = 1) -> tuple:
-    """The pad ladder for an n-replica cluster behind ONE shared engine:
-    one decision's quorum wave coalesces into ONE launch with near-full
-    lanes, and the coalescer's max_batch trigger fires the moment the wave
-    completes instead of waiting the window out."""
-    import inspect
-
-    from smartbft_tpu.crypto.provider import JaxVerifyEngine
-
-    quorum = (n + (n - 1) // 3 + 1 + 1) // 2  # util.go:176-180
-    # the shared engine's per-decision wave: every replica checks its
-    # quorum; BLS collapses each check to ONE aggregated pairing lane
-    wave = n if scheme_name == "bls" else n * (quorum - 1)
-    # top rung = the wave rounded up to a 128-lane Mosaic block (n=64:
-    # 2688 exactly — the power-of-two ladder padded it to 4096, wasting
-    # ~34% of every launch); smaller rungs come from the production
-    # engine's default ladder so bench shapes match deployed shapes
-    block = 8 if scheme_name == "bls" else 128
-    top = min(-(-wave // block) * block, 16384)
-    defaults = inspect.signature(JaxVerifyEngine).parameters[
-        "pad_sizes"].default
-    rungs = {s for s in defaults if s < top} | {top}
-    if pipeline > 1:
-        # deduped steady-state launch for a full window train: one
-        # distinct signature per replica per decision, and under the
-        # launch shadow up to 2k decisions' waves can sit in one
-        # coalesced flush -> k*n and 2k*n lanes
-        rungs |= {min(-(-(k * n) // block) * block, 16384)
-                  for k in (pipeline, 2 * pipeline)}
-    return tuple(sorted(rungs))
+# moved into the library (PR 28); re-exported for the callers that import
+# it from here (chip_smoke.py, chipbench/deployments/sharded.py)
+from smartbft_tpu.crypto.ladder import auto_pad_sizes  # noqa: E402,F401
 
 
 def bench_keyrings(n: int, scheme) -> dict:

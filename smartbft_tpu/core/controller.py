@@ -201,6 +201,12 @@ class Controller(RequestTimeoutHandler):
         # (1-slot, like the token: at most one assembly in flight)
         self._assembly_task: Optional[asyncio.Task] = None
         self._fwd_submit_failures = 0  # throttled warn counter (handle_request)
+        #: the verifier's awaited request check — present only where the
+        #: App verifies signed envelopes (it holds enrolled identities):
+        #: one envelope through the shared coalescer, raises on a refusal.
+        #: Absent, requests take the synchronous SPI path they always took.
+        self._check_request = getattr(verifier, "verify_request_async", None)
+        self.bad_forwards = 0  # forwards dropped for a bad request
         self._shed_submits = 0  # throttled info counter (submit_request)
         self._leader_memo_key = None  # (view, decisions, ckpt version) memo
         self._leader_memo = 0
@@ -311,12 +317,31 @@ class Controller(RequestTimeoutHandler):
     # ------------------------------------------------------------------ requests
 
     async def submit_request(self, request: bytes, *,
-                             forwarded: bool = False) -> None:
+                             forwarded: bool = False,
+                             verified: bool = False) -> None:
         """consensus entry (controller.go:249-264).  ``forwarded`` marks a
         follower's forward landing here: it bypasses the admission gate
         (the request already holds a pool slot cluster-side; shedding it
-        would only re-arm the follower's complain timer)."""
+        would only re-arm the follower's complain timer).
+
+        Where the App verifies signed envelopes, a client's submit is
+        verified HERE, before the pool takes it, and a refusal raises to
+        the submitter.  Not a ``forwarded`` one (a follower's forward was
+        verified by :meth:`handle_request`, which says ``verified``; the
+        control plane's is the embedder's own)."""
         info = self.request_inspector.request_id(request)
+        check = self._check_request
+        if check is not None and not (forwarded or verified):
+            rec = self.recorder
+            t_enqueue = rec.now() if rec.enabled else None
+            close_for_await()
+            try:
+                await check(request)
+            finally:
+                if t_enqueue is not None:
+                    # a wait: the front door's enqueue -> this envelope's
+                    # verdict
+                    rec.wait("request.verify", t_enqueue, key=str(info))
         try:
             await self.request_pool.submit(request, forwarded=forwarded)
         except Exception as e:
@@ -349,11 +374,41 @@ class Controller(RequestTimeoutHandler):
                 "Got request from %d but the leader is %d, dropping request", sender, leader
             )
             return None
+        if self._check_request is not None:
+            # awaited verification: off this node's inbox, so a burst of
+            # forwards (a new leader's first seconds) shares launches
+            # instead of queueing one behind the other.  The structured
+            # reject of the overload machinery is not carried back then.
+            create_logged_task(self._admit_forward(sender, req),
+                               name=f"forward-verify-{self.id}",
+                               logger=self.logger)
+            return None
         try:
             self.verifier.verify_request(req)
         except Exception as e:
-            self.logger.warnf("Got bad request from %d: %s", sender, e)
+            self._drop_bad_forward(sender, e)
             return None
+        return await self._submit_forward(sender, req)
+
+    def _drop_bad_forward(self, sender: int, e: Exception) -> None:
+        self.bad_forwards += 1
+        if self.bad_forwards == 1 or self.bad_forwards % 1000 == 0:
+            self.logger.warnf(
+                "Got bad request from %d (%d dropped so far): %s",
+                sender, self.bad_forwards, e)
+
+    async def _admit_forward(self, sender: int, req: bytes) -> None:
+        """A forwarded envelope: verified through the shared coalescer,
+        dropped and counted if refused, else submitted."""
+        try:
+            await self._check_request(req)
+        except Exception as e:
+            self._drop_bad_forward(sender, e)
+            return
+        if not self._stopped:
+            await self._submit_forward(sender, req)
+
+    async def _submit_forward(self, sender: int, req: bytes):
         # shunned forwarders lose the admission-gate bypass (ISSUE 18):
         # forwarded=True exists because an honest follower's forward
         # already holds a pool slot cluster-side — a sender this node has
@@ -363,7 +418,8 @@ class Controller(RequestTimeoutHandler):
         forwarded = not (self.misbehavior is not None
                          and self.misbehavior.is_shunned(sender))
         try:
-            await self.submit_request(req, forwarded=forwarded)
+            await self.submit_request(req, forwarded=forwarded,
+                                      verified=True)
         except Exception as e:
             # the reference warns on forwarded-submit failure too
             # (controller.go:258-263); a full pool here is routine under

@@ -121,6 +121,7 @@ from .view import (
     ViewSequence,
     ViewSequencesHolder,
     proposal_sequence_of_msg,
+    verify_proposal_requests,
     verify_sigs_batch,
     view_number_of_msg,
 )
@@ -825,7 +826,7 @@ class WindowedView:
         checks for every slot; certificate-chain + blacklist verification at
         window boundaries (rotation mode) or rotation-off invariants."""
         proposal = pp.proposal
-        requests = self.verifier.verify_proposal(proposal)
+        requests = await verify_proposal_requests(self, proposal)
         md = decode(ViewMetadata, proposal.metadata)
         if md.view_id != self.number:
             raise ValueError(f"invalid view number: expected {self.number} got {md.view_id}")

@@ -142,6 +142,32 @@ async def verify_sigs_batch(verifier, sigs, proposal, logger=None) -> list:
     return out
 
 
+async def verify_proposal_requests(view, proposal) -> list:
+    """The requests of ``proposal``, checked as the App checks them
+    (``Verifier.verify_proposal``; raises on a bad one).
+
+    Where the App verifies signed envelopes it also exposes
+    ``verify_proposal_async``: every envelope of the proposal as ONE
+    submission to the shared coalescer, awaited by each follower before it
+    votes (Fabric's ``VerifyProposal``).  The proposer does not ask again:
+    it cut the batch from its own pool, whose every entry was verified
+    when the pool took it.  Shared by :class:`View` and the windowed
+    view; ``view`` needs ``verifier``, ``recorder``, ``self_id``,
+    ``leader_id`` and ``number``."""
+    check = getattr(view.verifier, "verify_proposal_async", None)
+    if check is None:
+        return view.verifier.verify_proposal(proposal)
+    if view.self_id == view.leader_id:
+        return view.verifier.requests_from_proposal(proposal)
+    rec = view.recorder
+    t_start = rec.now() if rec.enabled else None
+    try:
+        return await check(proposal)
+    finally:
+        # a wait: pre-prepare in hand -> all its envelopes judged
+        rec.wait("proposal.verify", t_start, view=view.number)
+
+
 class View:
     """One protocol instance.  Constructed by ProposalMaker, owned by the
     Controller; communicates upward through Decider/FailureDetector/Sync."""
@@ -881,7 +907,7 @@ class View:
     ) -> list:
         """view.go:553-607 — structural, metadata, verification-sequence,
         prev-commit-signature, and blacklist checks."""
-        requests = self.verifier.verify_proposal(proposal)
+        requests = await verify_proposal_requests(self, proposal)
 
         md = decode(ViewMetadata, proposal.metadata)
 

@@ -1,0 +1,183 @@
+"""Signed client envelopes: the request a Fabric-style channel orders.
+
+An envelope is the embedder's request (client id, request id, payload in
+the canonical codec) followed by a fixed trailer: the creator's P-256
+public point (``X || Y``, 64 bytes big-endian, as the certificate in a
+Fabric envelope would carry it) and a 64-byte ``r || s`` ECDSA signature
+over everything before the trailer::
+
+    <client id> <request id> <payload>  u32(64) <creator>  u32(64) <r || s>
+    |------------ signed ------------|  |------------- trailer ----------|
+
+Replicas hold the channel's ENROLLED identities — a set of public keys.
+It stands for the MSP's cached certificate validation (a departure from
+Fabric, which validates a certificate chain per creator): an envelope
+whose creator is not in the set is refused before any device work.
+
+:class:`EnvelopeVerifier` is the one implementation of "envelope ->
+verify item, or reject as malformed / not enrolled" and of the verdict
+that follows; both embedders (``testing.app.App``, ``net.launch.
+ReplicaApp``) hold one when they are given enrolled identities, and
+expose its two coroutines to the protocol core as ``verify_request_async``
+/ ``verify_proposal_async``.  An App without enrolled identities holds
+none and the core takes the path it always took.
+"""
+
+from __future__ import annotations
+
+import struct
+from typing import Awaitable, Callable, Iterable, Optional, Sequence
+
+from ..codec import encode, wiremsg
+from ..obs.recorder import standby
+from . import p256
+
+__all__ = ["EnvelopeRejected", "EnvelopeVerifier", "TRAILER",
+           "creator_bytes", "sign_envelope", "split_envelope"]
+
+_KEY = 64  # X || Y
+_SIG = 64  # r || s
+_LEN = struct.Struct(">I")
+#: bytes after the signed part: two length-prefixed 64-byte fields
+TRAILER = 2 * _LEN.size + _KEY + _SIG
+_KEY_PREFIX = _LEN.pack(_KEY)
+_SIG_PREFIX = _LEN.pack(_SIG)
+
+#: why an envelope was refused (the ``cause`` of :class:`EnvelopeRejected`,
+#: the keys of :attr:`EnvelopeVerifier.rejected`, the ``req.rejected``
+#: mark's ``cause``)
+CAUSES = ("malformed", "not_enrolled", "bad_signature")
+
+
+@wiremsg
+class _Signed:
+    """The signed part: an embedder's unsigned request has these bytes.
+    (The verify path reads the trailer by offset and never decodes it.)"""
+
+    client_id: str = ""
+    request_id: str = ""
+    payload: bytes = b""
+
+
+class EnvelopeRejected(ValueError):
+    """An envelope the channel refuses; ``cause`` is one of
+    :data:`CAUSES`."""
+
+    def __init__(self, cause: str, detail: str = ""):
+        super().__init__(f"envelope rejected ({cause})"
+                         + (f": {detail}" if detail else ""))
+        self.cause = cause
+
+
+def creator_bytes(pub) -> bytes:
+    """A P-256 public point as the 64 bytes an envelope carries."""
+    return pub[0].to_bytes(32, "big") + pub[1].to_bytes(32, "big")
+
+
+def sign_envelope(private: int, public, client_id: str, request_id: str,
+                  payload: bytes = b"") -> bytes:
+    """Build and sign one envelope with the native signer
+    (``p256.sign_raw``)."""
+    signed = encode(_Signed(client_id=client_id, request_id=request_id,
+                            payload=payload))
+    return b"".join((signed, _KEY_PREFIX, creator_bytes(public),
+                     _SIG_PREFIX, p256.sign_raw(private, signed)))
+
+
+def split_envelope(raw: bytes) -> tuple[bytes, bytes, bytes]:
+    """-> (signed bytes, creator, signature); raises
+    ``EnvelopeRejected("malformed")`` unless the trailer is there."""
+    cut = len(raw) - TRAILER
+    if (cut < 0 or raw[cut:cut + 4] != _KEY_PREFIX
+            or raw[cut + 4 + _KEY:cut + 8 + _KEY] != _SIG_PREFIX):
+        raise EnvelopeRejected("malformed", "no creator / signature trailer")
+    return raw[:cut], raw[cut + 4:cut + 4 + _KEY], raw[cut + 8 + _KEY:]
+
+
+class EnvelopeVerifier:
+    """The enrolled identities of one channel and the check against them.
+
+    ``enrolled``: the clients' public keys.  ``engine``: the verify engine
+    behind the synchronous SPI methods (``Verifier.verify_request`` /
+    ``verify_proposal``); ``submit``: the provider's awaited path into the
+    shared coalescer (``CryptoProvider.verify_items_async``), behind the
+    two coroutines the protocol core awaits.  Client keys are handed to
+    the engine as they come, never registered with it: on a TPU they ride
+    the arbitrary-key kernel (``JaxVerifyEngine.pin_ring``)."""
+
+    scheme = p256
+
+    def __init__(self, enrolled: Iterable, *, engine,
+                 submit: Optional[Callable[[list], Awaitable[list]]] = None,
+                 recorder=None):
+        self._pub_of = {creator_bytes(pub): pub for pub in enrolled}
+        if not self._pub_of:
+            raise ValueError("an EnvelopeVerifier needs enrolled identities")
+        self.engine = engine
+        self._submit = submit
+        self.recorder = standby(recorder)
+        #: always on: envelopes refused, by cause
+        self.rejected = dict.fromkeys(CAUSES, 0)
+        #: envelopes judged valid (front door, forwards and proposals)
+        self.accepted = 0
+
+    # -- envelope -> item -------------------------------------------------------
+
+    def body(self, raw: bytes) -> bytes:
+        """The signed part (what the embedder decodes as its request)."""
+        try:
+            return split_envelope(raw)[0]
+        except EnvelopeRejected as e:
+            self._note(e.cause)
+            raise
+
+    def item(self, raw: bytes) -> tuple:
+        """The verify item of one envelope, or ``EnvelopeRejected``
+        (``malformed`` / ``not_enrolled``), counted."""
+        try:
+            signed, creator, signature = split_envelope(raw)
+            pub = self._pub_of.get(creator)
+            if pub is None:
+                raise EnvelopeRejected("not_enrolled",
+                                       f"creator {creator[:8].hex()}..")
+        except EnvelopeRejected as e:
+            self._note(e.cause)
+            raise
+        return p256.make_item(signed, signature, pub)
+
+    def items(self, raws: Sequence[bytes]) -> list:
+        rec = self.recorder
+        span = rec.begin("request.pack") if rec.enabled else None
+        try:
+            return [self.item(raw) for raw in raws]
+        finally:
+            if span is not None:
+                rec.end(span)
+
+    def _note(self, cause: str) -> None:
+        self.rejected[cause] += 1
+        rec = self.recorder
+        if rec.enabled:
+            rec.record("req.rejected", extra={"cause": cause})
+
+    def _judge(self, mask: Sequence) -> None:
+        bad = sum(1 for ok in mask if not ok)
+        self.accepted += len(mask) - bad
+        if bad:
+            for _ in range(bad):
+                self._note("bad_signature")
+            raise EnvelopeRejected(
+                "bad_signature", f"{bad} of {len(mask)} envelope(s)")
+
+    # -- the verdict ------------------------------------------------------------
+
+    def check(self, raws: Sequence[bytes]) -> None:
+        """Synchronous: every envelope of ``raws`` is well formed, enrolled
+        and validly signed, or ``EnvelopeRejected``."""
+        if raws:
+            self._judge(self.engine.verify(self.items(raws)))
+
+    async def check_async(self, raws: Sequence[bytes]) -> None:
+        """The same through the shared coalescer, as ONE submission."""
+        if raws:
+            self._judge(await self._submit(self.items(raws)))

@@ -41,6 +41,7 @@ from ..messages import Proposal, Signature
 from ..obs.recorder import (
     launch_span,
     name_this_thread,
+    note_lanes,
     set_thread_launch,
     standby,
 )
@@ -111,11 +112,18 @@ class VerifyStats:
     metrics: object = None
     launches_by_kernel: dict = field(
         default_factory=lambda: dict.fromkeys(KERNELS, 0))
+    #: lanes launched (padding included) and lanes used, per kernel
+    lanes_by_kernel: dict = field(
+        default_factory=lambda: dict.fromkeys(KERNELS, 0))
+    used_by_kernel: dict = field(
+        default_factory=lambda: dict.fromkeys(KERNELS, 0))
 
     def record(self, n_sigs: int, n_slots: int, seconds: float,
                kernel: str = "host") -> None:
         self.launches += 1
         self.launches_by_kernel[kernel] += 1
+        self.lanes_by_kernel[kernel] += n_slots
+        self.used_by_kernel[kernel] += n_sigs
         self.sigs_verified += n_sigs
         self.slots_used += n_slots
         self.total_kernel_seconds += seconds
@@ -499,8 +507,12 @@ class JaxVerifyEngine:
     def __init__(self,
                  pad_sizes: Sequence[int] = (8, 32, 128, 512, 2048, 4096,
                                              8192, 16384),
-                 scheme=p256, metrics=None):
-        """``pad_sizes``: the top rung bounds how much of a large cluster's
+                 scheme=p256, metrics=None,
+                 ring: Optional[Sequence] = None,
+                 request_pad_sizes: Optional[Sequence[int]] = None):
+        """``ring`` / ``request_pad_sizes``: see :meth:`pin_ring`.
+
+        ``pad_sizes``: the top rung bounds how much of a large cluster's
         quorum wave one launch can absorb (n=128 -> 10880 signatures);
         per-launch overhead is fixed, so bigger is better.  A size only
         compiles a kernel when a batch of that shape first occurs — call
@@ -545,6 +557,13 @@ class JaxVerifyEngine:
         self._launched: set = set()
         self._lock = threading.Lock()
         self.stats = VerifyStats(metrics=metrics)
+        #: the static keys (None: every key handed in is registrable, the
+        #: contract before rings) and the ladder of everything else
+        self._ring: Optional[frozenset] = None
+        self.request_pad_sizes = tuple(sorted(request_pad_sizes or ())) \
+            or self.pad_sizes
+        if ring is not None:
+            self.pin_ring(ring)
 
     def _use_pallas(self) -> bool:
         """Default the Pallas kernels on when the backend is a TPU.
@@ -592,21 +611,67 @@ class JaxVerifyEngine:
             return (kernel, size, self._comb.registry.slots())
         return (kernel, size)
 
-    def _pad_to(self, n: int) -> int:
-        for s in self.pad_sizes:
+    @staticmethod
+    def _rung(sizes: Sequence[int], n: int) -> int:
+        for s in sizes:
             if n <= s:
                 return s
-        return self.pad_sizes[-1]
+        return sizes[-1]
+
+    def _pad_to(self, n: int) -> int:
+        return self._rung(self.pad_sizes, n)
+
+    def pin_ring(self, pubs) -> None:
+        """Make the STATIC keys known: the orderers' ring(s), whose comb
+        tables are worth building.  From then on the engine serves two key
+        classes: ring keys ride the comb kernel on ``pad_sizes``, every
+        other key (a channel's client identities: thousands, far over the
+        comb registry's 128) rides the arbitrary-key kernel on
+        ``request_pad_sizes`` — a flush holding both becomes one launch
+        of each, verdicts reassembled in submission order.  A key outside
+        the ring is then never registered, at first use or by
+        :meth:`prewarm_keys` (which ignores it), so the registry's key
+        slots, part of every comb rung's compiled shape, never grow.
+        Additive: colocated groups pin a ring each.
+
+        An engine told no ring registers every key it is handed, as
+        before; on a backend without the Pallas kernels both classes ride
+        the XLA kernel, each on its ladder."""
+        pubs = list(pubs)
+        if self._comb is not None:
+            self._comb.prewarm_keys(pubs)
+        self._ring = (self._ring or frozenset()) | frozenset(pubs)
 
     def verify(self, items) -> list[bool]:
         """items: scheme.make_item tuples -> validity per item."""
         if not items:
             return []
+        ring = self._ring
+        if ring is None or not self.supports_pallas:
+            # no ring told, or a mesh engine (one kernel, one ladder)
+            return self._verify_class(items, False)
+        # a key is the last field of an item in every scheme
+        inside, outside = [], []
+        for i, it in enumerate(items):
+            (inside if it[-1] in ring else outside).append(i)
+        if not outside or not inside:
+            return self._verify_class(items, not inside)
+        out: list = [None] * len(items)
+        for idxs, generic in ((inside, False), (outside, True)):
+            verdicts = self._verify_class([items[i] for i in idxs], generic)
+            for i, v in zip(idxs, verdicts):
+                out[i] = v
+        return out
+
+    def _verify_class(self, items, generic: bool) -> list[bool]:
+        """One key class through its kernel, in chunks of its ladder's
+        largest rung."""
         out: list[bool] = []
-        # oversized batches run in chunks of the largest lane size
-        cap = self.pad_sizes[-1]
+        cap = (self.request_pad_sizes if generic else self.pad_sizes)[-1]
         for off in range(0, len(items), cap):
-            out.extend(self._verify_chunk(items[off : off + cap]))
+            chunk = items[off : off + cap]
+            out.extend(self._verify_chunk(chunk, True) if generic
+                       else self._verify_chunk(chunk))
         return out
 
     def _place(self, a):
@@ -615,9 +680,13 @@ class JaxVerifyEngine:
 
     def prewarm_keys(self, pubs) -> None:
         """Register a known key set (e.g. the whole keyring) with the comb
-        registry up front, so no verify path ever re-traces mid-protocol."""
-        if self._comb is not None:
-            self._comb.prewarm_keys(pubs)
+        registry up front, so no verify path ever re-traces mid-protocol.
+        With a ring pinned, keys outside it are left alone."""
+        if self._comb is None:
+            return
+        if self._ring is not None:
+            pubs = [pub for pub in pubs if pub in self._ring]
+        self._comb.prewarm_keys(pubs)
 
     def prewarm_shapes(self, item, sizes: Optional[Sequence[int]] = None) -> None:
         """Compile every pad-ladder shape up front with copies of ``item``
@@ -625,16 +694,25 @@ class JaxVerifyEngine:
 
         Kernel shapes otherwise compile on first use — fine for benches,
         but in a live protocol the first large quorum wave would stall for
-        the compile (possibly past heartbeat/view-change timeouts)."""
-        for size in (self.pad_sizes if sizes is None else sizes):
+        the compile (possibly past heartbeat/view-change timeouts).
+
+        With a ring pinned, an ``item`` whose key is outside it compiles
+        the arbitrary-key kernel's rungs (``request_pad_sizes``)."""
+        if sizes is None:
+            generic = (self._ring is not None and self.supports_pallas
+                       and item[-1] not in self._ring)
+            sizes = self.request_pad_sizes if generic else self.pad_sizes
+        for size in sizes:
             self.verify([item] * size)
 
-    def _launch(self, items, size: int):
+    def _launch(self, items, size: int, generic: bool = False):
         """One padded chunk through the kernel this engine's backend and
-        the chunk's keys select -> (kernel name, host mask)."""
+        the chunk's keys select -> (kernel name, host mask).  ``generic``:
+        the chunk's keys are outside the pinned ring, so the comb kernel
+        is not asked."""
         if self._pallas_on is None:
             self._pallas_on = self._use_pallas()
-        if self._pallas_on and self._comb is not None:
+        if self._pallas_on and self._comb is not None and not generic:
             # None: a key the registry cannot hold — the chunk rides the
             # arbitrary-key kernel below
             mask = self._guarded_launch(
@@ -659,14 +737,16 @@ class JaxVerifyEngine:
 
         return name, self._guarded_launch(name, size, launch)
 
-    def _verify_chunk(self, items) -> list[bool]:
+    def _verify_chunk(self, items, generic: bool = False) -> list[bool]:
         n = len(items)
-        size = self._pad_to(n)
+        size = self._rung(self.request_pad_sizes, n) if generic \
+            else self._pad_to(n)
         t0 = time.perf_counter()
-        kernel, mask = self._launch(items, size)
+        kernel, mask = self._launch(items, size, generic)
         dt = time.perf_counter() - t0
         with self._lock:
             self.stats.record(n, size, dt, kernel)
+        note_lanes(kernel, size, n)
         return [bool(v) for v in mask[:n]]
 
 
@@ -682,7 +762,14 @@ def prewarm_verify_engine(engine, scheme=None,
     per-process compile tax (PERF.md "cold-compile budget") stops
     poisoning device bench rows.  A shape the compiler refuses raises
     :class:`KernelCompileError` here, before any protocol traffic.  No-op
-    for engines without a pad ladder (host engines compile nothing)."""
+    for engines without a pad ladder (host engines compile nothing).
+
+    An engine with a pinned ring (:meth:`JaxVerifyEngine.pin_ring`) has
+    two kernels and this compiles both: the probe key is outside every
+    ring, so the first pass compiles the arbitrary-key kernel's rungs
+    (``request_pad_sizes``); a second pass presents the probe's signature
+    under a ring key (a comb launch needs a registered key, not a valid
+    signature) and compiles the comb kernel's rungs (``pad_sizes``)."""
     prewarm = getattr(engine, "prewarm_shapes", None)
     if prewarm is None:
         return
@@ -690,6 +777,9 @@ def prewarm_verify_engine(engine, scheme=None,
     sk, pub = scheme.keygen(b"smartbft-prewarm-probe")
     item = scheme.make_item(b"p", scheme.sign_raw(sk, b"p"), pub)
     prewarm(item, sizes)
+    ring = getattr(engine, "_ring", None)
+    if ring and sizes is None and getattr(engine, "supports_pallas", False):
+        prewarm(item[:-1] + (next(iter(ring)),), None)
 
 
 class AsyncBatchCoalescer:
@@ -1768,6 +1858,14 @@ class CryptoProvider:
         return self.engine.verify(items)
 
     async def _verify_items_async(self, items) -> list[bool]:
+        return await self.verify_items_async(items)
+
+    async def verify_items_async(self, items) -> list[bool]:
+        """The request path into the shared coalescer: scheme verify items
+        by ANY key (a channel's client envelopes,
+        :class:`~smartbft_tpu.crypto.envelope.EnvelopeVerifier`), one
+        submission, verdicts in order.  Votes keep
+        :meth:`verify_consenter_sigs_batch_async`."""
         return await self._coalescer.submit(items, tag=self.verify_tag)
 
     def _collect(self, signatures: Sequence[Signature], proposal: Proposal):

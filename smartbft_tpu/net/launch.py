@@ -61,6 +61,7 @@ from ..core.readplane import (
 )
 from ..core.util import compute_quorum
 from ..messages import Proposal, Signature, ViewMetadata
+from ..testing.app import EnvelopeChecks, decode_request
 from ..snapshot import (
     CHAIN_SEED,
     RECENT_IDS_CAP,
@@ -320,8 +321,20 @@ class _SnapshotServer:
         return total, data, last
 
 
-class ReplicaApp(Application, Assembler, Comm, Signer, Verifier,
-                 RequestInspector, Synchronizer, MembershipNotifier):
+def _request_crypto(node_id: int):
+    """A P-256 provider for the REQUEST path of a socket replica (its
+    votes stay trivial): OpenSSL on the host behind a coalescer of its
+    own — a replica process holds no device."""
+    from ..crypto.openssl_engine import OpenSSLVerifyEngine
+    from ..crypto.provider import Keyring, P256CryptoProvider
+
+    ring = Keyring.generate([node_id], seed=b"request-path")[node_id]
+    return P256CryptoProvider(ring, engine=OpenSSLVerifyEngine())
+
+
+class ReplicaApp(EnvelopeChecks, Application, Assembler, Comm, Signer,
+                 Verifier, RequestInspector, Synchronizer,
+                 MembershipNotifier):
     """The multi-process embedder: one OS process, no shared memory."""
 
     #: ledger appends are a buffered write + flush — cheap enough to run
@@ -358,6 +371,14 @@ class ReplicaApp(Application, Assembler, Comm, Signer, Verifier,
             enabled=bool(spec.get("trace")),
         )
         self.transport.recorder = self.recorder
+        # enrolled client identities (spec "enrolled": hex X || Y each):
+        # every request is then a signed envelope, verified where the
+        # in-process App verifies it (crypto.envelope)
+        enrolled = [(int(h[:64], 16), int(h[64:], 16))
+                    for h in spec.get("enrolled", ())]
+        self.enroll(enrolled,
+                    _request_crypto(self.id) if enrolled else None,
+                    self.recorder)
         # cluster health plane (ISSUE 14): every replica judges itself
         # against the declarative SLO spec on a periodic tick; cmd=health
         # serves the verdict, SocketCluster.cluster_health aggregates n
@@ -490,7 +511,7 @@ class ReplicaApp(Application, Assembler, Comm, Signer, Verifier,
         ``(client_id, request_id, payload)`` per well-formed TestRequest
         in the batch, in batch order.  Foreign payloads contribute
         nothing (mirrors ``_reconfig_in``'s tolerance)."""
-        from ..testing.app import BatchPayload, TestRequest
+        from ..testing.app import BatchPayload
 
         if not proposal.payload:
             return []
@@ -501,7 +522,7 @@ class ReplicaApp(Application, Assembler, Comm, Signer, Verifier,
         out: list[tuple[str, str, bytes]] = []
         for raw in batch.requests:
             try:
-                req = decode(TestRequest, raw)
+                req = decode_request(raw, self.envelopes)
             except Exception:  # noqa: BLE001 — foreign request
                 continue
             out.append((req.client_id, req.request_id, bytes(req.payload)))
@@ -600,7 +621,7 @@ class ReplicaApp(Application, Assembler, Comm, Signer, Verifier,
             self._snap_inflight = False
 
     def _reconfig_in(self, proposal: Proposal) -> Reconfig:
-        from ..testing.app import BatchPayload, TestRequest
+        from ..testing.app import BatchPayload
         from ..testing.reconfig import RECONFIG_MAGIC, detect_reconfig
 
         found = Reconfig(in_latest_decision=False)
@@ -612,7 +633,7 @@ class ReplicaApp(Application, Assembler, Comm, Signer, Verifier,
             return found
         for raw in batch.requests:
             try:
-                req = decode(TestRequest, raw)
+                req = decode_request(raw, self.envelopes)
             except Exception:
                 continue
             reconfig = detect_reconfig(req.payload)
@@ -659,11 +680,7 @@ class ReplicaApp(Application, Assembler, Comm, Signer, Verifier,
         return Signature(signer=self.id, value=b"sig-%d" % self.id,
                          msg=auxiliary_input)
 
-    def verify_proposal(self, proposal: Proposal) -> list[RequestInfo]:
-        return self.requests_from_proposal(proposal)
-
-    def verify_request(self, raw_request: bytes) -> RequestInfo:
-        return self.request_id(raw_request)
+    # verify_request / verify_proposal: testing.app.EnvelopeChecks
 
     def verify_consenter_sig(self, signature: Signature, proposal: Proposal) -> bytes:
         return signature.msg
@@ -686,10 +703,8 @@ class ReplicaApp(Application, Assembler, Comm, Signer, Verifier,
         return msg
 
     def request_id(self, raw_request: bytes) -> RequestInfo:
-        from ..testing.app import TestRequest
-
         def compute() -> RequestInfo:
-            req = decode(TestRequest, raw_request)
+            req = decode_request(raw_request, self.envelopes)
             return RequestInfo(client_id=req.client_id, request_id=req.request_id)
 
         return self._request_id_cache.get_or(raw_request, compute)

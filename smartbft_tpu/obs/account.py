@@ -27,10 +27,16 @@ cover the same span of time.
 ``counters``           ``decisions`` (delivered by the replica that
                        proposed them), ``requests_proposed``, ``launches``,
                        ``signatures``, ``fsync_waves``
+``lanes``              kernel -> ``launches``, ``launched`` (lanes, padding
+                       included) and ``used`` (``verify.lanes`` marks)
+``rejected``           cause -> client envelopes refused (``req.rejected``)
 ``segments``           segment -> ms per decision (:func:`decision_rows`)
 ``decisions``          the rows themselves: ``view``, ``seq``, ``node``,
                        ``total_ms`` and one ms value per segment
-``waits``              wait kind -> ms per wait; ``pool.wait`` and
+``waits``              wait kind -> ms per wait (``request.verify``: the
+                       front door's enqueue -> verdict of one envelope;
+                       ``proposal.verify``: a follower's pre-prepare in
+                       hand -> all its envelopes judged); ``pool.wait`` and
                        ``req.total`` per request delivered by its proposer
 ``durations``          ``wal.fsync`` -> ms per fsync (a busy span on the
                        executor thread that ran the wave)
@@ -48,7 +54,8 @@ from .critpath import DECISION_SEGMENTS, decision_rows
 __all__ = ["assemble_account"]
 
 #: wait kinds recorded as such, taken as they are
-_WAIT_KINDS = ("verify.wait", "verify.hold", "wal.persist")
+_WAIT_KINDS = ("verify.wait", "verify.hold", "wal.persist",
+               "request.verify", "proposal.verify")
 
 
 def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
@@ -91,6 +98,8 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
     counters = {"decisions": 0, "requests_proposed": 0, "launches": 0,
                 "signatures": 0, "fsync_waves": 0}
     waits: dict = {k: [] for k in _WAIT_KINDS}
+    lanes: dict = {}
+    rejected: dict = {}
     submits: dict = {}
     delivered = []
     fsync_ms: list = []
@@ -112,6 +121,16 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
             submits.setdefault((e.node, e.key), e.t)
         elif kind == "req.deliver":
             delivered.append(e)
+        elif kind == "verify.lanes":
+            x = e.extra
+            per = lanes.setdefault(
+                x["kernel"], {"launches": 0, "launched": 0, "used": 0})
+            per["launches"] += 1
+            per["launched"] += x["lanes"]
+            per["used"] += x["used"]
+        elif kind == "req.rejected":
+            cause = (e.extra or {}).get("cause", "?")
+            rejected[cause] = rejected.get(cause, 0) + 1
         if kind in waits and e.dur >= 0.0:
             waits[kind].append(e.dur * 1e3)
     pool_wait, total = [], []
@@ -140,6 +159,8 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
         "collector": {"passes": passes, "frozen": frozen,
                       "thresholds": list(thresholds)},
         "counters": counters,
+        "lanes": lanes,
+        "rejected": rejected,
         "segments": {seg: [r[seg] for r in rows]
                      for seg in DECISION_SEGMENTS},
         "decisions": rows,

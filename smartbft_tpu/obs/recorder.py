@@ -66,6 +66,7 @@ __all__ = [
     "last_summary",
     "launch_span",
     "name_this_thread",
+    "note_lanes",
     "poll_profiler",
     "close_for_await",
     "set_thread_launch",
@@ -666,6 +667,16 @@ def launch_span(kind: str):
     CPU says how long the launch stood blocked.  Off: a shared no-op,
     nothing allocated."""
     return _LaunchSpan(kind) if PROCESS.enabled else _NO_SPAN
+
+
+def note_lanes(kernel: str, lanes: int, used: int) -> None:
+    """A verify launch's lanes (padding included) and the lanes used, by
+    the kernel that served it: a mark on the launch's thread, folded into
+    the account's ``lanes`` block.  Off: one attribute read."""
+    if PROCESS.enabled:
+        PROCESS.record("verify.lanes", launch=_state().launch,
+                       extra={"kernel": kernel, "lanes": lanes,
+                              "used": used})
 
 
 def _live_recorders() -> list:

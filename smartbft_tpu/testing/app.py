@@ -49,6 +49,71 @@ class TestRequest:
     payload: bytes = b""
 
 
+def decode_request(raw: bytes, envelopes=None) -> TestRequest:
+    """The request inside ``raw``: the bytes themselves, or the signed
+    part of a client envelope where the App holds enrolled identities
+    (``envelopes``: its :class:`~smartbft_tpu.crypto.envelope.
+    EnvelopeVerifier`) — every request of such a channel is an envelope."""
+    return decode(TestRequest,
+                  raw if envelopes is None else envelopes.body(raw))
+
+
+class EnvelopeChecks:
+    """The request half of the Verifier SPI for an embedder whose
+    requests are :class:`TestRequest` bytes in a :class:`BatchPayload`,
+    mixed into both Apps.  ``self.envelopes`` is the EnvelopeVerifier or
+    None; with one, ``verify_request`` / ``verify_proposal`` refuse a
+    forged envelope (synchronously, on the engine) and the two coroutines
+    the protocol core prefers go through the shared coalescer.  The
+    coroutines are bound as instance attributes only then
+    (:meth:`enroll`): their presence IS the switch."""
+
+    envelopes = None
+
+    def enroll(self, identities, crypto, recorder=None) -> None:
+        """Hold the channel's enrolled client identities (before the
+        consensus instance is built: the core looks for the coroutines
+        when it is): an EnvelopeVerifier over ``crypto``'s engine and its
+        awaited path into the shared coalescer.  No identities: no-op."""
+        if not identities:
+            return
+        if crypto is None or not hasattr(crypto, "verify_items_async"):
+            raise ValueError("enrolled identities need a crypto provider "
+                             "with a request path (CryptoProvider)")
+        from ..crypto.envelope import EnvelopeVerifier
+
+        if crypto.scheme is not EnvelopeVerifier.scheme:
+            raise ValueError("client envelopes are P-256 signed")
+        self.envelopes = EnvelopeVerifier(
+            identities, engine=crypto.engine,
+            submit=crypto.verify_items_async, recorder=recorder)
+        self.verify_request_async = self._verify_request_async
+        self.verify_proposal_async = self._verify_proposal_async
+
+    def verify_request(self, raw_request: bytes) -> RequestInfo:
+        info = self.request_id(raw_request)
+        if self.envelopes is not None:
+            self.envelopes.check([raw_request])
+        return info
+
+    def verify_proposal(self, proposal: Proposal) -> list[RequestInfo]:
+        infos = self.requests_from_proposal(proposal)
+        if self.envelopes is not None and proposal.payload:
+            self.envelopes.check(
+                decode(BatchPayload, proposal.payload).requests)
+        return infos
+
+    async def _verify_request_async(self, raw_request: bytes) -> None:
+        await self.envelopes.check_async([raw_request])
+
+    async def _verify_proposal_async(self, proposal: Proposal) -> list:
+        infos = self.requests_from_proposal(proposal)
+        if proposal.payload:
+            await self.envelopes.check_async(
+                decode(BatchPayload, proposal.payload).requests)
+        return infos
+
+
 @wiremsg
 class BatchPayload:
     requests: list[bytes] = None  # type: ignore[assignment]
@@ -170,8 +235,8 @@ class SharedLedgers:
             return list(self.ledgers.get(node_id, []))
 
 
-class App(Application, Assembler, Comm, Signer, Verifier, RequestInspector,
-          Synchronizer, MembershipNotifier):
+class App(EnvelopeChecks, Application, Assembler, Comm, Signer, Verifier,
+          RequestInspector, Synchronizer, MembershipNotifier):
     """One test node: SPI implementation + fault injection + lifecycle."""
 
     def __init__(
@@ -187,7 +252,12 @@ class App(Application, Assembler, Comm, Signer, Verifier, RequestInspector,
         wal_file_size_bytes: Optional[int] = None,
         comm=None,
         recorder=None,
+        enrolled=None,
     ):
+        """``enrolled``: the channel's enrolled client identities (P-256
+        public keys).  With them every request is a signed envelope
+        (``crypto.envelope``) and is verified on ``crypto``'s engine;
+        without, requests are unsigned and unchecked as before."""
         self.id = node_id
         self.network = network
         self.shared = shared
@@ -279,6 +349,7 @@ class App(Application, Assembler, Comm, Signer, Verifier, RequestInspector,
             # MisbehaviorTable to the provider so failed verify verdicts
             # are charged to the signer instead of the aggregate counter
             self.configure_misbehavior = crypto.configure_misbehavior
+        self.enroll(enrolled, crypto, recorder)
 
     # ------------------------------------------------------------------ app
 
@@ -310,7 +381,7 @@ class App(Application, Assembler, Comm, Signer, Verifier, RequestInspector,
             return found
         for raw in batch.requests:
             try:
-                req = decode(TestRequest, raw)
+                req = decode_request(raw, self.envelopes)
             except Exception:
                 continue
             reconfig = detect_reconfig(req.payload)
@@ -370,11 +441,7 @@ class App(Application, Assembler, Comm, Signer, Verifier, RequestInspector,
 
     # -- Verifier (trivial crypto, test_app.go:237-267) --------------------
 
-    def verify_proposal(self, proposal: Proposal) -> list[RequestInfo]:
-        return self.requests_from_proposal(proposal)
-
-    def verify_request(self, raw_request: bytes) -> RequestInfo:
-        return self.request_id(raw_request)
+    # verify_request / verify_proposal: EnvelopeChecks
 
     def verify_consenter_sig(self, signature: Signature, proposal: Proposal) -> bytes:
         if self.crypto is not None:
@@ -423,7 +490,7 @@ class App(Application, Assembler, Comm, Signer, Verifier, RequestInspector,
         # hit path free of per-call closure allocation.
         info = self._request_id_cache.get(raw_request)
         if info is None:
-            req = decode(TestRequest, raw_request)
+            req = decode_request(raw_request, self.envelopes)
             info = RequestInfo(client_id=req.client_id,
                                request_id=req.request_id)
             self._request_id_cache.put(raw_request, info)
@@ -560,8 +627,16 @@ class App(Application, Assembler, Comm, Signer, Verifier, RequestInspector,
         await self.start()
 
     async def submit(self, client_id: str, request_id: str, payload: bytes = b"",
-                     *, internal: bool = False) -> None:
-        req = encode(TestRequest(client_id=client_id, request_id=request_id, payload=payload))
+                     *, internal: bool = False, signer=None) -> None:
+        """``signer``: ``(private, public)`` of an enrolled identity — the
+        request then goes out as a signed envelope (what a channel with
+        enrolled identities orders; its control plane signs too)."""
+        if signer is not None:
+            from ..crypto.envelope import sign_envelope
+
+            req = sign_envelope(*signer, client_id, request_id, payload)
+        else:
+            req = encode(TestRequest(client_id=client_id, request_id=request_id, payload=payload))
         await self.consensus.submit_request(req, internal=internal)
 
     async def submit_reconfig(
@@ -630,7 +705,7 @@ class App(Application, Assembler, Comm, Signer, Verifier, RequestInspector,
                     continue
                 for raw in batch.requests:
                     try:
-                        req = decode(TestRequest, raw)
+                        req = decode_request(raw, self.envelopes)
                     except Exception:  # noqa: BLE001 — foreign request
                         continue
                     self._kv[req.client_id] = bytes(req.payload)
@@ -714,7 +789,7 @@ class App(Application, Assembler, Comm, Signer, Verifier, RequestInspector,
                 continue
             for raw in batch.requests:
                 try:
-                    req = decode(TestRequest, raw)
+                    req = decode_request(raw, self.envelopes)
                 except Exception:  # noqa: BLE001 — foreign request
                     continue
                 kv[req.client_id] = bytes(req.payload)

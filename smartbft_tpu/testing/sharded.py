@@ -365,8 +365,13 @@ class ShardedCluster:
         trace: bool = False,
         trace_capacity: int = 4096,
         slo_spec=None,
+        enrolled=None,
     ):
-        """``crypto``: "trivial" | "p256" | "ed25519" | "toy" (see module
+        """``enrolled``: the enrolled client identities (P-256 public keys)
+        of every channel; with them each replica verifies every client
+        envelope (``crypto="p256"`` only; see ``crypto.envelope``).
+
+        ``crypto``: "trivial" | "p256" | "ed25519" | "toy" (see module
         docstring; "toy" is the real provider stack over the array-math
         testing.toy_scheme — the mesh-path configuration tests use it).  ``engine``: the shared device-stand-in engine for the
         real-crypto modes (defaults to a HostVerifyEngine of the scheme);
@@ -496,6 +501,7 @@ class ShardedCluster:
             # round-trips it); an explicit constructor arg still wins
             reshard_drain_deadline = self.base_config.reshard_drain_deadline
         self._crypto_for = crypto_for
+        self._enrolled: Optional[list] = None
         #: incarnation count per shard id — a retired-then-recreated id is
         #: a NEW consensus group with its own network namespace + WAL dirs
         self._incarnations: dict[int, int] = {s: 1 for s in range(shards)}
@@ -546,6 +552,22 @@ class ShardedCluster:
         )
         self.health.add_source(coalescer_signal_source(self.coalescer))
         self.health.add_source(self._vc_signal_source())
+        if enrolled:
+            self.enroll(enrolled)
+
+    def enroll(self, identities) -> None:
+        """Give every replica of every channel the enrolled client
+        identities (before :meth:`start`); shards born of a later reshard
+        get them too."""
+        self._enrolled = list(identities)
+        for sh in self.shard_list:
+            self._enroll_shard(sh)
+
+    def _enroll_shard(self, shard: "AppShard") -> "AppShard":
+        if self._enrolled:
+            for app in shard.apps:
+                app.enroll(self._enrolled, app.crypto, app.recorder)
+        return shard
 
     def _vc_signal_source(self):
         """A source folding every LIVE replica's VC tracker signals into
@@ -618,7 +640,7 @@ class ShardedCluster:
         before)."""
         inc = self._incarnations.get(sid, 0)
         self._incarnations[sid] = inc + 1
-        return AppShard(
+        return self._enroll_shard(AppShard(
             sid, self.network, self.scheduler, self.wal_root, n=self.n,
             config_fn=lambda i, _s=sid: self._config_fn(_s, i),
             crypto_fn=lambda i, _s=sid: self._crypto_for(_s, i),
@@ -629,7 +651,7 @@ class ShardedCluster:
             recorder_fn=lambda i, _s=sid, _g=inc: self._recorder_for(
                 f"s{_s}n{i}" if _g == 0 else f"s{_s}g{_g}n{i}"
             ),
-        )
+        ))
 
     async def reshard(self, new_shards: int, **kw) -> dict:
         """Live split/merge to ``new_shards`` groups under traffic (the
@@ -648,12 +670,15 @@ class ShardedCluster:
     # -- the front door -----------------------------------------------------
 
     async def submit(self, client_id: str, request_id: str,
-                     payload: bytes = b"") -> int:
+                     payload: bytes = b"", *,
+                     envelope: Optional[bytes] = None) -> int:
         """Encode a TestRequest and push it through the routed front door;
         returns the shard it landed on.  The request's committed-stream id
         rides along so the set's CommitLatencyTracker can stamp
-        submit→commit latency for it."""
-        req = encode(TestRequest(
+        submit→commit latency for it.  ``envelope``: the client's own
+        signed bytes for this ``(client_id, request_id)``
+        (``crypto.envelope.sign_envelope``), submitted as they are."""
+        req = envelope if envelope is not None else encode(TestRequest(
             client_id=client_id, request_id=request_id, payload=payload
         ))
         return await self.set.submit(
