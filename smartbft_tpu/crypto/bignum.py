@@ -73,9 +73,24 @@ def from_limbs(arr) -> int:
     return x
 
 
+def bytes_to_limbs(raw: bytes, nlimbs: int) -> np.ndarray:
+    """Little-endian values of ``2 * nlimbs`` bytes each, joined ->
+    (B, nlimbs) uint32 limbs: one read and one widening cast."""
+    return np.frombuffer(raw, "<u2").astype(np.uint32).reshape(-1, nlimbs)
+
+
 def batch_to_limbs(xs, nlimbs: int) -> np.ndarray:
-    """List of Python ints -> (B, nlimbs) uint32."""
-    return np.stack([to_limbs(x, nlimbs) for x in xs])
+    """List of Python ints -> (B, nlimbs) uint32, in bulk from bytes:
+    what stacking :func:`to_limbs` of each value gives, with no
+    interpreted work per limb."""
+    width = nlimbs * LIMB_BITS // 8
+    try:
+        raw = b"".join([x.to_bytes(width, "little") for x in xs])
+    except OverflowError as exc:  # int.to_bytes: negative, or too wide
+        raise ValueError(
+            "negative, or overflow: value does not fit in %d limbs" % nlimbs
+        ) from exc
+    return bytes_to_limbs(raw, nlimbs)
 
 
 # ---------------------------------------------------------------------------

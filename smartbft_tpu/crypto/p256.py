@@ -340,14 +340,24 @@ def verify_int(pub, msg: bytes, r: int, s: int) -> bool:
 # host <-> kernel marshalling
 # ---------------------------------------------------------------------------
 
+def hashes_to_limbs(msgs) -> np.ndarray:
+    """SHA-256 of each message as a row of 16 limbs (the kernel's ``e``
+    input), in bulk: the big-endian digests reversed, joined and read
+    once.  ``hashlib`` lets go of the interpreter lock for a message
+    over 2047 bytes."""
+    raw = b"".join([hashlib.sha256(m).digest()[::-1] for m in msgs])
+    return bn.bytes_to_limbs(raw, NLIMBS)
+
+
 def hash_to_limbs(msg: bytes) -> np.ndarray:
-    """SHA-256(msg) as a 16-limb vector (the kernel's ``e`` input)."""
-    return bn.to_limbs(int.from_bytes(hashlib.sha256(msg).digest(), "big"), NLIMBS)
+    """SHA-256(msg) as a 16-limb vector."""
+    return hashes_to_limbs([msg])[0]
 
 
 def verify_inputs(items) -> tuple[np.ndarray, ...]:
-    """[(msg, r, s, (qx,qy)), ...] -> stacked (B,16) kernel inputs."""
-    e = np.stack([hash_to_limbs(m) for m, _, _, _ in items])
+    """[(msg, r, s, (qx,qy)), ...] -> stacked (B,16) kernel inputs, each
+    column built from bytes in one pass (:func:`bn.batch_to_limbs`)."""
+    e = hashes_to_limbs([m for m, _, _, _ in items])
     r = bn.batch_to_limbs([r for _, r, _, _ in items], NLIMBS)
     s = bn.batch_to_limbs([s for _, _, s, _ in items], NLIMBS)
     qx = bn.batch_to_limbs([q[0] for _, _, _, q in items], NLIMBS)

@@ -152,9 +152,9 @@ def g_table() -> np.ndarray:
 def pack_items(items, registry) -> tuple:
     """Fast host prep: items -> ((B,32) uint8 e/r/s little-endian, kidx).
 
-    Transfers to the device at 96 B/sig instead of the 192 B/sig of padded
-    uint32 limb arrays, and avoids the pure-Python per-limb conversion
-    loops of :func:`p256.verify_inputs` (~17 us/sig) in favor of C-speed
+    Transfers to the device at 96 B/sig instead of the 192 B/sig of the
+    padded uint32 limb arrays that :func:`p256.verify_inputs` hands the
+    arbitrary-key kernel (built from bytes as here, since PR 29): C-speed
     ``int.to_bytes`` + ``frombuffer`` (~1 us/sig).  Raises ValueError via
     the registry for unregistrable keys.
     """

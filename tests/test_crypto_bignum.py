@@ -34,6 +34,49 @@ def test_limb_roundtrip():
         bn.to_limbs(2**256, 16)
 
 
+def assert_same_limbs(got, want):
+    """Element for element, and as the kernels are handed it."""
+    assert got.shape == want.shape and got.dtype == want.dtype == np.uint32
+    assert got.flags.c_contiguous
+    assert np.array_equal(got, want)
+
+
+#: where packing from bytes goes wrong: the ends of the range, and values
+#: whose top one, two and sixteen bytes are zero
+BULK_CASES = {
+    "random": (rnd_batch(2**256, 64), 16),
+    "ends": ([0, 1, P256_N - 1, 2**256 - 1, 0xFFFF, 0x10000], 16),
+    "top_byte_zero": ([2**248 - 1, 2**247 + 5, rng.randrange(2**248)], 16),
+    "top_two_bytes_zero": ([2**240 - 1, rng.randrange(2**240)], 16),
+    "top_sixteen_bytes_zero": ([2**128 - 1, rng.randrange(2**128), 2**128], 16),
+    "batch_of_one": ([rng.randrange(2**256)], 16),
+    "four_limbs": ([0, 5, 2**64 - 1, rng.randrange(2**64)], 4),
+    "bls_width": ([0, 2**384 - 1] + rnd_batch(2**381, 6), 24),
+    "none": ([], 16),
+}
+
+
+@pytest.mark.parametrize("case", BULK_CASES)
+def test_batch_to_limbs_equals_to_limbs_of_each(case):
+    """The bulk build from bytes against the per-integer reference."""
+    xs, nlimbs = BULK_CASES[case]
+    want = np.stack([bn.to_limbs(x, nlimbs) for x in xs]) if xs \
+        else np.zeros((0, nlimbs), np.uint32)
+    got = bn.batch_to_limbs(xs, nlimbs)
+    assert_same_limbs(got, want)
+    assert [bn.from_limbs(row) for row in got] == xs
+
+
+@pytest.mark.parametrize("xs", [[2**256], [1, 2**256 + 7, 2], [-1], [3, -5]],
+                         ids=["overflow", "overflow_among", "negative",
+                              "negative_among"])
+def test_batch_to_limbs_refuses_what_to_limbs_refuses(xs):
+    with pytest.raises(ValueError):
+        np.stack([bn.to_limbs(x, 16) for x in xs])
+    with pytest.raises(ValueError):
+        bn.batch_to_limbs(xs, 16)
+
+
 def test_mul_full_matches_python():
     xs, ys = rnd_batch(2**256, 8), rnd_batch(2**256, 8)
     F = bn.mul_full(jnp.asarray(bn.batch_to_limbs(xs, 16)),

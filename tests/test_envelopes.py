@@ -10,6 +10,7 @@ are booleans and ledgers are bytes: every comparison is exact.
 
 import asyncio
 import dataclasses
+import hashlib
 import random
 
 import numpy as np
@@ -116,6 +117,32 @@ def test_the_envelope_layout_is_the_unsigned_request_plus_a_trailer():
 # -- (i) the engine's split ----------------------------------------------------
 
 
+def one_flush(ev, lanes) -> tuple:
+    """``lanes``: ``("vote", item)`` / ``("env", raw)`` in submission order
+    -> the flush's items and, by lane, the cause of each envelope refused
+    before any device work."""
+    items, refused = [], {}
+    for n, (kind, x) in enumerate(lanes):
+        if kind == "vote":
+            items.append(x)
+            continue
+        try:
+            items.append(ev.item(x))
+        except EnvelopeRejected as e:
+            refused[n] = e.cause
+    return items, refused
+
+
+def judged(eng, items, refused, lanes, ch) -> tuple:
+    """-> (the system's verdict, OpenSSL's) lane by lane."""
+    verdicts = iter(eng.verify(items))
+    got = [False if n in refused else next(verdicts)
+           for n in range(len(lanes))]
+    return got, [openssl_accepts_vote(x) if kind == "vote"
+                 else ch.accepts(x)
+                 for kind, x in lanes]
+
+
 def test_a_mixed_flush_is_one_comb_and_one_generic_launch_in_order(
         monkeypatch):
     """A ring of 4 and 40 other keys in ONE flush: the ring's lanes ride
@@ -155,25 +182,13 @@ def test_a_mixed_flush_is_one_comb_and_one_generic_launch_in_order(
     # one flush, votes scattered among the envelopes' lanes
     lanes = [("vote", v) for v in votes] + [("env", e) for e in envelopes]
     rng.shuffle(lanes)
-    items, refused = [], {}
-    for n, (kind, x) in enumerate(lanes):
-        if kind == "vote":
-            items.append(x)
-            continue
-        try:
-            items.append(ev.item(x))
-        except EnvelopeRejected as e:
-            refused[n] = e.cause
+    items, refused = one_flush(ev, lanes)
     assert set(refused.values()) == {"not_enrolled"} and len(refused) == 3
 
     for pub in ring:  # what the ring's first vote wave does
         eng._comb.registry.register(pub)
     slots = eng._comb.registry.slots()
-    verdicts = iter(eng.verify(items))
-    got = [False if n in refused else next(verdicts)
-           for n in range(len(lanes))]
-    want = [openssl_accepts_vote(x) if kind == "vote" else ch.accepts(x)
-            for kind, x in lanes]
+    got, want = judged(eng, items, refused, lanes, ch)
     assert got == want
     assert want.count(False) == 2 + 15 and want.count(True) == 6 + 25
 
@@ -191,6 +206,83 @@ def test_a_mixed_flush_is_one_comb_and_one_generic_launch_in_order(
     assert eng._comb._pending_prewarm == []
     assert len(eng._comb.registry) == 4
     assert eng._comb.registry.slots() == slots
+
+
+def test_leading_zero_bytes_in_r_s_and_digest_are_judged_as_openssl_does(
+        monkeypatch):
+    """The launch packs ``r``, ``s``, the key and the digest from bytes; a
+    value whose top bytes are zero is where that goes wrong.  A ring of 4
+    and 30 other keys in ONE flush on the XLA P-256 kernel (a CPU has no
+    other: both key classes ride it, each on its ladder), with envelopes
+    and votes SEARCHED for a zero top byte in ``r``, in ``s`` and in the
+    digest of the signed part (two for a digest), and the five forgeries
+    among them: verdicts equal OpenSSL's lane by lane, in submission
+    order."""
+    monkeypatch.delenv("SMARTBFT_PALLAS", raising=False)
+    rings = Keyring.generate([1, 2, 3, 4], seed=b"zeros", scheme=p256)
+    ring = [rings[1].public_keys[i] for i in (1, 2, 3, 4)]
+    eng = JaxVerifyEngine(pad_sizes=(64,), scheme=p256, ring=ring,
+                          request_pad_sizes=(64,))
+    ch = Channel(30, 3)
+    rng = ch.rng
+    digest = lambda signed: hashlib.sha256(signed).digest()  # noqa: E731
+
+    def search(make, found, tries=1 << 18):
+        for _ in range(tries):
+            x = make()
+            if found(x):
+                return x
+        raise AssertionError("no such value in %d tries" % tries)
+
+    # envelopes: signing is randomised, so the same envelope signed again
+    # gives another (r, s); another payload gives another digest
+    zero_r = [search(lambda: ch.honest(i, "zr"), lambda raw: raw[-64] == 0)
+              for i in (0, 1, 2)]
+    zero_s = [search(lambda: ch.honest(i, "zs"), lambda raw: raw[-32] == 0)
+              for i in (3, 4, 5)]
+    zero_e = [search(lambda: ch.honest(6, "ze"),
+                     lambda raw: digest(split_envelope(raw)[0])[0] == 0),
+              search(lambda: ch.honest(7, "ze2"),
+                     lambda raw: digest(split_envelope(raw)[0])[:2] == b"\0\0")]
+    # a forgery OF a value with a zero top byte: its zero byte stays, a
+    # bit of the other half flips
+    forged_zero = [flip(zero_r[0], len(zero_r[0]) - 10, 0x01),
+                   flip(zero_s[0], len(zero_s[0]) - 40, 0x80)]
+    plain = [ch.honest(i, "p") for i in range(8, 20)]
+    forged = [ch.forged(20 + n, "f", how) for n, how in enumerate(FORGERIES)]
+    envelopes = zero_r + zero_s + zero_e + forged_zero + plain + forged
+    # votes of the ring, two of them with a zero top byte too
+    votes = []
+    for k, want_zero in enumerate((None, 0, None, 32, None, None)):
+        i, msg = 1 + k % 4, rng.randbytes(48)
+        sig = search(lambda: p256.sign_raw(rings[i].private_key, msg),
+                     lambda sig: want_zero is None or sig[want_zero] == 0)
+        if k == 4:
+            sig = flip(sig, 5, 0x10)
+        votes.append(p256.make_item(msg, sig, ring[i - 1]))
+    assert sum(v[1] < 1 << 248 for v in votes) >= 1 \
+        and sum(v[2] < 1 << 248 for v in votes) >= 1
+
+    ev = EnvelopeVerifier(ch.enrolled, engine=eng)
+    lanes = [("vote", v) for v in votes] + [("env", e) for e in envelopes]
+    rng.shuffle(lanes)
+    items, refused = one_flush(ev, lanes)
+    assert refused and set(refused.values()) == {"not_enrolled"}
+    # the lanes the search was for are really in the flush
+    outside = [it for it in items if it[-1] not in ring]
+    assert sum(it[1] < 1 << 248 for it in outside) >= 4  # r
+    assert sum(it[2] < 1 << 248 for it in outside) >= 4  # s
+    assert sum(digest(it[0])[0] == 0 for it in outside) >= 2
+    assert sum(digest(it[0])[:2] == b"\0\0" for it in outside) >= 1
+
+    got, want = judged(eng, items, refused, lanes, ch)
+    assert got == want
+    assert want.count(False) == 1 + 2 + 5
+    assert want.count(True) == 5 + 8 + 12
+    s = eng.stats
+    assert s.launches_by_kernel == {"comb": 0, "pallas": 0, "xla": 2,
+                                    "host": 0}
+    assert s.used_by_kernel["xla"] == len(items) == 6 + 27 - len(refused)
 
 
 def test_an_engine_told_no_ring_registers_what_it_is_handed(monkeypatch):
