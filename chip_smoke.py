@@ -15,8 +15,7 @@ line is never printed.
   corrupted lanes; every mask is compared lane by lane with the host
   verifier (OpenSSL for P-256, the host implementation for Ed25519).
 * cluster — BASELINE.json configs[2] through the normal entry points, via
-  ``benchmarks/throughput.py:run_cluster`` exactly as ``bench.py`` reaches
-  it: 64 replicas (``Consensus`` started through ``App``), one shared
+  ``benchmarks/throughput.py:run_cluster``: 64 replicas (``Consensus`` started through ``App``), one shared
   ``JaxVerifyEngine`` + dedupe ``AsyncBatchCoalescer``, RequestBatch 500,
   pipeline depth 16, group-commit WALs on the native framing library,
   every commit vote a real P-256 signature.  4000 requests must land
@@ -324,8 +323,8 @@ def phase_cluster(log: CompileLog, n: int = 64, requests: int = 4000,
         raise AssertionError(
             f"compiled during the cluster run: {log.events[mark:]}")
 
-    # the plain reference: bench.py's CPU row — per-replica OpenSSL
-    # engines, no shared coalescer, no dedupe, no pipelining
+    # the plain reference: per-replica OpenSSL engines, no shared
+    # coalescer, no dedupe, no pipelining
     ref_row, ref_committed = run("openssl", pipeline=1)
     if set(ref_committed) != set(committed):
         raise AssertionError("the OpenSSL reference committed another set")

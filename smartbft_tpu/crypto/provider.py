@@ -976,6 +976,12 @@ class AsyncBatchCoalescer:
     def breaker_open(self) -> bool:
         return self._breaker_is_open
 
+    @property
+    def busy(self) -> bool:
+        """Submissions are waiting for a flush or riding a launch."""
+        return bool(self._pending) or self._launch_inflight \
+            or self._flush_scheduled
+
     def fault_snapshot(self) -> dict:
         """One JSON-able dict for bench rows: breaker state + fault counts,
         so a degraded run is never silently reported as a device run."""

@@ -784,7 +784,7 @@ def tpu_counters_aggregate(providers: Sequence[InMemoryProvider]) -> dict:
 
 # ---------------------------------------------------------------------------
 # Commit-latency accounting (the open-loop service surface: README
-# "Overload behavior", benchmarks/openloop.py, bench.py --open-loop)
+# "Overload behavior", testing/load.py)
 # ---------------------------------------------------------------------------
 
 
@@ -896,34 +896,6 @@ class LogScaleHistogram:
             self.max_seen = other.max_seen
         if other.min_seen < self.min_seen:
             self.min_seen = other.min_seen
-
-    def export_state(self) -> dict:
-        """JSON-able FULL state (geometry + raw buckets) — the wire shape
-        the per-shard affinity-sweep workers ship to the parent so the
-        merged percentiles come from :meth:`merge_from`'s exact bucket
-        sum, never a percentile-of-percentiles."""
-        return {
-            "low": self.low,
-            "growth": self.growth,
-            "buckets": list(self.buckets),
-            "count": self.count,
-            "total": self.total,
-            "max_seen": self.max_seen,
-            "min_seen": self.min_seen if self.count else None,
-        }
-
-    @classmethod
-    def from_state(cls, state: dict) -> "LogScaleHistogram":
-        """Rebuild a histogram from :meth:`export_state` output."""
-        h = cls(low=state["low"], growth=state["growth"],
-                nbuckets=len(state["buckets"]))
-        h.buckets = [int(n) for n in state["buckets"]]
-        h.count = int(state["count"])
-        h.total = float(state["total"])
-        h.max_seen = float(state["max_seen"])
-        if state.get("min_seen") is not None:
-            h.min_seen = float(state["min_seen"])
-        return h
 
     def nonzero_buckets(self) -> dict:
         """Sparse bucket dump for the bench row's ``histogram`` block:
@@ -1107,9 +1079,8 @@ class ProtocolPlaneTimers:
     registration, and (in any real transport) per-recipient codec work.
     These counters make that cost measured instead of asserted: the
     in-process network, the controller dispatch, and the views accumulate
-    wall-time (microseconds) and call counts here, and every
-    ``bench.py`` / ``benchmarks/throughput.py`` JSON row exports a
-    ``protocol_plane`` block from a snapshot delta.
+    wall-time (microseconds) and call counts here; a snapshot delta over
+    a window is what ``chipbench``'s ``protocol_us_per_decision`` reads.
 
     Accumulation is a couple of float adds per WAVE (never per message),
     so the accounting itself stays off the path it measures.  The four

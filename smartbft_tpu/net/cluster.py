@@ -90,7 +90,7 @@ class ControlClient:
     reachability property, now one reconnect away instead of free); a
     failure on a fresh connection propagates, since retrying it would
     just fail the same way.  ``stats`` counts connects / calls / reuses /
-    reconnects so benches can prove the pooling actually pools.
+    reconnects so a test can prove the pooling actually pools.
 
     The one-retry policy is safe for ``cmd=submit`` because the request
     pool deduplicates by (client_id, request_id): if the first attempt's
@@ -358,20 +358,6 @@ class SocketCluster:
 
     def control(self, node_id: int) -> ControlClient:
         return self.replicas[node_id].control
-
-    def control_stats(self) -> dict:
-        """Aggregate pooled-control-channel stats across every replica's
-        client: connects / calls / reuses / reconnects, plus the reuse
-        fraction the read benches report (1.0 = after the first call,
-        every call rode an existing connection)."""
-        total = {"connects": 0, "calls": 0, "reuses": 0, "reconnects": 0}
-        for h in self.replicas.values():
-            for k in total:
-                total[k] += h.control.stats[k]
-        total["reuse_fraction"] = (
-            total["reuses"] / total["calls"] if total["calls"] else 0.0
-        )
-        return total
 
     def leader_of(self) -> int:
         for i in self.live_ids():

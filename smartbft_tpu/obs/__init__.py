@@ -8,7 +8,7 @@ one attribute check when tracing is off), the account of the last
 interval the profiler was on (:func:`last_summary`), a :class:`~smartbft_tpu.obs.vcphases.ViewChangePhaseTracker` that
 decomposes the complain → depose → ViewData → new-view → first-commit
 pipeline into measured sub-phases, and the pure ``assemble_*`` helpers
-that fold either into bench-row JSON blocks.  ``python -m
+that fold either into JSON-able blocks.  ``python -m
 smartbft_tpu.obs.report`` renders a recorder dump as a text timeline +
 per-span-type percentile summary.
 """

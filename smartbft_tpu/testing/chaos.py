@@ -46,6 +46,7 @@ import asyncio
 import dataclasses
 import math
 import random
+import time
 from dataclasses import dataclass, field
 from typing import Callable, Optional
 
@@ -334,6 +335,7 @@ class ChaosCluster:
         #: deadline, retry/backoff, host-fallback breaker, canary probe
         self.engine: Optional[object] = None
         self.coalescer = None
+        self.byzantine = byzantine
         self.verify_metrics = None  # InMemoryProvider backing the breaker counters
         crypto_fn: Callable[[int], Optional[object]] = lambda i: None
         if engine_faults:
@@ -599,6 +601,23 @@ class ChaosCluster:
                 if lead:
                     return lead
         return 0
+
+    async def _verify_plane_settled(self, limit: float = 1.0) -> None:
+        """Hold the LOGICAL clock while the forgery-rejecting cluster's
+        shared verify plane has submissions waiting or a launch in flight
+        (at most ``limit`` real seconds a tick).  Its engine runs on a
+        worker thread in real time while a tick of this loop is 50 logical
+        ms: on a loaded machine one launch outlasted the 2 s heartbeat and
+        1 s forward timers, a replica started a view change nobody joined,
+        and the requests in its pool kept their timers stopped for good.
+        The engine-fault cluster is left alone: its launches hang on
+        purpose and its timers are sized for that."""
+        if not self.byzantine:
+            return
+        co = self.coalescer
+        deadline = time.monotonic() + limit
+        while co.busy and time.monotonic() < deadline:
+            await asyncio.sleep(0.0005)
 
     def healthy_apps(self) -> list[App]:
         """Live apps with no active injected fault — pump targets."""
@@ -966,6 +985,7 @@ class ChaosCluster:
                 raise TimeoutError("chaos run exceeded the hard 1h logical cap")
             # 5. advance logical time in lockstep with the loop
             await asyncio.sleep(0)
+            await self._verify_plane_settled()
             self.scheduler.advance_by(step)
             await asyncio.sleep(0.001)
             now += step

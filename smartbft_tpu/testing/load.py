@@ -9,8 +9,8 @@ tail latency and shed rate under that pressure, not on burst tx/s.  This
 module is the shared arrival machinery for everything that measures that:
 
 * :class:`OpenLoopPump` — a Poisson arrival schedule (exponential gaps)
-  against an EXTERNAL clock, so the same pump paces wall-clock benches
-  (``benchmarks/openloop.py``) and logical-clock tier-1 tests (advance
+  against an EXTERNAL clock, so the same pump paces wall-clock runs
+  (under a ``WallClockDriver``) and logical-clock tier-1 tests (advance
   the scheduler, ask the pump what is due);
 * :class:`ZipfClients` — client ids drawn from a Zipf(s) popularity
   distribution, the canonical skewed-workload shape (Mir-BFT treats

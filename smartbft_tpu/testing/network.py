@@ -23,10 +23,7 @@ vectorized so fan-out costs O(1) codec work instead of O(n):
   ``Consensus.handle_message_batch`` in one call, so a quorum wave of votes
   registers in one scheduler tick instead of ~n call chains.
 
-``Network(naive=True)`` disables all three (per-recipient encode,
-per-delivery decode, per-message dispatch) — the pre-vectorization plane,
-kept as the A/B baseline for the message-plane microbench and regression
-tests.  All costs and call counts feed :data:`smartbft_tpu.metrics.
+All costs and call counts feed :data:`smartbft_tpu.metrics.
 PROTOCOL_PLANE` by default, or a per-group plane in sharded mode.
 
 **Consensus groups (sharded mode).**  Transport keys are namespaced by a
@@ -55,7 +52,6 @@ from ..messages import (
     Message,
     deep_copy_message,
     marshal,
-    unmarshal,
     unmarshal_interned,
     wire_of,
 )
@@ -67,8 +63,8 @@ INCOMING_BUFFER = 1000  # network.go:18-20
 
 
 def _marshal_timed(msg: Message, plane) -> bytes:
-    """Plain (un-memoized) encode with codec accounting — the naive plane's
-    per-recipient cost, and the path mutated (per-target) copies take."""
+    """Plain (un-memoized) encode with codec accounting — the path
+    mutated (per-target) copies take."""
     span = _REC.begin("codec") if _REC.enabled else None
     t0 = perf_counter()
     w = marshal(msg)
@@ -77,19 +73,6 @@ def _marshal_timed(msg: Message, plane) -> bytes:
         _REC.end(span)
     plane.encodes += 1
     return w
-
-
-def _unmarshal_timed(data: bytes, plane) -> Message:
-    span = _REC.begin("codec") if _REC.enabled else None
-    try:
-        t0 = perf_counter()
-        m = unmarshal(data)
-        plane.codec_us += (perf_counter() - t0) * 1e6
-    finally:
-        if span is not None:
-            _REC.end(span)
-    plane.decodes += 1
-    return m
 
 
 class Node:
@@ -140,7 +123,7 @@ class Node:
         """Wave-batched drain: each wakeup collects EVERYTHING already
         queued and dispatches it as one batch — a whole prepare/commit wave
         registers in one ``handle_message_batch`` call instead of ~n
-        per-message call chains (naive mode dispatches per message)."""
+        per-message call chains."""
         while True:
             item = await self._inbox.get()
             batch: list = []
@@ -174,7 +157,6 @@ class Node:
         t0 = perf_counter()
         codec0 = plane.codec_us
         vote0 = plane.vote_reg_us
-        naive = self.network.naive
         token = install_plane(plane)
         try:
             run: list = []  # consecutive consensus (sender, msg) pairs
@@ -183,10 +165,7 @@ class Node:
                     msg = payload
                     if isinstance(payload, (bytes, bytearray)):
                         try:
-                            if naive:
-                                msg = _unmarshal_timed(payload, plane)
-                            else:
-                                msg = unmarshal_interned(payload, plane)
+                            msg = unmarshal_interned(payload, plane)
                         except CodecError:
                             self.malformed += 1
                             plane.malformed_dropped += 1
@@ -214,18 +193,17 @@ class Node:
         if not run:
             return
         c = self.consensus
-        if not self.network.naive:
-            batch_async = getattr(c, "handle_message_batch_async", None)
-            if batch_async is not None:
-                await batch_async(list(run))
-                run.clear()
-                return
-            batch_sync = getattr(c, "handle_message_batch", None)
-            if batch_sync is not None:
-                batch_sync(list(run))
-                run.clear()
-                return
-        # naive mode / injected doubles without the batch surface
+        batch_async = getattr(c, "handle_message_batch_async", None)
+        if batch_async is not None:
+            await batch_async(list(run))
+            run.clear()
+            return
+        batch_sync = getattr(c, "handle_message_batch", None)
+        if batch_sync is not None:
+            batch_sync(list(run))
+            run.clear()
+            return
+        # injected doubles without the batch surface
         for sender, msg in run:
             # async intake: a backpressure-configured cluster blocks THIS
             # node's delivery task on a full component inbox (the
@@ -308,10 +286,6 @@ class Node:
 class Network:
     """The mesh (network.go:34-74).
 
-    ``naive=True`` reverts to the pre-vectorization message plane — one
-    encode per recipient, one decode per delivery, per-message dispatch —
-    as the A/B baseline for the message-plane microbench.
-
     ``plane`` is the default cost-attribution sink (the process-wide
     :data:`~smartbft_tpu.metrics.PROTOCOL_PLANE` unless given); per-GROUP
     planes registered via :meth:`group` override it for that group's
@@ -319,8 +293,7 @@ class Network:
     reuse node ids 1..n without inbox collisions; ``self.nodes`` stays the
     group-0 map so every pre-sharding caller is untouched."""
 
-    def __init__(self, seed: int = 0, naive: bool = False, plane=None):
-        self.naive = naive
+    def __init__(self, seed: int = 0, plane=None):
         self.plane = PROTOCOL_PLANE if plane is None else plane
         self.rng = random.Random(seed)
         self._groups: dict[int, dict[int, Node]] = {0: {}}
@@ -405,9 +378,7 @@ class Network:
                 return
         plane = self.plane_of(group)
         plane.sends += 1
-        wire = _marshal_timed(msg, plane) if self.naive \
-            else wire_of(msg, plane)
-        dst._offer("consensus", source, wire)
+        dst._offer("consensus", source, wire_of(msg, plane))
 
     def broadcast_consensus(self, source: int, msg: Message,
                             targets: Optional[list[int]] = None,
@@ -443,7 +414,7 @@ class Network:
         t0 = perf_counter()
         codec0 = plane.codec_us
         wire: Optional[bytes] = None
-        if not self.naive and src.mutate_send is None:
+        if src.mutate_send is None:
             wire = wire_of(msg, plane)  # ONE encode for the whole fan-out
         target_ids = targets if targets is not None else gmap
         for target in target_ids:
@@ -471,7 +442,7 @@ class Network:
             if veto:
                 continue
             if w is None:
-                if not self.naive and m == msg:
+                if m == msg:
                     # hook did not change this target's copy
                     w = wire_of(msg, plane)
                 else:
@@ -559,10 +530,6 @@ class GroupNet:
     def __init__(self, network: Network, gid: int):
         self.network = network
         self.gid = gid
-
-    @property
-    def naive(self) -> bool:
-        return self.network.naive
 
     @property
     def plane(self):

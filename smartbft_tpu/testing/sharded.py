@@ -10,8 +10,8 @@ while EVERY replica of EVERY shard verifies through one shared
 ``AsyncBatchCoalescer`` (each provider tagged with its shard id), so
 quorum waves from different shards coalesce into common launches.  That
 shared plane is the whole point: it is what the cross-shard-coalescing
-tier-1 gate (tests/test_sharded.py) and the ``benchmarks/sharded.py``
-sweep measure, and what the ``--shards`` chaos soak stresses.
+tier-1 gate (tests/test_sharded.py) pins and what the ``--shards`` chaos
+soak stresses.
 
 Crypto modes:
 
@@ -21,8 +21,8 @@ Crypto modes:
   genuinely traverse the shared coalescer (and its fault machinery when
   ``engine_faults=True`` wraps the engine in a FaultyEngine).
 * ``"p256"`` / ``"ed25519"`` — real per-shard keyrings + CryptoProviders
-  over a caller-supplied (or host-default) shared engine: the bench
-  configuration.
+  over a caller-supplied (or host-default) shared engine: what
+  ``chipbench``'s deployments build.
 """
 
 from __future__ import annotations
@@ -357,7 +357,6 @@ class ShardedCluster:
         seed: int = 7,
         router_seed: int = 0,
         config_fn: Optional[Callable[[int, int], Configuration]] = None,
-        naive: bool = False,
         reshard_drain_deadline: Optional[float] = None,
         mux_retention: int = 4096,
         collect_entries: bool = False,
@@ -385,7 +384,7 @@ class ShardedCluster:
         self.n = n
         self.depth = depth
         self.scheduler = Scheduler()
-        self.network = Network(seed=seed, naive=naive)
+        self.network = Network(seed=seed)
         self.verify_metrics_provider = InMemoryProvider()
         tpu_metrics = TPUCryptoMetrics(self.verify_metrics_provider)
 
@@ -741,12 +740,6 @@ class ShardedCluster:
         return [r for r in self._recorders.values()
                 if r.enabled or r.recorded]
 
-    def trace_block(self) -> dict:
-        """The merged ``trace`` bench-row block (pure assemble helper)."""
-        from ..obs import assemble_trace_block
-
-        return assemble_trace_block(self.trace_recorders())
-
     def trace_events(self) -> list[dict]:
         """Every recorder's buffered events merged chronologically — ONE
         timeline already (all recorders share the cluster scheduler
@@ -762,22 +755,6 @@ class ShardedCluster:
         from ..obs import assemble_critical_path_block
 
         return assemble_critical_path_block(self.trace_events(), **kw)
-
-    def vc_trackers(self) -> list:
-        """Every live replica's view-change phase tracker — the
-        ``viewchange`` bench-row block's input (always available; the
-        tracker runs whether or not event tracing is on)."""
-        return [
-            a.consensus.vc_phases
-            for sh in self.shard_list
-            for a in sh.live_apps()
-            if a.consensus is not None
-        ]
-
-    def viewchange_block(self) -> dict:
-        from ..obs import assemble_viewchange_block
-
-        return assemble_viewchange_block(self.vc_trackers())
 
     def dump_flight_recorders(self, out_dir: str) -> list:
         """Write each recorder's buffered spans to ``out_dir`` as

@@ -468,41 +468,6 @@ def test_wal_append_and_group_fsync_spans(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# bench row: the critical_path block rides the open-loop row
-# ---------------------------------------------------------------------------
-
-
-def test_open_loop_row_carries_critical_path_block():
-    from bench import assemble_open_loop_row
-
-    sweep_row = {
-        "bench": "openloop", "offered_per_sec": 100.0,
-        "goodput_per_sec": 95.0, "shards": 2, "zipf_skew": 1.1,
-        "admission_high_water": 0.8,
-        "open_loop": {"shed_rate": 0.0, "shed_admission": 0,
-                      "shed_timeout": 0, "peak_occupancy": 10},
-        "latency": {"p99_ms": 50.0, "shed": {}},
-    }
-    critical = {
-        "requests_decomposed": 40, "sums_consistent": True,
-        "dominant_segment": "commit_wave", "worst_residual_ms": 0.0,
-        "segments": {}, "phases": {
-            "view_change": {"dominant_segment": "propose_wait"},
-        },
-    }
-    degraded = {
-        "metric": "open_loop_degraded", "phases": {}, "notes": {},
-        "viewchange": {}, "trace": {}, "critical_path": critical,
-    }
-    knee = {"metric": "open_loop_knee", "slo": "x", "last_ok": None,
-            "first_overloaded": None, "beyond_sweep": True}
-    row = assemble_open_loop_row([sweep_row, knee, degraded])
-    assert row["critical_path"]["sums_consistent"] is True
-    assert row["critical_path"]["phases"]["view_change"][
-        "dominant_segment"] == "propose_wait"
-
-
-# ---------------------------------------------------------------------------
 # reshard generations: fresh recorder labels, no cross-generation merge
 # ---------------------------------------------------------------------------
 

@@ -510,72 +510,6 @@ def test_control_client_pools_and_reconnects():
 
 
 # ---------------------------------------------------------------------------
-# selfdrive bench rows + the baseline oscillation guard
-
-
-def _storm_stats(**over):
-    stats = {"seed": 1, "faults": 3, "actions": 3, "actions_ok": 3,
-             "scale_out": 1, "scale_in": 1, "retune": 1, "reversals": 0,
-             "vetoes": {"veto_breaker": 2}, "ctl_spans": 3,
-             "clear_spans": 2, "verdict_samples": 40,
-             "final_status": "healthy", "peak_fill": 0.9,
-             "fill_at_scale_out": 0.215}
-    stats.update(over)
-    return stats
-
-
-def test_selfdrive_rows_validate_and_identify():
-    from smartbft_tpu.obs.benchschema import (
-        assemble_selfdrive_rows, identify_row, validate_rows)
-
-    rows = assemble_selfdrive_rows(_storm_stats())
-    assert [r["metric"] for r in rows] == [
-        "selfdrive_actions_per_fault", "selfdrive_oscillation_reversals"]
-    assert rows[0]["value"] == 1.0
-    assert rows[0]["unit"] == "actions/fault"
-    assert rows[1]["value"] == 0.0
-    assert validate_rows(rows) == []
-    assert identify_row(rows[0]) == "selfdrive_*"
-    # the oscillation row is an EXACT family so it carries its own
-    # (tighter) re-pin threshold
-    assert identify_row(rows[1]) == "selfdrive_oscillation_reversals"
-    with pytest.raises(ValueError):
-        assemble_selfdrive_rows({"faults": 0, "actions": 1})
-
-
-def test_selfdrive_baseline_guard_trips_on_thrash_and_oscillation():
-    import os
-
-    from smartbft_tpu.obs.baseline import check_rows, load_baseline
-    from smartbft_tpu.obs.benchschema import assemble_selfdrive_rows
-
-    path = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "BASELINE_OBS.json")
-    base = load_baseline(path)
-    assert "selfdrive_actions_per_fault" in base["rows"]
-    assert "selfdrive_oscillation_reversals" in base["rows"]
-
-    ok = check_rows(assemble_selfdrive_rows(_storm_stats()), base)
-    assert ok["ok"], ok
-    # 2 actions/fault is the acceptance bound — AT it still passes
-    edge = check_rows(
-        assemble_selfdrive_rows(_storm_stats(actions=6)), base)
-    assert edge["ok"], edge
-    # past it: thrash
-    thrash = check_rows(
-        assemble_selfdrive_rows(_storm_stats(actions=7)), base)
-    assert not thrash["ok"]
-    assert [r["metric"] for r in thrash["regressions"]] == [
-        "selfdrive_actions_per_fault"]
-    # a single A->B->A flip fails (baseline 0: any nonzero is 100% worse)
-    osc = check_rows(
-        assemble_selfdrive_rows(_storm_stats(reversals=1)), base)
-    assert not osc["ok"]
-    assert [r["metric"] for r in osc["regressions"]] == [
-        "selfdrive_oscillation_reversals"]
-
-
-# ---------------------------------------------------------------------------
 # the full reflex arc under injected faults
 
 

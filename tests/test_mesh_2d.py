@@ -305,12 +305,15 @@ def test_2d_mesh_launch_fault_contract_deadline_retry_breaker_canary():
     mem = InMemoryProvider()
     mesh = QuorumMeshVerifyEngine(devices=8, scheme=toy_scheme, quorum=2)
     engine = FaultyEngine(mesh)
+    items, expect = toy_wave(random.Random(9), 7)
+    # as tests/test_mesh_plane.py's twin: compile outside the coalescer,
+    # and a healthy launch's deadline is one a loaded worker keeps
+    assert mesh.verify(items) == expect
     co = AsyncBatchCoalescer(
-        engine, window=0.001, policy=tight_policy(),
+        engine, window=0.001, policy=tight_policy(launch_timeout=1.0),
         fallback_engine=HostVerifyEngine(scheme=toy_scheme),
         metrics=TPUCryptoMetrics(mem),
     )
-    items, expect = toy_wave(random.Random(9), 7)
 
     async def wait_until(cond, timeout=10.0):
         deadline = time.monotonic() + timeout
@@ -322,7 +325,7 @@ def test_2d_mesh_launch_fault_contract_deadline_retry_breaker_canary():
         assert await co.submit(items) == expect  # healthy 2D launch first
         before = mesh.stats.launches
         engine.hang()
-        assert await asyncio.wait_for(co.submit(items), 10) == expect
+        assert await asyncio.wait_for(co.submit(items), 30) == expect
         assert co.fault_stats.launch_timeouts >= 1      # deadline abandon
         assert co.fault_stats.breaker_opens == 1        # breaker trip
         assert co.fault_stats.host_fallback_batches == 1

@@ -4,9 +4,7 @@
   the renderer multi-process replicas now serve over ``cmd=metrics``;
 * :class:`~smartbft_tpu.metrics.LogScaleHistogram` edge cases (empty,
   single observation, overflow past the top bucket, sparse-bucket JSON
-  round-trip through a bench row);
-* the ``viewchange``/``trace`` blocks riding ``bench.py``'s open-loop
-  row (pure assemble fn, PR 8 idiom);
+  round-trip);
 * the multi-process pull: ``ControlServer cmd=trace`` / ``cmd=metrics``
   against live socket replicas, and the dump the report tool renders.
 """
@@ -128,7 +126,7 @@ def test_overflow_past_top_bucket_clamps():
     assert h2.quantile(0.5) == pytest.approx(1e-9)
 
 
-def test_nonzero_buckets_round_trip_through_bench_row_json():
+def test_nonzero_buckets_round_trip_through_json():
     h = LogScaleHistogram()
     for v in (0.001, 0.001, 0.004, 0.1, 5.0):
         h.observe(v)
@@ -159,42 +157,6 @@ def test_merge_from_is_exact_and_rejects_mismatched_geometry():
     assert merged.buckets == one_by_one.buckets
     with pytest.raises(ValueError):
         merged.merge_from(LogScaleHistogram(nbuckets=8))
-
-
-# ---------------------------------------------------------------------------
-# bench row: the viewchange/trace blocks ride the open-loop row
-# ---------------------------------------------------------------------------
-
-
-def test_open_loop_row_carries_viewchange_and_trace_blocks():
-    from bench import assemble_open_loop_row
-
-    sweep_row = {
-        "bench": "openloop", "offered_per_sec": 100.0,
-        "goodput_per_sec": 95.0, "shards": 2, "zipf_skew": 1.1,
-        "admission_high_water": 0.8,
-        "open_loop": {"shed_rate": 0.0, "shed_admission": 0,
-                      "shed_timeout": 0, "peak_occupancy": 10},
-        "latency": {"p99_ms": 50.0, "shed": {}},
-    }
-    degraded = {
-        "metric": "open_loop_degraded",
-        "phases": {"view_change": {"p99_ms": 800.0}},
-        "notes": {},
-        "viewchange": {"count": 3, "dominant_phase": "viewdata_collect",
-                       "phases": {}, "end_to_end": {"p99_ms": 700.0},
-                       "sums_consistent": True},
-        "trace": {"enabled": True, "recorders": 9, "recorded": 1000,
-                  "dropped": 0, "kinds": {}, "spans": {}},
-    }
-    knee = {"metric": "open_loop_knee", "slo": "x",
-            "last_ok": {"offered_per_sec": 100.0}, "first_overloaded": None,
-            "beyond_sweep": True}
-    row = assemble_open_loop_row([sweep_row, knee, degraded])
-    assert row["viewchange"]["dominant_phase"] == "viewdata_collect"
-    assert row["viewchange"]["sums_consistent"] is True
-    assert row["trace"]["enabled"] is True
-    assert row["latency"]["phases"]["view_change"]["p99_ms"] == 800.0
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,5 @@
 """Overload-safe front door: admission control, bounded FIFO space waits,
-commit-latency accounting, the open-loop harness, and the bench row schema.
+commit-latency accounting and the open-loop harness.
 
 Coverage map (ISSUE 8):
 
@@ -19,9 +19,8 @@ Coverage map (ISSUE 8):
   (host-fallback phase) at fixed offered load;
 - chaos vocabulary: load_spike/load_stop timeline actions (spike past
   the knee -> sheds -> occupancy bounded -> stop -> p99 recovers);
-- bench schema: the `latency` block of bench.py --open-loop rows
-  (p50/p95/p99, shed counts, knee, per-degraded-phase percentiles)
-  pinned the way test_verify_plane pins the breaker block.
+- the pump on the wall clock: one tiny paced point under a
+  WallClockDriver.
 """
 
 import asyncio
@@ -801,116 +800,52 @@ def test_chaos_load_spike_timeline_sheds_and_recovers(tmp_path):
     asyncio.run(run())
 
 
-# -- bench row schema ---------------------------------------------------------
+# -- the pump on the wall clock ------------------------------------------------
 
-def _sweep_row(offered, goodput, p99, shed_rate=0.0):
-    return {
-        "bench": "openloop",
-        "offered_per_sec": offered,
-        "goodput_per_sec": goodput,
-        "shards": 2,
-        "zipf_skew": 1.1,
-        "admission_high_water": 0.8,
-        "open_loop": {"offered": 100, "acked": 98, "shed_admission": 1,
-                      "shed_timeout": 1, "failed": 0,
-                      "shed_rate": shed_rate, "peak_occupancy": 42,
-                      "peak_fill": 0.2, "retry_after_p50": 0.05},
-        "latency": {"count": 98, "p50_ms": 20.0, "p95_ms": 60.0,
-                    "p99_ms": p99, "mean_ms": 25.0, "max_ms": 120.0,
-                    "shed": {"admission": 1, "timeout": 1, "other": 0},
-                    "pending_stamps": 0, "dropped_stamps": 0,
-                    "per_shard": {}},
-    }
+def test_open_loop_pump_paces_on_the_wall_clock(tmp_path):
+    """One tiny WALL-clock point through ``run_open_loop(wall=True)``
+    under a ``WallClockDriver`` (n=4, 150 /s for 1 s): the branch
+    ``load_spike`` and every gate above never take (they advance the
+    logical scheduler).  Arrivals follow real time, every one is
+    accounted for, and the committed stream stamps their latency."""
+    import time
 
+    from smartbft_tpu.utils.clock import WallClockDriver
 
-def test_bench_open_loop_row_schema():
-    """ACCEPTANCE: bench.py --open-loop rows carry a `latency` block with
-    p50/p95/p99, shed counts, the knee, and per-degraded-phase
-    (breaker_open / view_change / reshard) percentiles — pinned against
-    the row assembler exactly like the breaker block pin."""
-    import bench
-
-    degraded_phases = {
-        name: {"count": 50, "p50_ms": 30.0, "p95_ms": 80.0, "p99_ms": 200.0,
-               "mean_ms": 35.0, "max_ms": 300.0,
-               "shed": {"admission": 2, "timeout": 0, "other": 0}}
-        for name in ("healthy", "breaker_open", "view_change", "reshard",
-                     "recovered")
-    }
-    rows = [
-        _sweep_row(200, 199, 80.0),
-        _sweep_row(800, 500, 900.0, shed_rate=0.3),
-        {"metric": "open_loop_knee", "slo": "goodput >= 0.9*offered and shed < 1%",
-         "last_ok": {"offered_per_sec": 200, "goodput_per_sec": 199,
-                     "p99_ms": 80.0},
-         "first_overloaded": {"offered_per_sec": 800, "goodput_per_sec": 500,
-                              "p99_ms": 900.0, "shed_rate": 0.3},
-         "beyond_sweep": False},
-        {"metric": "open_loop_degraded", "offered_per_sec": 300,
-         "phases": degraded_phases, "notes": {}},
-    ]
-    row = bench.assemble_open_loop_row(rows)
-    assert row["metric"] == "open_loop_p99_ms"
-    # the latency block anchors on the last-ok sweep point
-    lat = row["latency"]
-    assert row["offered_per_sec"] == 200 and row["value"] == 80.0
-    for key in ("count", "p50_ms", "p95_ms", "p99_ms", "max_ms"):
-        assert key in lat, f"latency block lost {key}"
-    assert lat["shed"]["shed_admission"] == 1
-    assert lat["shed"]["shed_timeout"] == 1
-    assert lat["knee"]["last_ok"]["offered_per_sec"] == 200
-    assert lat["knee"]["first_overloaded"]["shed_rate"] == 0.3
-    for phase in ("breaker_open", "view_change", "reshard"):
-        block = lat["phases"][phase]
-        assert {"p50_ms", "p95_ms", "p99_ms", "shed"} <= set(block), (
-            f"degraded phase {phase} lost its percentiles"
+    def cfg(_s, i):
+        return dataclasses.replace(
+            sharded_config(i, depth=2),
+            request_pool_size=64, admission_high_water=0.8,
+            request_pool_submit_timeout=1.0,
+            request_batch_max_count=8, request_batch_max_interval=0.02,
         )
-    # every sweep point is summarized alongside
-    assert [p["offered_per_sec"] for p in row["sweep"]] == [200, 800]
-    # with everything overloaded the block anchors on the top point
-    # (worst honest number) instead of going empty
-    rows2 = [_sweep_row(800, 500, 900.0, shed_rate=0.3),
-             {"metric": "open_loop_knee", "last_ok": None,
-              "first_overloaded": {"offered_per_sec": 800},
-              "beyond_sweep": False, "slo": "x"}]
-    row2 = bench.assemble_open_loop_row(rows2)
-    assert row2["offered_per_sec"] == 800 and row2["latency"]["phases"] == {}
 
+    async def run():
+        cluster = ShardedCluster(str(tmp_path), shards=1, n=4, depth=2,
+                                 window=0.005, config_fn=cfg, seed=17)
+        driver = WallClockDriver(cluster.scheduler, tick_interval=0.005)
+        driver.start()
+        await cluster.start()
+        try:
+            t0 = time.monotonic()
+            stats = await run_open_loop(
+                cluster, rate=150.0, duration=1.0, drain=1.5, seed=31,
+                clients=ZipfClients(64, skew=1.1), wall=True, step=0.005,
+            )
+            took = time.monotonic() - t0
+            lat = cluster.set.latency.snapshot()
+            cluster.check_invariants()
+        finally:
+            await cluster.stop()
+            await driver.stop()
+        # a Poisson draw of mean 150, five sigma either side
+        assert 89 <= stats.offered <= 211, stats.block()
+        assert took >= 1.0, f"a 1 s span of arrivals took {took:.2f} s"
+        assert stats.acked + stats.shed + stats.failed == stats.offered
+        assert stats.failed == 0 and stats.acked > 0, stats.block()
+        assert lat["count"] > 0 and 0 < lat["p99_ms"] < 1e6, lat
 
-def test_openloop_bench_sweep_point_row_shape():
-    """One REAL (tiny, wall-clock) sweep point through
-    benchmarks/openloop.py produces the row shape the assembler and the
-    schema pin above consume — the child and parent cannot drift."""
-    import argparse
-    import importlib
-
-    openloop = importlib.import_module("benchmarks.openloop")
-    args = argparse.Namespace(
-        rates="150", duration=1.0, drain=1.5, shards=1, nodes=4, batch=8,
-        pool_size=64, admission=0.8, clients=64, zipf=1.1,
-        degraded_rate=0.0, phase_duration=0.0, no_degraded=True, cpu=True,
-        no_adaptive=False, affinity="shared", sweep_shards="",
-    )
-    row = asyncio.run(openloop.run_sweep_point(150.0, args))
-    assert row["bench"] == "openloop"
-    assert row["offered_per_sec"] == 150.0
-    assert row["goodput_per_sec"] >= 0
-    assert {"p50_ms", "p95_ms", "p99_ms", "count", "shed"} <= set(row["latency"])
-    assert {"offered", "acked", "shed_rate", "peak_occupancy"} \
-        <= set(row["open_loop"])
-    # round-18 bench hygiene: rows are self-describing about loop topology
-    # and carry the honest (loopback: 0.0) RTT envelope
-    assert row["loop_affinity"] == "shared"
-    assert row["rtt_s_max"] == 0.0
-    assert row["adaptive_batching"] is True and row["batch_max"] == 8
-    knee = openloop.find_knee([row])
-    assert "last_ok" in knee and "first_overloaded" in knee
-    # the assembler consumes real child rows end-to-end
-    import bench
-
-    assembled = bench.assemble_open_loop_row([row, {"metric": "open_loop_knee",
-                                                    **knee}])
-    assert assembled["latency"]["knee"]["slo"]
+    asyncio.run(run())
 
 
 # -- config plumbing ----------------------------------------------------------

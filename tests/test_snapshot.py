@@ -5,9 +5,8 @@ possible (the full kill-rejoin-via-snapshot runs are slow-marked at the
 bottom): the pure verification functions, the crash-safe SnapshotStore,
 LedgerFile compaction/recovery, the ReplicaApp crash-point recovery
 matrix and install path, the sync-poisoning guard (satellite 2), the
-reshard snapshot handoff on the in-process App, ConfigMirror round-trip
-of the snapshot knobs, and the rejoin bench row/guard/baseline plumbing
-(satellite 5)."""
+reshard snapshot handoff on the in-process App, and ConfigMirror
+round-trip of the snapshot knobs."""
 
 import asyncio
 import dataclasses
@@ -17,19 +16,12 @@ from types import SimpleNamespace
 
 import pytest
 
-import bench
 from smartbft_tpu.codec import decode, encode
 from smartbft_tpu.core.pool import ReqAlreadyProcessedError
 from smartbft_tpu.core.util import compute_quorum
 from smartbft_tpu.messages import Proposal, Signature, ViewMetadata
 from smartbft_tpu.net.framing import WireDecision
 from smartbft_tpu.net.launch import LedgerFile, ReplicaApp
-from smartbft_tpu.obs.baseline import check_rows, load_baseline
-from smartbft_tpu.obs.benchschema import (
-    assemble_rejoin_row,
-    identify_row,
-    validate_row,
-)
 from smartbft_tpu.snapshot import (
     CHAIN_SEED,
     RECENT_IDS_CAP,
@@ -731,73 +723,6 @@ def test_config_mirror_roundtrips_snapshot_knobs():
     back = unmirror_config(mirror_config(cfg))
     assert back.snapshot_interval_decisions == 8
     assert back.snapshot_chunk_bytes == 4096
-
-
-# ---------------------------------------------------------------------------
-# satellite 5: rejoin bench rows, the flatness guard, the baseline gate
-# ---------------------------------------------------------------------------
-
-
-def _rejoin_rows(deep_snap_s=0.003):
-    return [
-        assemble_rejoin_row(history=100, mode="snapshot", rejoin_s=0.002,
-                            bytes_transferred=5000, snapshot_bytes=5000,
-                            snap_chunks=1, interval=25),
-        assemble_rejoin_row(history=100, mode="replay", rejoin_s=0.004,
-                            bytes_transferred=24000, decisions_replayed=100),
-        assemble_rejoin_row(history=100000, mode="snapshot",
-                            rejoin_s=deep_snap_s, bytes_transferred=60000,
-                            snapshot_bytes=60000, snap_chunks=1, interval=25,
-                            vs_small_history=deep_snap_s / 0.002),
-        assemble_rejoin_row(history=100000, mode="replay", rejoin_s=3.3,
-                            bytes_transferred=24000000,
-                            decisions_replayed=100000,
-                            vs_small_history=825.0),
-    ]
-
-
-def test_rejoin_rows_and_flatness_guard_validate():
-    rows = _rejoin_rows()
-    for row in rows:
-        assert identify_row(row) == "rejoin_*"
-        assert validate_row(row) == []
-    with pytest.raises(ValueError):
-        assemble_rejoin_row(history=1, mode="teleport", rejoin_s=0.0,
-                            bytes_transferred=0)
-    (guard,) = bench.rejoin_guard_rows(rows)
-    assert guard["metric"] == "rejoin_flatness_vs_depth"
-    # the exact family wins over the rejoin_* wildcard
-    assert identify_row(guard) == "rejoin_flatness_vs_depth"
-    assert validate_row(guard) == []
-    assert guard["value"] == pytest.approx(1.5)
-    assert guard["history_small"] == 100
-    assert guard["history_deep"] == 100000
-    assert guard["replay_ratio"] == pytest.approx(825.0)
-    # without both snapshot points there is no guard row
-    assert bench.rejoin_guard_rows(rows[:2]) == []
-    assert bench.rejoin_guard_rows([]) == []
-
-
-def test_rejoin_flatness_gate_fires_past_2x(tmp_path):
-    """The committed baseline pins the ratio at the ideal 1.0 with a
-    100% allowance: a 1.45x measured run passes, a 3.1x run (an O(1)
-    rejoin regression) fails the gate."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    baseline = load_baseline(os.path.join(repo, "BASELINE_OBS.json"))
-    assert "rejoin_flatness_vs_depth" in baseline["rows"]
-    (ok_row,) = bench.rejoin_guard_rows(_rejoin_rows(deep_snap_s=0.0029))
-    assert ok_row["value"] == pytest.approx(1.45)
-    res = check_rows([ok_row], baseline)
-    assert not any(r["metric"] == "rejoin_flatness_vs_depth"
-                   for r in res["regressions"])
-    assert not res["schema_errors"]
-    (bad_row,) = bench.rejoin_guard_rows(_rejoin_rows(deep_snap_s=0.0062))
-    assert bad_row["value"] == pytest.approx(3.1)
-    bad = check_rows([bad_row], baseline)
-    (reg,) = [r for r in bad["regressions"]
-              if r["metric"] == "rejoin_flatness_vs_depth"]
-    assert reg["threshold_pct"] == 100.0
-    assert reg["delta_pct"] == pytest.approx(210.0)
 
 
 # ---------------------------------------------------------------------------
