@@ -137,18 +137,25 @@ class Configuration:
     detection_backoff_base: float = 2.0
     detection_backoff_max: float = 8.0
 
-    # Flip-time backlog drain (ISSUE 15 — round 16's critical path put
-    # 98% of forced-VC request time in `propose_wait`: followers' pooled
-    # requests wait out a full request_forward_timeout before reaching
-    # the NEW leader after the flip).  When > 0, a view-flip timer
-    # restart fast-forwards the oldest
+    # Backlog drain at a change of leader (ISSUE 15 — round 16's critical
+    # path put 98% of forced-VC request time in `propose_wait`:
+    # followers' pooled requests wait out a full request_forward_timeout
+    # before reaching the NEW leader after the flip).  When > 0, a
+    # view-flip timer restart fast-forwards the oldest
     #   flip_drain_windows * pipeline_depth * request_batch_max_count
     # pooled requests (their forward timers arm at the floor instead of
     # the full timeout), so the new view's first proposals batch the
     # stalled backlog into deep windows immediately; the rest of the
     # pool keeps the ordinary timeout chain.  Leader-side pool dedup
-    # absorbs the duplicates this may forward.  0 disables (every timer
-    # restarts at the full forward timeout — reference-faithful).
+    # absorbs the duplicates this may forward.  The same leg serves a
+    # ROTATION's hand-over (ISSUE 31): the replica whose turn just ended
+    # forwards the oldest ONE window (pipeline_depth *
+    # request_batch_max_count) of what its pool still holds and has not
+    # proposed to the new leader, instead of keeping it until the lead
+    # comes round again (every rotation re-arms the forward timeout,
+    # which is longer than a turn, so there it never fired at all); the
+    # other replicas re-arm as upstream does.  0 disables both (every
+    # timer restarts at the full forward timeout — reference-faithful).
     flip_drain_windows: int = 4
 
     # State collection (config.go:64-66)

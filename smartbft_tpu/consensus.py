@@ -364,6 +364,7 @@ class Consensus:
                 admission_high_water=self.config.admission_high_water,
                 forward_timeout_fn=self._forward_timeout_fn(),
                 flip_drain_limit=self._flip_drain_limit(),
+                handover_limit=self._handover_limit(),
             ),
         )
         self._continue_create_components()
@@ -661,6 +662,14 @@ class Consensus:
                 * self.config.pipeline_depth
                 * self.config.request_batch_max_count)
 
+    def _handover_limit(self) -> int:
+        """A rotation's hand-over budget in REQUESTS: the new leader's
+        first window, and nothing where flip_drain_windows is 0
+        (ISSUE 31; PoolOptions.handover_limit says why one)."""
+        return (min(self.config.flip_drain_windows, 1)
+                * self.config.pipeline_depth
+                * self.config.request_batch_max_count)
+
     def _create_pool(self) -> None:
         """consensus.go:139-151."""
         self.pool = Pool(
@@ -677,6 +686,7 @@ class Consensus:
                 admission_high_water=self.config.admission_high_water,
                 forward_timeout_fn=self._forward_timeout_fn(),
                 flip_drain_limit=self._flip_drain_limit(),
+                handover_limit=self._handover_limit(),
             ),
             self.scheduler,
             metrics=self.metrics.pool,

@@ -56,7 +56,13 @@ from ..types import (
     blacklist_of,
     cached_view_metadata,
 )
-from .pool import Pool, RequestTimeoutHandler, remove_delivered_requests
+from .pool import (
+    AdmissionRejected,
+    Pool,
+    RequestTimeoutHandler,
+    SubmitTimeoutError,
+    remove_delivered_requests,
+)
 from .state import ABORT, COMMITTED
 from .util import InFlightData, compute_quorum, get_leader_id
 from ..utils.tasks import create_logged_task
@@ -207,6 +213,10 @@ class Controller(RequestTimeoutHandler):
         #: Absent, requests take the synchronous SPI path they always took.
         self._check_request = getattr(verifier, "verify_request_async", None)
         self.bad_forwards = 0  # forwards dropped for a bad request
+        #: forwards dropped because this node did not lead when they came
+        #: (a hand-over's or a view flip's bonus forward that outran this
+        #: node's own flip, or a forwarder behind on the leader)
+        self.not_leader_forwards = 0
         self._shed_submits = 0  # throttled info counter (submit_request)
         self._leader_memo_key = None  # (view, decisions, ckpt version) memo
         self._leader_memo = 0
@@ -370,6 +380,12 @@ class Controller(RequestTimeoutHandler):
         purely additive."""
         i_am, leader = self.i_am_the_leader()
         if not i_am:
+            self.not_leader_forwards += 1
+            rec = self.recorder
+            if rec.enabled:
+                rec.record("req.not_leader",
+                           key=str(self.request_inspector.request_id(req)),
+                           extra={"sender": sender})
             self.logger.warnf(
                 "Got request from %d but the leader is %d, dropping request", sender, leader
             )
@@ -418,6 +434,15 @@ class Controller(RequestTimeoutHandler):
         forwarded = not (self.misbehavior is not None
                          and self.misbehavior.is_shunned(sender))
         try:
+            if not self.request_pool.has_room():
+                # refused, not parked: this runs on the node's inbox task,
+                # and parked on space it would hold back the very commits
+                # that free the space, for the whole submit timeout.  The
+                # forwarder keeps its copy and its timeout chain.
+                raise AdmissionRejected(
+                    "no room in the pool for a forwarded request",
+                    retry_after=self.request_pool.retry_after_hint(),
+                    occupancy=self.request_pool.occupancy())
             await self.submit_request(req, forwarded=forwarded,
                                       verified=True)
         except Exception as e:
@@ -431,8 +456,6 @@ class Controller(RequestTimeoutHandler):
                     "Got request from %d but couldn't submit it (%d failures so far): %s",
                     sender, self._fwd_submit_failures, e,
                 )
-            from .pool import AdmissionRejected, SubmitTimeoutError
-
             if isinstance(e, (AdmissionRejected, SubmitTimeoutError)):
                 return e
         return None
@@ -912,12 +935,16 @@ class Controller(RequestTimeoutHandler):
                 rec.end(span)
         if md is None:  # stopped meanwhile
             return
-        if self._check_if_rotate(list(md.black_list)):
+        outgoing = self._check_if_rotate(list(md.black_list))
+        if outgoing:
             self.logger.debugf("Restarting view to rotate the leader")
             await self._change_view(
                 self.curr_view_number, md.latest_sequence + 1, self.curr_decisions_in_view
             )
-            self.request_pool.restart_timers()
+            # the replica whose turn ended hands what its pool still holds
+            # to the new leader (one bonus forward each, the ordinary
+            # chain behind it); everyone else re-arms as upstream does
+            self.request_pool.restart_timers(handover=outgoing == self.id)
         self.maybe_prune_revoked_requests()
         if self.i_am_the_leader()[0]:
             self._acquire_leader_token()
@@ -968,8 +995,10 @@ class Controller(RequestTimeoutHandler):
                                view=md.view_id, seq=md.latest_sequence)
         return md
 
-    def _check_if_rotate(self, blacklist: list[int]) -> bool:
-        """controller.go:560-574 (called after increment).
+    def _check_if_rotate(self, blacklist: list[int]) -> int:
+        """controller.go:560-574 (called after increment).  -> the node
+        that led the turn that just ended if the lead passes on with this
+        decision, else 0 (no node has id 0).
 
         ``decisions_per_leader`` is the EFFECTIVE per-decision value
         (window granularity pre-multiplies by pipeline_depth), so in
@@ -993,10 +1022,10 @@ class Controller(RequestTimeoutHandler):
             view, self.n, self.nodes_list, self.leader_rotation,
             dec, self.decisions_per_leader, blacklist,
         )
-        rotate = curr_leader != next_leader
-        if rotate:
-            self.logger.infof("Rotating leader from %d to %d", curr_leader, next_leader)
-        return rotate
+        if curr_leader == next_leader:
+            return 0
+        self.logger.infof("Rotating leader from %d to %d", curr_leader, next_leader)
+        return curr_leader
 
     # ------------------------------------------------------------------ sync
 

@@ -11,7 +11,10 @@ and the commit path pays no schedule/cancel pair for timers that never
 fire — which at open-loop rates is nearly all of them.
 
 Timeout chain per request (requestpool.go:493-567):
-  forward timeout  -> on_request_timeout  (forward request to leader)
+  forward timeout  -> on_request_timeout  (forward request to leader;
+                      a view flip and a rotation's hand-over put one
+                      bonus forward at FORWARD_TIMEOUT_FLOOR before it,
+                      see restart_timers)
   complain timeout -> on_leader_fwd_request_timeout (complain -> view change)
   auto-remove      -> on_auto_remove_timeout (drop the request)
 """
@@ -127,6 +130,17 @@ class PoolOptions:
     #: enough to fill the new view's deep windows immediately.  0
     #: disables (every restart uses the ordinary timeout).
     flip_drain_limit: int = 0
+    #: the same leg for a ROTATION's hand-over (ISSUE 31): how many of
+    #: the oldest requests the replica whose turn just ended forwards to
+    #: the new leader, instead of keeping them until the lead comes round
+    #: again.  Derived as ONE window (pipeline_depth *
+    #: request_batch_max_count), the new leader's first proposals: below
+    #: capacity what a leader is left with is what arrived while its last
+    #: batch committed, less than a batch; a deeper backlog means the
+    #: cluster is at capacity, where carrying it along with the lead
+    #: every rotation costs the loop more than it saves (at 400 a
+    #: hand-over the knee fell; PERF.md, PR 31).  0 disables.
+    handover_limit: int = 0
 
 
 #: hard lower bound of a derived forward timeout: forwarding is benign
@@ -242,6 +256,9 @@ class Pool:
         self.shed_timeout = 0
         #: requests fast-forwarded by flip-time timer restarts (ISSUE 15)
         self.flip_drains = 0
+        #: requests this replica fast-forwarded when it handed the lead
+        #: over at a rotation (ISSUE 31), kept apart from the view changes'
+        self.handovers = 0
         self._drain_anchor = scheduler.now()
         self._drain_accum = 0
         self._drain_rate = 0.0  # requests/sec, EWMA over DRAIN_WINDOW spans
@@ -430,6 +447,11 @@ class Pool:
     def size_bytes(self) -> int:
         return self._size_bytes
 
+    def has_room(self) -> bool:
+        """Would a submit land now, without parking on space?"""
+        return (len(self._items) + self._reserved_slots
+                < self._opts.queue_size and not self._space_waiters)
+
     def occupancy(self) -> dict:
         """One JSON-able backpressure snapshot — the per-shard building
         block of the sharded front door's combined occupancy surface
@@ -456,6 +478,7 @@ class Pool:
             "shed_admission": self.shed_admission,
             "shed_timeout": self.shed_timeout,
             "flip_drains": self.flip_drains,
+            "handovers": self.handovers,
             "drain_rate": round(self._drain_rate, 3),
             "arrival_rate": round(self.arrival_rate(), 3),
         }
@@ -811,7 +834,8 @@ class Pool:
             self._th.on_leader_fwd_request_timeout(request, info)
         elif stage == _STAGE_AUTOREMOVE:
             self._on_auto_remove_to(info)
-        else:  # _STAGE_FLIP: the flip-time BONUS forward (round 15).
+        else:  # _STAGE_FLIP: the BONUS forward of a view flip (round 15)
+            # or of a rotation's hand-over.
             # Push the stalled request to the new leader immediately, then
             # re-arm the ORDINARY forward->complain chain behind it on its
             # original schedule.  The early forward is purely additive —
@@ -858,7 +882,8 @@ class Pool:
         self._cancel_wheel()
         self._log.debugf("Stopped all timers: size=%d", len(self._items))
 
-    def restart_timers(self, *, flip: bool = False) -> None:
+    def restart_timers(self, *, flip: bool = False,
+                       handover: bool = False) -> None:
         """Restart all request timers as forward timeouts
         (requestpool.go:472-490).
 
@@ -870,20 +895,42 @@ class Pool:
         forward timeout while the new view idles (round 16: propose_wait
         was 98% of forced-VC request time).  Leader-side dedup absorbs
         any duplicate this forwards; requests past the limit keep the
-        ordinary chain."""
+        ordinary chain.
+
+        ``handover=True`` (a rotation took the lead from THIS replica):
+        the same leg for the oldest ``handover_limit`` it still holds —
+        what reached it after the last batch of its turn was cut would
+        otherwise wait until the lead comes round again, every rotation
+        re-arming the full forward timeout before it can fire.  Counted
+        in ``handovers`` (and marked ``req.handover``), apart from
+        ``flip_drains``.
+
+        Either way a request reserved in flight is no leftover and keeps
+        the ordinary chain."""
         self._stopped = False
         for q in self._timer_qs:
             q.clear()  # every item is re-armed fresh below
         self._cancel_wheel()
         fwd = self._forward_timeout()
-        fast = self._opts.flip_drain_limit if flip else 0
-        for k, (info, item) in enumerate(self._items.items()):
-            if k < fast:
+        limit = (self._opts.handover_limit if handover
+                 else self._opts.flip_drain_limit if flip else 0)
+        rec = self._recorder if handover and self._recorder.enabled else None
+        now = self._scheduler.now()
+        fast = 0
+        for info, item in self._items.items():
+            if fast < limit and info not in self._in_flight:
+                fast += 1
                 self._arm(info, item, _STAGE_FLIP, FORWARD_TIMEOUT_FLOOR)
+                if rec is not None:
+                    # dur: how long it had been pooled when the lead went
+                    rec.record("req.handover", key=str(info),
+                               dur=now - item.addition_time)
             else:
                 self._arm(info, item, _STAGE_FWD, fwd)
-        if fast and self._items:
-            self.flip_drains += min(fast, len(self._items))
+        if handover:
+            self.handovers += fast
+        else:
+            self.flip_drains += fast
         self._log.debugf("Restarted all timers: size=%d", len(self._items))
 
     def _forward_timeout(self) -> float:

@@ -26,7 +26,11 @@ cover the same span of time.
                        ``thresholds`` in force at the off edge
 ``counters``           ``decisions`` (delivered by the replica that
                        proposed them), ``requests_proposed``, ``launches``,
-                       ``signatures``, ``fsync_waves``
+                       ``signatures``, ``fsync_waves``, ``handovers``
+                       (requests a replica forwarded as it handed the lead
+                       over, ``req.handover``), ``not_leader_forwards``
+                       (forwards dropped where they came: that replica did
+                       not lead, ``req.not_leader``)
 ``lanes``              kernel -> ``launches``, ``launched`` (lanes, padding
                        included) and ``used`` (``verify.lanes`` marks)
 ``rejected``           cause -> client envelopes refused (``req.rejected``)
@@ -96,7 +100,8 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
     rows = decision_rows(inside)
     proposed = {(r["node"], r["view"], r["seq"]): r["t"] for r in rows}
     counters = {"decisions": 0, "requests_proposed": 0, "launches": 0,
-                "signatures": 0, "fsync_waves": 0}
+                "signatures": 0, "fsync_waves": 0, "handovers": 0,
+                "not_leader_forwards": 0}
     waits: dict = {k: [] for k in _WAIT_KINDS}
     lanes: dict = {}
     rejected: dict = {}
@@ -131,6 +136,10 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
         elif kind == "req.rejected":
             cause = (e.extra or {}).get("cause", "?")
             rejected[cause] = rejected.get(cause, 0) + 1
+        elif kind == "req.handover":
+            counters["handovers"] += 1
+        elif kind == "req.not_leader":
+            counters["not_leader_forwards"] += 1
         if kind in waits and e.dur >= 0.0:
             waits[kind].append(e.dur * 1e3)
     pool_wait, total = [], []

@@ -6,7 +6,10 @@ control-channel response saved to disk).  Output: a merged text timeline
 (events from every replica interleaved by timestamp, offsets relative to
 the earliest event) followed by a per-span-type percentile summary over
 the events that carry durations, plus derived submit→deliver spans
-joined by request key when both ends are present.
+joined by request key when both ends are present, and one line of
+forwards: requests handed over with the lead at a rotation
+(``req.handover``, whose duration is the time each had been pooled) and
+forwards dropped at a replica that did not lead (``req.not_leader``).
 
 **Cluster timelines (ISSUE 13).**  Multi-PROCESS dumps live on different
 monotonic clocks; a dump carrying ``clock_offset_s`` (written by
@@ -217,6 +220,12 @@ def render(dumps: list[dict], *, last: Optional[int] = None,
         for kind, n, p50, p95, p99, mx in rows:
             out.append(f"  {kind:<24} {n:>6} {p50:>10.3f} {p95:>10.3f} "
                        f"{p99:>10.3f} {mx:>10.3f}")
+    handed = sum(ev.get("kind") == "req.handover" for ev in events)
+    strays = sum(ev.get("kind") == "req.not_leader" for ev in events)
+    if handed or strays:
+        out.append("")
+        out.append(f"forwards: {handed} handed over with the lead, "
+                   f"{strays} dropped at a replica that did not lead")
     offsets = {d.get("node", ""): d.get("clock_offset_s", 0.0)
                for d in dumps
                if d.get("node") and d.get("offset_known", True)}

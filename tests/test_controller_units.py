@@ -9,6 +9,7 @@ doubles; support.go:13-70).
 from __future__ import annotations
 
 import asyncio
+import types
 from typing import Optional
 
 import pytest
@@ -131,6 +132,16 @@ class FakePool:
         self.removed: list[RequestInfo] = []
         self.timers_restarted = 0
         self._requests = [b"a", b"b"]
+        self.room = True
+
+    def has_room(self) -> bool:
+        return self.room
+
+    def retry_after_hint(self) -> float:
+        return 0.25
+
+    def occupancy(self) -> dict:
+        return {"size": 2}
 
     def prune(self, predicate) -> None:
         self.pruned += 1
@@ -140,7 +151,7 @@ class FakePool:
     def remove_request(self, info) -> None:
         self.removed.append(info)
 
-    def restart_timers(self) -> None:
+    def restart_timers(self, **kw) -> None:
         self.timers_restarted += 1
 
     def mark_in_flight(self, infos) -> None:
@@ -576,6 +587,50 @@ def test_check_if_rotate_detects_leader_change():
     c.leader_rotation = True
     c.decisions_per_leader = 1
     c.curr_decisions_in_view = 1  # decision 0 -> leader 1; decision 1 -> leader 2
-    assert c._check_if_rotate([])
+    # the answer is the node whose turn ended: the one that hands its
+    # pool over (Controller._decide)
+    assert c._check_if_rotate([]) == 1
     c.decisions_per_leader = 10  # same leader for both
-    assert not c._check_if_rotate([])
+    assert c._check_if_rotate([]) == 0
+
+
+@pytest.mark.parametrize("traced", [False, True], ids=["off", "traced"])
+def test_forward_to_a_replica_that_does_not_lead_is_dropped_and_counted(traced):
+    """handle_request's drop branch: counted always, marked when a
+    recorder is on, and nothing reaches the pool; the leader's own branch
+    counts nothing."""
+    from smartbft_tpu.core.pool import AdmissionRejected
+    from smartbft_tpu.obs import TraceRecorder
+
+    async def run():
+        c = make_controller(self_id=2)  # view 0: node 1 leads
+        c.recorder = TraceRecorder(node="n2", enabled=traced)
+        c.request_inspector = types.SimpleNamespace(
+            request_id=c.verifier.verify_request)
+        submitted = []
+
+        async def submit(raw, *, forwarded=False):
+            submitted.append((raw, forwarded))
+
+        c.request_pool.submit = submit
+        assert await c.handle_request(3, b"early") is None
+        assert await c.handle_request(4, b"early-too") is None
+        assert c.not_leader_forwards == 2 and submitted == []
+        marks = [e for e in c.recorder.events() if e.kind == "req.not_leader"]
+        assert [(e.key, e.extra) for e in marks] == (
+            [("c:early", {"sender": 3}), ("c:early-too", {"sender": 4})]
+            if traced else [])
+        c.id = 1  # the same forward at the node that leads is pooled
+        assert await c.handle_request(3, b"early") is None
+        assert c.not_leader_forwards == 2
+        assert submitted == [(b"early", True)]
+        # ... unless it would have to park on space: this is the node's
+        # inbox task, which the commits that free the space come through,
+        # so it is refused at once (the forwarder keeps its copy)
+        c.request_pool.room = False
+        shed = await c.handle_request(3, b"later")
+        assert isinstance(shed, AdmissionRejected)
+        assert shed.retry_after == 0.25 and shed.occupancy == {"size": 2}
+        assert submitted == [(b"early", True)]
+
+    asyncio.run(run())

@@ -327,6 +327,124 @@ def test_pool_flip_restart_fast_forwards_oldest():
     asyncio.run(run())
 
 
+# -- pool hand-over at a leader rotation --------------------------------------
+
+def _handover_pool():
+    from smartbft_tpu.core.pool import Pool, PoolOptions
+    from smartbft_tpu.obs import TraceRecorder
+    from tests.test_core_units import _Handler, _Inspector
+
+    sched = Scheduler()
+    th = _Handler()
+    pool = Pool(
+        StdLogger("t"), _Inspector(), th,
+        PoolOptions(queue_size=16, forward_timeout=5.0,
+                    complain_timeout=50.0, auto_remove_timeout=500.0,
+                    flip_drain_limit=4, handover_limit=3),
+        sched,
+        recorder=TraceRecorder(node="n1", clock=sched.now, enabled=False),
+    )
+    return sched, th, pool
+
+
+async def _case_oldest_only(sched, th, pool, floor):
+    """The oldest ``handover_limit`` (one window; a view flip's budget
+    is ``flip_drain_limit``) are forwarded one floor later and only
+    they; the rest wait out the constant."""
+    pool.restart_timers(handover=True)
+    sched.advance_by(floor + 0.001)
+    assert [i.request_id for i in th.forwarded] == ["req-0", "req-1", "req-2"]
+    sched.advance_by(4.9)           # still short of forward(5)
+    assert len(th.forwarded) == 3
+
+
+async def _case_skips_in_flight(sched, th, pool, floor):
+    """What is reserved in flight is no leftover: it keeps the ordinary
+    chain and does not use up the budget."""
+    from smartbft_tpu.types import RequestInfo
+
+    pool.mark_in_flight([RequestInfo("c", "req-0"), RequestInfo("c", "req-2")])
+    pool.restart_timers(handover=True)
+    sched.advance_by(floor + 0.001)
+    assert [i.request_id for i in th.forwarded] == ["req-1", "req-3", "req-4"]
+    assert pool.handovers == 3
+    sched.advance_by(5.1)           # the ordinary pass takes the reserved too
+    assert sorted(i.request_id for i in th.forwarded[3:]) == \
+        [f"req-{k}" for k in range(6)]
+
+
+async def _case_chain_behind(sched, th, pool, floor):
+    """The bonus forward is additive: the ordinary forward follows at
+    ``forward_timeout`` and no complain comes earlier than after a plain
+    restart."""
+    pool.restart_timers(handover=True)
+    sched.advance_by(floor + 0.001)
+    assert len(th.forwarded) == 3
+    sched.advance_by(5.1)           # past forward(5): 3 retries + the other 3
+    assert len(th.forwarded) == 9
+    assert th.complained == []
+    sched.advance_by(45.0)          # t ~ 50.1: still inside complain
+    assert th.complained == []
+    sched.advance_by(5.5)           # past forward(5) + complain(50)
+    assert len(th.complained) == 6
+
+
+async def _case_counted_apart(sched, th, pool, floor):
+    """Hand-overs and view-change drains are two counts, each with its
+    own budget."""
+    pool.restart_timers(handover=True)
+    assert (pool.handovers, pool.flip_drains) == (3, 0)
+    pool.stop_timers()
+    pool.restart_timers(flip=True)
+    assert (pool.handovers, pool.flip_drains) == (3, 4)
+    occ = pool.occupancy()
+    assert (occ["handovers"], occ["flip_drains"]) == (3, 4)
+    pool.restart_timers(handover=True)
+    assert (pool.handovers, pool.flip_drains) == (6, 4)
+
+
+async def _case_plain_forwards_nothing(sched, th, pool, floor):
+    """A replica that did not lead restarts as upstream does."""
+    pool.restart_timers(handover=False)
+    sched.advance_by(floor + 0.001)
+    assert th.forwarded == []
+    assert (pool.handovers, pool.flip_drains) == (0, 0)
+    sched.advance_by(5.0)
+    assert len(th.forwarded) == 6
+
+
+async def _case_marked_when_traced(sched, th, pool, floor):
+    """One ``req.handover`` mark a forwarded request, carrying how long
+    it had been pooled; nothing with the recorder off."""
+    rec = pool._recorder
+    sched.advance_by(0.25)
+    pool.restart_timers(handover=True)
+    assert [e for e in rec.events() if e.kind == "req.handover"] == []
+    rec.enabled = True
+    pool.restart_timers(handover=True)
+    marks = [e for e in rec.events() if e.kind == "req.handover"]
+    assert [e.key for e in marks] == ["c:req-0", "c:req-1", "c:req-2"]
+    assert all(e.dur == pytest.approx(0.25) for e in marks)
+
+
+@pytest.mark.parametrize("case", [
+    _case_oldest_only, _case_skips_in_flight, _case_chain_behind,
+    _case_counted_apart, _case_plain_forwards_nothing,
+    _case_marked_when_traced,
+], ids=lambda f: f.__name__[len("_case_"):])
+def test_pool_handover_restart(case):
+    from smartbft_tpu.core.pool import FORWARD_TIMEOUT_FLOOR
+
+    async def run():
+        sched, th, pool = _handover_pool()
+        for k in range(6):
+            await pool.submit(b"req-%d" % k)
+        await case(sched, th, pool, FORWARD_TIMEOUT_FLOOR)
+        pool.close()
+
+    asyncio.run(run())
+
+
 # -- coalescer flip-warm transient --------------------------------------------
 
 def test_coalescer_flip_warm_flushes_without_window():

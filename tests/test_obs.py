@@ -204,6 +204,28 @@ def test_report_renders_dump_round_trip(tmp_path):
     assert main([path, "--summary-only"]) == 0
 
 
+def test_report_counts_handed_over_and_dropped_forwards(tmp_path):
+    """The forwards line: hand-overs (also a row of the span summary,
+    their duration being the time pooled) and the forwards a replica
+    dropped because it did not lead; absent where neither happened."""
+    old = TraceRecorder(capacity=64, node="n1")
+    for k, pooled in enumerate((0.004, 0.020, 0.041)):
+        old.record("req.handover", key=f"c:r{k}", dur=pooled)
+    new = TraceRecorder(capacity=64, node="n2")
+    new.record("req.not_leader", key="c:r0", extra={"sender": 1})
+    dumps = [load_dump(r.dump_to(str(tmp_path / f"flight-{r.node}.json")))
+             for r in (old, new)]
+    text = render(dumps, summary_only=True)
+    assert "forwards: 3 handed over with the lead, 1 dropped at a " \
+           "replica that did not lead" in text
+    row = [ln for ln in text.splitlines() if ln.strip().startswith("req.handover")]
+    assert row and row[0].split()[1] == "3"
+    quiet = TraceRecorder(capacity=64, node="n3")
+    quiet.record("req.submit", key="c:r9")
+    assert "forwards:" not in render(
+        [load_dump(quiet.dump_to(str(tmp_path / "flight-n3.json")))])
+
+
 # ---------------------------------------------------------------------------
 # live cluster: a real view change decomposes
 # ---------------------------------------------------------------------------
@@ -352,7 +374,9 @@ def test_tracing_overhead_within_bound(tmp_path):
 
 def test_recorder_bounded_and_dump_renders_under_chaos(tmp_path):
     """A traced chaos segment (leader mute → depose → heal) with a tiny
-    ring cap (32): every replica's buffer stays at/below the cap while far
+    ring cap (48; 32 until a rotation's hand-over got its marks, which
+    pushed the one surviving ``vc.`` mark out of the newest 32): every
+    replica's buffer stays at/below the cap while far
     more events were recorded (the wrap really happened), a FORCED
     invariant failure dumps per-replica artifacts, and the report tool
     renders them."""
@@ -366,7 +390,7 @@ def test_recorder_bounded_and_dump_renders_under_chaos(tmp_path):
     async def run():
         cluster = ChaosCluster(
             str(tmp_path), n=4, depth=1, rotation=True, trace=True,
-            trace_capacity=32,
+            trace_capacity=48,
         )
         await cluster.start()
         try:
@@ -381,11 +405,11 @@ def test_recorder_bounded_and_dump_renders_under_chaos(tmp_path):
 
         # task-audit-style memory pin: the ring never exceeds its cap,
         # and it genuinely wrapped under the soak segment's traffic
-        assert any(r.recorded > 32 for r in cluster.recorders.values()), \
+        assert any(r.recorded > 48 for r in cluster.recorders.values()), \
             "chaos segment recorded too few events to exercise the bound"
         for rec in cluster.recorders.values():
-            assert len(rec.events()) <= 32
-            assert rec.dropped == max(0, rec.recorded - 32)
+            assert len(rec.events()) <= 48
+            assert rec.dropped == max(0, rec.recorded - 48)
 
         # forced invariant failure -> parseable dump -> report renders
         out_dir = tmp_path / "flight"

@@ -410,6 +410,23 @@ def test_decision_segments_are_exact_and_sum_to_propose_to_deliver():
     assert acc["counters"]["requests_proposed"] == 300
 
 
+def test_account_counts_handovers_and_forwards_dropped_for_no_lead():
+    """``req.handover`` (the outgoing leader's recorder) and
+    ``req.not_leader`` (the receiver's) are counters of the account, from
+    every recorder, inside the interval only; an interval with neither
+    reads 0, not absent."""
+    events = [SpanEvent(1.0 + k / 100, "req.handover", "s0n1", key=f"c:{k}",
+                        dur=0.02) for k in range(5)]
+    events += [SpanEvent(1.5, "req.handover", "s0n2", key="c:9", dur=0.01),
+               SpanEvent(1.6, "req.not_leader", "s0n3", key="c:9",
+                         extra={"sender": 2}),
+               SpanEvent(9.0, "req.handover", "s0n4", key="c:10", dur=0.01)]
+    c = _account(events, t1=2.0)["counters"]
+    assert (c["handovers"], c["not_leader_forwards"]) == (6, 1)
+    c = _account([], t1=2.0)["counters"]
+    assert (c["handovers"], c["not_leader_forwards"]) == (0, 0)
+
+
 class _Ring:
     """What assemble_account needs of a recorder."""
 
