@@ -352,6 +352,51 @@ def ecdsa_verify_comb(e, r, s, kidx, gtab, qtab, tile: int = 128,
     return out[0, :bsz]
 
 
+#: one lane of a mesh launch as the host hands it over: e | r | s (32
+#: little-endian bytes each) | the key's index (int32, little-endian)
+MESH_LANE_BYTES = 100
+
+
+def pack_mesh_lanes(arrays, kidx) -> np.ndarray:
+    """:func:`pack_items`' columns side by side, (B, MESH_LANE_BYTES)
+    uint8: ONE host array a launch, so one scatter and one transfer to
+    each device where there were four.  Every hand-over to the runtime
+    lets the interpreter lock go, and getting it back from a busy loop
+    thread has cost the launch's thread up to 5 ms a time (PERF.md)."""
+    key_bytes = np.ascontiguousarray(kidx, "<i4").view(np.uint8)
+    return np.concatenate([*arrays, key_bytes.reshape(-1, 4)], axis=1)
+
+
+def mesh_comb_launcher(mesh, tile: int = 128, interpret: bool = False):
+    """:func:`ecdsa_verify_comb` on every device of a 1D ``mesh`` at once.
+
+    ``pallas_call`` has no partitioning rules, so ``jit`` alone cannot
+    split it; ``shard_map`` can: the lanes (:func:`pack_mesh_lanes`) and
+    the mask are sharded along the mesh's one axis, the two tables are
+    whole on every device, and each device unpacks its own lanes and runs
+    the kernel on them.  Lanes per device must be a multiple of ``tile``
+    (each device pads its shard to one otherwise, and the mask would no
+    longer line up).  Returns the jitted function ``(lanes, gtab, qtab) ->
+    mask``; its XLA module is named ``jit_ecdsa_verify_comb_mesh``.
+    """
+    from jax.sharding import PartitionSpec
+
+    lane, whole = PartitionSpec(mesh.axis_names[0]), PartitionSpec()
+
+    def ecdsa_verify_comb_mesh(lanes, gtab, qtab):
+        e, r, s = (lanes[:, at:at + 32] for at in (0, 32, 64))
+        k = lanes[:, 96:].astype(jnp.int32)
+        kidx = k[:, 0] | (k[:, 1] << 8) | (k[:, 2] << 16) | (k[:, 3] << 24)
+        return ecdsa_verify_comb(e, r, s, kidx, gtab, qtab, tile=tile,
+                                 interpret=interpret)
+
+    # check_vma off: the kernel's carry chains start from unvarying
+    # constants, which the checker rejects (as in parallel/engine.py)
+    return jax.jit(jax.shard_map(
+        ecdsa_verify_comb_mesh, mesh=mesh, in_specs=(lane, whole, whole),
+        out_specs=lane, check_vma=False))
+
+
 # ---------------------------------------------------------------------------
 # key registry + engine adapter
 # ---------------------------------------------------------------------------
@@ -441,11 +486,24 @@ class CombVerifier:
     device-table caching / pad-and-launch scaffolding is scheme-agnostic;
     subclasses (pallas_ed25519.Ed25519CombVerifier) override the four
     ``_...`` hooks.
+
+    ``mesh``: a 1D device mesh (P-256 only).  The tables are then whole on
+    EVERY device of it, placed once per registry version, and
+    ``MeshVerifyEngine`` launches through ``launch_on_mesh`` with
+    :meth:`pack_for_mesh`'s lanes, which it lays out over the mesh itself.  ``interpret`` is the
+    kernel's own switch, for tests on the CPU.
     """
 
-    def __init__(self, tile: int = 128, cap: int = 128):
+    def __init__(self, tile: int = 128, cap: int = 128, mesh=None,
+                 interpret: bool = False):
         self.registry = self._make_registry(cap)
         self.tile = tile
+        self.mesh = mesh
+        #: ``(lanes, gtab, qtab) -> mask``: :meth:`pack_for_mesh`'s lanes,
+        #: padded and sharded over the mesh by the caller, through the
+        #: kernel on every device; the mask comes back sharded as they are
+        self.launch_on_mesh = None if mesh is None else \
+            mesh_comb_launcher(mesh, tile, interpret)
         self._pending_prewarm: list = []
         self._dev_version: int = -1
         self._dev_gtab = None
@@ -533,11 +591,21 @@ class CombVerifier:
                 "kernel: %s", exc,
             )
 
+    def _put_table(self, table: np.ndarray):
+        """One table onto the device — or, on a mesh, whole onto every
+        device of it, straight from the host."""
+        if self.mesh is None:
+            return jnp.asarray(table, jnp.bfloat16)
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        return jax.device_put(table.astype(jnp.bfloat16),
+                              NamedSharding(self.mesh, PartitionSpec()))
+
     def _device_tables(self):
         version = len(self.registry)
         if version != self._dev_version:
-            self._dev_gtab = jnp.asarray(self._base_table(), jnp.bfloat16)
-            self._dev_qtab = jnp.asarray(self.registry.stacked(), jnp.bfloat16)
+            self._dev_gtab = self._put_table(self._base_table())
+            self._dev_qtab = self._put_table(self.registry.stacked())
             self._dev_version = version
         return self._dev_gtab, self._dev_qtab
 
@@ -558,6 +626,16 @@ class CombVerifier:
             # program for every new wave size
             mask = np.asarray(self._launch(arrays, ok, kidx, gtab, qtab))
         return mask[:len(items)]
+
+    def pack_for_mesh(self, items):
+        """Register, hash and pack one chunk, unpadded -> ``(lanes, gtab,
+        qtab)`` for ``launch_on_mesh``, or None when a key is
+        unregistrable."""
+        packed = self._pack_chunk(items, len(items))
+        if packed is None:
+            return None
+        arrays, _ok, kidx, gtab, qtab = packed
+        return pack_mesh_lanes(arrays, kidx), gtab, qtab
 
     def _pack_chunk(self, items, pad_to: int):
         """Register, hash, pack and pad one chunk -> the launch's inputs,

@@ -165,6 +165,11 @@ class MeshVerifyStats(VerifyStats):
     #: read off the arrays' shardings — the mesh width as it RAN, beside
     #: ``devices``, the width as it was built
     last_io_devices: tuple = (0, 0)
+    #: launches whose inputs or output were laid out over FEWER devices
+    #: than the engine was built with (a launch of one signature need not
+    #: USE every device, ``launches_spanning_all_devices``; it is still
+    #: laid out over all of them)
+    launches_below_width: int = 0
 
     def record(self, n_sigs: int, n_slots: int, seconds: float,
                kernel: str = "xla",
@@ -176,6 +181,8 @@ class MeshVerifyStats(VerifyStats):
         devices front to back, padding on the tail)."""
         super().record(n_sigs, n_slots, seconds, kernel)
         self.last_io_devices = tuple(io_devices)
+        if min(self.last_io_devices) < self.devices:
+            self.launches_below_width += 1
         pad = max(n_slots - n_sigs, 0)
         self.pad_slots += pad
         per_dev = max(1, n_slots // max(1, self.devices))
@@ -211,6 +218,7 @@ class MeshVerifyStats(VerifyStats):
             "device_fill_pct_last": list(self.last_device_fill_pct),
             "io_devices_last": list(self.last_io_devices),
             "launches_spanning_all_devices": self.launches_spanning_all_devices,
+            "launches_below_width": self.launches_below_width,
         }
 
 
@@ -499,9 +507,13 @@ class JaxVerifyEngine:
 
     preferred_coalesce_window = 0.002  # batched engine: wait for fan-in
 
-    #: subclasses whose inputs are mesh-placed (ShardedVerifyEngine) must
-    #: opt out — pallas_call has no partitioning rules, so routing sharded
-    #: lanes into it would silently collapse the mesh to one device
+    #: may a chunk be routed into a Pallas kernel?  An engine that places
+    #: its lanes on a mesh and leaves the split to ``jit``
+    #: (ShardedVerifyEngine, the 2D engine) opts out: pallas_call has no
+    #: partitioning rules, so sharded lanes routed into it would silently
+    #: collapse the mesh to one device.  An engine that wraps the kernel in
+    #: ``shard_map`` itself (MeshVerifyEngine) stays in, for the kernels it
+    #: wraps (:meth:`_pallas_kernels`)
     supports_pallas = True
 
     def __init__(self,
@@ -538,21 +550,9 @@ class JaxVerifyEngine:
         # route — host-precomputed per-replica comb tables, 32 point-op
         # levels per verify.  Used for every chunk whose signer keys are
         # registrable.
-        self._comb = None
-        self._pallas_kernel = None
-        if self.supports_pallas:
-            if scheme is p256:
-                from . import pallas_ecdsa
-                from .pallas_comb import CombVerifier
-
-                self._comb = CombVerifier()
-                self._pallas_kernel = pallas_ecdsa.ecdsa_verify
-            elif scheme is ed25519:
-                # ed25519 has no generic pallas kernel — the comb path IS
-                # the fused kernel; unregistrable keys ride the XLA kernel
-                from .pallas_ed25519 import Ed25519CombVerifier
-
-                self._comb = Ed25519CombVerifier()
+        self._comb, self._pallas_kernel = \
+            self._pallas_kernels(scheme) if self.supports_pallas \
+            else (None, None)
         #: (kernel, shape) pairs that have launched once, i.e. compiled
         self._launched: set = set()
         self._lock = threading.Lock()
@@ -564,6 +564,22 @@ class JaxVerifyEngine:
             or self.pad_sizes
         if ring is not None:
             self.pin_ring(ring)
+
+    def _pallas_kernels(self, scheme) -> tuple:
+        """-> (comb verifier, arbitrary-key Pallas kernel) of ``scheme``,
+        None where it has none."""
+        if scheme is p256:
+            from . import pallas_ecdsa
+            from .pallas_comb import CombVerifier
+
+            return CombVerifier(), pallas_ecdsa.ecdsa_verify
+        if scheme is ed25519:
+            # ed25519 has no generic pallas kernel — the comb path IS the
+            # fused kernel; unregistrable keys ride the XLA kernel
+            from .pallas_ed25519 import Ed25519CombVerifier
+
+            return Ed25519CombVerifier(), None
+        return None, None
 
     def _use_pallas(self) -> bool:
         """Default the Pallas kernels on when the backend is a TPU.
@@ -648,7 +664,11 @@ class JaxVerifyEngine:
             return []
         ring = self._ring
         if ring is None or not self.supports_pallas:
-            # no ring told, or a mesh engine (one kernel, one ladder)
+            # no ring told, or an engine whose lanes jit partitions: one
+            # kernel, one ladder.  (MeshVerifyEngine does split a pinned
+            # ring: ring keys -> the comb kernel on every device, the
+            # rest -> the sharded XLA kernel, its _verify_chunk's
+            # ``generic``)
             return self._verify_class(items, False)
         # a key is the last field of an item in every scheme
         inside, outside = [], []
@@ -1764,6 +1784,13 @@ class CryptoProvider:
                     devices=int(devices), scheme=self.scheme,
                     pad_sizes=donor, metrics=metrics,
                 )
+                # the static keys the replaced engine was told stay told:
+                # the mesh serves a ring as it does (ring -> comb, the
+                # rest -> the sharded XLA kernel)
+                ring = getattr(getattr(current, "inner", None) or current,
+                               "_ring", None)
+                if ring:
+                    engine.pin_ring(ring)
         except MeshUnavailable as exc:
             co.mesh_downgrades += 1
             if metrics is not None and hasattr(metrics, "count_mesh_downgrades"):

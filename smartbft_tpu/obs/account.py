@@ -33,6 +33,12 @@ cover the same span of time.
                        not lead, ``req.not_leader``)
 ``lanes``              kernel -> ``launches``, ``launched`` (lanes, padding
                        included) and ``used`` (``verify.lanes`` marks)
+``mesh``               the launches laid out over a device mesh (the
+                       ``verify.lanes`` marks that carry ``per_device``):
+                       ``launches``, ``spanning`` (of them, those that used
+                       every device), ``used`` and ``launched`` lanes, and
+                       the same two ``by_device``; zeros and empty lists
+                       where no launch was
 ``rejected``           cause -> client envelopes refused (``req.rejected``)
 ``segments``           segment -> ms per decision (:func:`decision_rows`)
 ``decisions``          the rows themselves: ``view``, ``seq``, ``node``,
@@ -60,6 +66,23 @@ __all__ = ["assemble_account"]
 #: wait kinds recorded as such, taken as they are
 _WAIT_KINDS = ("verify.wait", "verify.hold", "wal.persist",
                "request.verify", "proposal.verify")
+
+
+def _fold_mesh_launch(mesh: dict, mark: dict) -> None:
+    """One mesh launch's ``verify.lanes`` mark into the ``mesh`` block."""
+    used = mark["per_device"]
+    if len(mesh["used_by_device"]) < len(used):
+        grow = len(used) - len(mesh["used_by_device"])
+        mesh["used_by_device"] += [0] * grow
+        mesh["launched_by_device"] += [0] * grow
+    each = mark["lanes"] // len(used)
+    for d, n in enumerate(used):
+        mesh["used_by_device"][d] += n
+        mesh["launched_by_device"][d] += each
+    mesh["launches"] += 1
+    mesh["spanning"] += min(used) > 0
+    mesh["used"] += mark["used"]
+    mesh["launched"] += mark["lanes"]
 
 
 def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
@@ -104,6 +127,8 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
                 "not_leader_forwards": 0}
     waits: dict = {k: [] for k in _WAIT_KINDS}
     lanes: dict = {}
+    mesh = {"launches": 0, "spanning": 0, "used": 0, "launched": 0,
+            "used_by_device": [], "launched_by_device": []}
     rejected: dict = {}
     submits: dict = {}
     delivered = []
@@ -133,6 +158,8 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
             per["launches"] += 1
             per["launched"] += x["lanes"]
             per["used"] += x["used"]
+            if "per_device" in x:
+                _fold_mesh_launch(mesh, x)
         elif kind == "req.rejected":
             cause = (e.extra or {}).get("cause", "?")
             rejected[cause] = rejected.get(cause, 0) + 1
@@ -169,6 +196,7 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
                       "thresholds": list(thresholds)},
         "counters": counters,
         "lanes": lanes,
+        "mesh": mesh,
         "rejected": rejected,
         "segments": {seg: [r[seg] for r in rows]
                      for seg in DECISION_SEGMENTS},

@@ -669,14 +669,18 @@ def launch_span(kind: str):
     return _LaunchSpan(kind) if PROCESS.enabled else _NO_SPAN
 
 
-def note_lanes(kernel: str, lanes: int, used: int) -> None:
+def note_lanes(kernel: str, lanes: int, used: int,
+               per_device: Optional[Sequence[int]] = None) -> None:
     """A verify launch's lanes (padding included) and the lanes used, by
     the kernel that served it: a mark on the launch's thread, folded into
-    the account's ``lanes`` block.  Off: one attribute read."""
+    the account's ``lanes`` block.  ``per_device``: a mesh launch's used
+    lanes on each device (its lanes are split evenly), folded into the
+    account's ``mesh`` block too.  Off: one attribute read."""
     if PROCESS.enabled:
-        PROCESS.record("verify.lanes", launch=_state().launch,
-                       extra={"kernel": kernel, "lanes": lanes,
-                              "used": used})
+        extra = {"kernel": kernel, "lanes": lanes, "used": used}
+        if per_device is not None:
+            extra["per_device"] = list(per_device)
+        PROCESS.record("verify.lanes", launch=_state().launch, extra=extra)
 
 
 def _live_recorders() -> list:

@@ -5,13 +5,18 @@ Design notes (TPU-first):
 * Verification lanes are independent — the ideal SPMD workload.  The
   engine pads each batch to a lane count divisible by the mesh and places
   inputs with ``NamedSharding(mesh, P('lane'))``; ``jax.jit`` then
-  partitions the whole kernel body across devices without any hand-written
-  collectives.
+  partitions the XLA kernel's body across devices without any
+  hand-written collectives.
 * The quorum step is the one place a cross-device reduction exists: vote
   counts sum over the 'vote' mesh axis (``lax.psum`` riding ICI), the
   cheapest possible collective (one scalar per in-flight sequence).
-* Both paths reuse the scheme modules' single-chip kernels unchanged —
-  sharding is an annotation, not a rewrite.
+* The XLA kernels are the scheme modules' single-chip kernels unchanged:
+  for them sharding is an annotation, not a rewrite.  A Pallas kernel is
+  not: ``pallas_call`` has no partitioning rules, so the 1D engine runs
+  the static-key comb kernel under ``shard_map`` (lanes split over the
+  mesh's axis, the key tables whole on every device:
+  ``pallas_comb.mesh_comb_launcher``) wherever the one-device engine
+  would run it, and the XLA kernel elsewhere.  The 2D engine is XLA only.
 """
 
 from __future__ import annotations
@@ -23,6 +28,7 @@ import numpy as np
 
 from ..crypto import p256
 from ..crypto.provider import JaxVerifyEngine, MeshVerifyStats
+from ..obs.recorder import launch_span, note_lanes
 
 
 def build_mesh(shape: Optional[tuple[int, ...]] = None,
@@ -61,8 +67,8 @@ class ShardedVerifyEngine(JaxVerifyEngine):
     with a lane sharding and XLA partitions the kernel.
     """
 
-    # the fused Pallas kernel is single-device (no partitioning rules);
-    # mesh-placed lanes must stay on the XLA kernel so jit partitions them
+    # this engine leaves the split to jit, which can partition the XLA
+    # kernel and cannot partition a pallas_call: its lanes stay on XLA
     supports_pallas = False
 
     def __init__(self, mesh=None,
@@ -127,8 +133,23 @@ class MeshVerifyEngine(ShardedVerifyEngine):
     Verification lanes are independent, so the permutation cannot change
     any verdict; results un-permute before slicing, keeping the output
     bit-identical to the single-device engine.
+
+    **Which kernel** (PR 32) is decided as on one device, by what the
+    engine can observe: on a TPU, P-256 chunks whose keys are registrable
+    ride the static-key comb kernel ON EVERY DEVICE (``shard_map`` over
+    the ``batch`` axis, the tables whole on each device), recorded
+    ``comb``; on other backends, for other schemes (Ed25519's comb kernel
+    has no mesh wrapper: it stays on XLA here) and for keys outside a
+    pinned ring (:meth:`JaxVerifyEngine.pin_ring`; the arbitrary-key
+    Pallas kernel has none either) the XLA kernel that ``jit`` partitions
+    does, recorded ``xla``.  When the comb kernel serves, ``pad_sizes`` is
+    rounded to multiples of ``devices x tile`` lanes (a device's share
+    must be whole kernel tiles) and rungs that would be the same launch
+    are one rung; ``request_pad_sizes`` stays the XLA kernel's ladder.
     """
 
+    #: wraps the comb kernel in shard_map itself (see the class docstring)
+    supports_pallas = True
     #: bench/wiring marker: which mesh shape this engine runs (the 2D
     #: seq×vote engine says "2d"); configure_verify_mesh keys idempotence
     #: on (devices, topology)
@@ -157,6 +178,20 @@ class MeshVerifyEngine(ShardedVerifyEngine):
         #: as "already graduated")
         self.devices = self.lanes
         self.stats = MeshVerifyStats(devices=self.devices, metrics=metrics)
+        # the mesh was built from the backend's devices, so the backend is
+        # up and the kernel can be chosen now, with the ladder it needs
+        self._pallas_on = self._use_pallas()
+        if self._pallas_on and self._comb is not None:
+            block = self.devices * self._comb.tile
+            self.pad_sizes = tuple(sorted(
+                {-(-s // block) * block for s in self.pad_sizes}))
+
+    def _pallas_kernels(self, scheme) -> tuple:
+        if scheme is p256:
+            from ..crypto.pallas_comb import CombVerifier
+
+            return CombVerifier(mesh=self.mesh), None
+        return None, None
 
     def mesh_snapshot(self) -> dict:
         """JSON-able block: devices, per-launch fill per device, pad
@@ -165,36 +200,69 @@ class MeshVerifyEngine(ShardedVerifyEngine):
         out["topology"] = self.topology
         return out
 
-    def _verify_chunk(self, items) -> list[bool]:
+    def _verify_chunk(self, items, generic: bool = False) -> list[bool]:
         """Strided chunk verify: scatter item *j* to padded row
         ``(j % D) * per_dev + j // D`` — device *d*'s tile holds items
         ``d, d+D, d+2D, ...`` — run ONE mesh launch, then un-permute the
         mask back to submission order.  Pad rows stay zero (they verify
-        False and are never read back)."""
+        False and are never read back).  ``generic``: the chunk's keys are
+        outside the pinned ring, so the comb kernel is not asked."""
         n = len(items)
-        size = self._pad_to(n)
+        size = self._rung(self.request_pad_sizes, n) if generic \
+            else self._pad_to(n)
         d_count = self.devices
-        per_dev = size // d_count
         idx = np.arange(n)
-        rows = (idx % d_count) * per_dev + idx // d_count
+        rows = (idx % d_count) * (size // d_count) + idx // d_count
         t0 = time.perf_counter()
-        arrays = self.scheme.verify_inputs(items)
-
-        def scatter(a):
-            out = np.zeros((size,) + a.shape[1:], a.dtype)
-            out[rows] = a
-            return self._place(out)
-
-        placed = [scatter(a) for a in arrays]
-        out = self._kernel(*placed)
-        io_devices = (_device_span(placed), _device_span([out]))
-        mask = np.asarray(out)
+        kernel, (mask, io_devices) = self._launch_strided(
+            items, size, rows, generic)
         dt = time.perf_counter() - t0
         counts = [len(range(d, n, d_count)) for d in range(d_count)]
         with self._lock:
-            self.stats.record(n, size, dt, per_device=counts,
+            self.stats.record(n, size, dt, kernel, per_device=counts,
                               io_devices=io_devices)
+        note_lanes(kernel, size, n, per_device=counts)
         return [bool(v) for v in mask[rows]]
+
+    def _launch_strided(self, items, size: int, rows, generic: bool):
+        """One chunk through the kernel the backend and the chunk's keys
+        select -> (kernel name, (host mask in padded row order, the
+        devices its inputs / its output were laid out over))."""
+        if self._pallas_on and self._comb is not None and not generic:
+            got = self._guarded_launch(
+                "comb", size, lambda: self._comb_strided(items, size, rows))
+            if got is not None:
+                return "comb", got
+        with launch_span("verify.pack"):
+            arrays = self.scheme.verify_inputs(items)
+        return "xla", self._guarded_launch(
+            "xla", size, lambda: self._run(self._kernel, arrays, size, rows))
+
+    def _comb_strided(self, items, size: int, rows):
+        """The chunk through the comb kernel on every device, or None for
+        a key the registry cannot hold."""
+        with launch_span("verify.pack"):
+            packed = self._comb.pack_for_mesh(items)
+        if packed is None:
+            return None
+        lanes, gtab, qtab = packed
+        return self._run(
+            lambda placed: self._comb.launch_on_mesh(placed, gtab, qtab),
+            [lanes], size, rows)
+
+    def _run(self, kernel, arrays, size: int, rows) -> tuple:
+        """Scatter ``arrays`` to the strided ``rows`` of ``size`` padded
+        lanes, hand each device its share, launch, read the mask back."""
+        with launch_span("verify.place"):
+            placed = []
+            for a in arrays:
+                out = np.zeros((size,) + a.shape[1:], a.dtype)
+                out[rows] = a
+                placed.append(self._place(out))
+        with launch_span("verify.device"):
+            out = kernel(*placed)
+            io_devices = (_device_span(placed), _device_span([out]))
+            return np.asarray(out), io_devices
 
 
 class QuorumMeshVerifyEngine(JaxVerifyEngine):
@@ -224,7 +292,7 @@ class QuorumMeshVerifyEngine(JaxVerifyEngine):
     launch exactly like the 1D engine's.
     """
 
-    supports_pallas = False  # mesh-placed lanes stay on the XLA kernel
+    supports_pallas = False  # jit partitions its lanes: the XLA kernel
     topology = "2d"
 
     def __init__(self, devices: Optional[int] = None, mesh=None,

@@ -3,7 +3,7 @@ real widths, compiled for a described (not attached) v5e chip.
 
 Interpret-mode tests cannot see what Mosaic refuses — a misaligned slice,
 or more scoped VMEM than a kernel may use (the 128-key comb stack needed
-18.5 MB against the 16 MiB default).  These four compiles can, at no chip
+18.5 MB against the 16 MiB default).  These compiles can, at no chip
 time.  The XLA and BLS compiles (minutes each) stay in the builder's
 scratch script.
 
@@ -26,15 +26,19 @@ WAVE_LANES, TILE_LANES = 2688, 128
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")  # no logs under /tmp
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — any reason it cannot be described
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -87,6 +91,37 @@ def test_comb_p256_compiles_for_v5e(one_chip, lanes, nkeys):
     assert module.startswith("HloModule") and "comb" in module, module
     assert any("custom-call" in line and "ecdsa_verify_comb" in line
                for line in text.splitlines())
+
+
+def test_comb_p256_compiles_for_a_four_chip_mesh(topo):
+    """`mesh4-n16-p256`'s one rung: 4 x 128 lanes over the 16 replicas'
+    keys, the comb kernel under shard_map on every chip of a v5e-4 host.
+    Lanes in and mask out are split four ways, the tables are whole on
+    each chip, and the compiler puts in no collective."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("batch",))
+    lane, whole = NamedSharding(mesh, P("batch")), NamedSharding(mesh, P())
+    lanes, nkeys = 4 * TILE_LANES, 16
+    shapes = [((lanes, pallas_comb.MESH_LANE_BYTES), jnp.uint8)] \
+        + _tables(nkeys)
+    args = [jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=whole if i else lane)
+            for i, (shape, dtype) in enumerate(shapes)]
+    compiled = pallas_comb.mesh_comb_launcher(mesh).lower(*args).compile()
+    text = compiled.as_text()
+    module = text.split("\n", 1)[0]
+    assert "comb" in module, module  # what comb_us_per_sig matches
+    assert any("custom-call" in line and "ecdsa_verify_comb" in line
+               for line in text.splitlines())
+    assert not any(op in text for op in
+                   ("all-reduce", "all-gather", "all-to-all",
+                    "collective-permute"))
+    out, = compiled.output_shardings if isinstance(
+        compiled.output_shardings, (list, tuple)) \
+        else (compiled.output_shardings,)
+    assert len(out.device_set) == 4 and out.spec == P("batch")
 
 
 def test_comb_ed25519_compiles_for_v5e(one_chip):

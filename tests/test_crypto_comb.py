@@ -306,3 +306,142 @@ def test_unregistrable_key_short_circuits_before_pack(monkeypatch, caplog):
     msgs = [rec.message for rec in caplog.records
             if "registry full at verify time" in rec.message]
     assert len(msgs) == 1
+
+
+# ------------------------------------------------ the kernel across a mesh
+
+MESH_DEVICES, MESH_TILE = 4, 16
+
+
+def _mesh_wave():
+    """12 lanes over 2 registered keys: valid votes and one forgery of each
+    kind the benchmark's set-up wave holds (a bit of r, a bit of s, another
+    message, another registered key)."""
+    keys = [p256.keygen(b"ct-%d" % i) for i in range(2)]
+    items, expect = [], []
+    for i in range(12):
+        sk, pub = keys[i % 2]
+        msg = b"mesh-m-%d" % i
+        sig = p256.sign_raw(sk, msg)
+        how = {2: "r", 5: "s", 7: "msg", 8: "key"}.get(i)
+        if how == "r":
+            sig = bytes([sig[0] ^ 0x20]) + sig[1:]
+        elif how == "s":
+            sig = sig[:40] + bytes([sig[40] ^ 0x01]) + sig[41:]
+        elif how == "msg":
+            msg = msg[:-1] + bytes([msg[-1] ^ 0xFF])
+        elif how == "key":
+            pub = keys[(i + 1) % 2][1]
+        items.append(p256.make_item(msg, sig, pub))
+        expect.append(how is None)
+    return keys, items, expect
+
+
+@pytest.fixture(scope="module")
+def mesh_launch():
+    """ONE interpret-mode launch of the shard-mapped comb kernel on four
+    virtual devices (its compile takes ~3 min here), through the engine
+    that runs it in production; every test below reads what it left."""
+    import functools
+
+    from smartbft_tpu.parallel import MeshVerifyEngine
+
+    keys, items, expect = _mesh_wave()
+    mp = pytest.MonkeyPatch()
+    try:
+        # what a TPU backend decides by itself; and the kernel's own
+        # interpret switch with a small tile, as the one-device test above
+        mp.setenv("SMARTBFT_PALLAS", "1")
+        mp.setattr(pc, "CombVerifier", functools.partial(
+            pc.CombVerifier, tile=MESH_TILE, interpret=True))
+        eng = MeshVerifyEngine(devices=MESH_DEVICES, pad_sizes=(8, 32, 64),
+                               scheme=p256)
+    finally:
+        mp.undo()
+    eng.prewarm_keys([pub for _, pub in keys])
+    got = eng.verify(items)
+    return eng, keys, items, expect, got
+
+
+def test_mesh_comb_launch_equals_every_other_verifier(mesh_launch):
+    """Lane for lane: the comb kernel on four devices == the comb kernel
+    on one == the XLA kernel == OpenSSL == what was corrupted."""
+    from smartbft_tpu.crypto.openssl_engine import OpenSSLVerifyEngine
+    from smartbft_tpu.crypto.provider import JaxVerifyEngine
+
+    eng, _keys, items, expect, got = mesh_launch
+    assert got == expect
+    reg = pc.CombKeyRegistry()
+    e8, r8, s8, kidx = pc.pack_items(items, reg)
+    one = pc.ecdsa_verify_comb(e8, r8, s8, kidx, pc.g_table(), reg.stacked(),
+                               tile=MESH_TILE, interpret=True)
+    assert [bool(v) for v in np.asarray(one)] == got
+    assert JaxVerifyEngine(pad_sizes=(16,), scheme=p256).verify(items) == got
+    assert OpenSSLVerifyEngine(scheme=p256).verify(items) == got
+    # padded lanes: 12 of the rung's 64 were real, the rest verified False
+    # on the device and were never read back
+    assert eng.stats.slots_used == 64 and eng.stats.sigs_verified == 12
+
+
+def test_mesh_comb_launch_is_counted_under_comb_and_spans_the_mesh(
+        mesh_launch):
+    from smartbft_tpu.crypto.provider import KERNELS
+
+    eng = mesh_launch[0]
+    s = eng.stats
+    assert s.launches_by_kernel == {**dict.fromkeys(KERNELS, 0), "comb": 1}
+    assert s.lanes_by_kernel["comb"] == 64 and s.used_by_kernel["comb"] == 12
+    # inputs and output laid out over all four devices, none below width
+    assert s.last_io_devices == (MESH_DEVICES, MESH_DEVICES)
+    assert s.launches_below_width == 0
+    assert eng.mesh_snapshot()["launches_below_width"] == 0
+    # strided placement: 12 lanes over 4 devices, 3 each
+    per_dev = 64 // MESH_DEVICES
+    counts = [round(f * per_dev / 100.0) for f in s.last_device_fill_pct]
+    assert counts == [3, 3, 3, 3] and s.launches_spanning_all_devices == 1
+    assert ("comb", 64, eng._comb.registry.slots()) in eng._launched
+
+
+def test_mesh_comb_tables_are_whole_on_every_device_and_follow_the_registry(
+        mesh_launch):
+    eng, keys = mesh_launch[0], mesh_launch[1]
+
+    def whole_everywhere(tab):
+        assert len(tab.sharding.device_set) == MESH_DEVICES
+        assert tab.sharding.is_fully_replicated
+        assert {s.data.shape for s in tab.addressable_shards} == {tab.shape}
+
+    # what the launch ran on
+    for tab in (eng._comb._dev_gtab, eng._comb._dev_qtab):
+        whole_everywhere(tab)
+    assert eng._comb._dev_qtab.shape == (2 * pc.ROWS, pc.TSIZE)
+    # a verifier of its own on the same mesh (growing the engine's
+    # registry would cost the tests after this one a second compile)
+    comb = pc.CombVerifier(mesh=eng.mesh)
+    for _, pub in keys:
+        comb.registry.register(pub)
+    qtab = comb._device_tables()[1]
+    assert comb._device_tables()[1] is qtab  # placed once per version
+    # a third key: the registry grows, the stack is placed anew, whole on
+    # every device again
+    _, pub3 = p256.keygen(b"ct-third")
+    comb.registry.register(pub3)
+    gtab, grown = comb._device_tables()
+    assert grown is not qtab and grown.shape == (4 * pc.ROWS, pc.TSIZE)
+    whole_everywhere(gtab)
+    whole_everywhere(grown)
+    assert np.array_equal(
+        np.asarray(grown.addressable_shards[3].data, np.float32),
+        comb.registry.stacked())
+
+
+def test_mesh_launcher_needs_no_second_compile_for_a_second_wave(mesh_launch):
+    """The same rung again (fewer lanes, other rows): the compiled launch
+    is reused and the verdicts un-permute to submission order."""
+    eng, _keys, items, expect, _got = mesh_launch
+    keep = [0, 2, 3, 5, 11]
+    n0 = eng._comb.launch_on_mesh._cache_size()
+    assert eng.verify([items[i] for i in keep]) == [expect[i] for i in keep]
+    assert eng._comb.launch_on_mesh._cache_size() == n0
+    assert eng.stats.launches_by_kernel["comb"] == 2
+    assert eng.stats.launches_spanning_all_devices == 2  # 5 lanes, 4 devices

@@ -492,3 +492,214 @@ def test_prewarm_verify_engine_compiles_every_rung():
     assert eng.stats.launches == 2            # one launch per rung
     assert eng.stats.slots_used == 16 + 64    # every shape compiled
     prewarm_verify_engine(always_valid_engine())  # no ladder: no-op
+
+
+# ------------------------------------------- the comb kernel on the mesh
+# (the kernel itself, in interpret mode on four devices:
+# tests/test_crypto_comb.py; here what the engine does around it)
+
+def _p256_votes(n, keys, forge=()):
+    items, expect = [], []
+    for i in range(n):
+        sk, pub = keys[i % len(keys)]
+        msg = b"ring-vote-%d" % i
+        sig = p256.sign_raw(sk, msg)
+        if i in forge:
+            sig = bytes([sig[0] ^ 1]) + sig[1:]
+        items.append(p256.make_item(msg, sig, pub))
+        expect.append(i not in forge)
+    return items, expect
+
+
+@pytest.mark.parametrize("devices, given, comb, xla", [
+    # mesh4-n16-p256: auto_pad_sizes(16, "p256", 16) on four chips
+    (4, (8, 32, 128, 256, 512), (512,), (8, 32, 128, 256, 512)),
+    (4, (8, 600, 2688), (512, 1024, 3072), (8, 600, 2688)),
+    (8, (16,), (1024,), (16,)),
+    (2, None, (256, 1024, 4096), (16, 128, 1024, 4096)),
+])
+def test_mesh_ladder_is_rounded_to_what_the_serving_kernel_can_launch(
+        monkeypatch, devices, given, comb, xla):
+    """Where the comb kernel serves, a device's share is whole 128-lane
+    tiles and rungs that would be the same launch are one rung; on the XLA
+    kernel a device multiple is enough.  The XLA ladder stays the one of
+    keys outside a pinned ring."""
+    monkeypatch.setenv("SMARTBFT_PALLAS", "1")  # what a TPU backend decides
+    on = MeshVerifyEngine(devices=devices, pad_sizes=given, scheme=p256)
+    assert on.pad_sizes == comb and on.request_pad_sizes == xla
+    assert on.supports_pallas and on._comb.mesh is on.mesh
+    assert on._pallas_kernel is None  # no arbitrary-key kernel on a mesh
+    monkeypatch.delenv("SMARTBFT_PALLAS")
+    off = MeshVerifyEngine(devices=devices, pad_sizes=given, scheme=p256)
+    assert off.pad_sizes == off.request_pad_sizes == xla
+    # a scheme whose comb kernel has no mesh wrapper stays on XLA
+    monkeypatch.setenv("SMARTBFT_PALLAS", "1")
+    from smartbft_tpu.crypto import ed25519
+
+    ed = MeshVerifyEngine(devices=devices, pad_sizes=given, scheme=ed25519)
+    assert ed._comb is None and ed.pad_sizes == xla and not ed._pallas_on
+
+
+def test_pinned_ring_on_a_mesh_engine_splits_comb_and_xla(monkeypatch):
+    """A mesh engine told a ring: ring keys ride the comb kernel (stubbed
+    here: it answers "valid" from the mesh, with the sharding the real
+    one answers with), keys outside it the sharded XLA kernel, recorded
+    ``xla``, verdicts in submission order — and no TypeError from the
+    generic flag the one-device engine passes down."""
+    import jax
+
+    monkeypatch.setenv("SMARTBFT_PALLAS", "1")
+    ring_keys = [p256.keygen(b"mesh-ring-%d" % i) for i in range(3)]
+    other_keys = [p256.keygen(b"mesh-client-%d" % i) for i in range(2)]
+    eng = MeshVerifyEngine(devices=8, pad_sizes=(16,), scheme=p256)
+    assert eng.pad_sizes == (1024,) and eng.request_pad_sizes == (16,)
+    eng.pin_ring([pub for _, pub in ring_keys])
+    seen = {}
+
+    def comb_stub(lanes, gtab, qtab):
+        seen["io"] = len(lanes.sharding.device_set)
+        seen["tables"] = [len(t.sharding.device_set) for t in (gtab, qtab)]
+        seen["shape"] = lanes.shape
+        return jax.device_put(np.ones(lanes.shape[0], np.uint32),
+                              eng._sharding)
+
+    monkeypatch.setattr(eng._comb, "launch_on_mesh", comb_stub)
+    votes, _ = _p256_votes(5, ring_keys)
+    envelopes, want = _p256_votes(6, other_keys, forge=(1, 4))
+    items = [votes[0], envelopes[0], envelopes[1], votes[1], votes[2],
+             envelopes[2], envelopes[3], votes[3], envelopes[4], votes[4],
+             envelopes[5]]
+    got = eng.verify(items)
+    assert [got[i] for i in (1, 2, 5, 6, 8, 10)] == want
+    assert all(got[i] for i in (0, 3, 4, 7, 9))
+    assert eng.stats.launches_by_kernel == {
+        **dict.fromkeys(KERNELS, 0), "comb": 1, "xla": 1}
+    assert eng.stats.used_by_kernel["comb"] == 5 \
+        and eng.stats.lanes_by_kernel["comb"] == 1024
+    assert eng.stats.used_by_kernel["xla"] == 6 \
+        and eng.stats.lanes_by_kernel["xla"] == 16
+    assert seen == {"io": 8, "tables": [8, 8], "shape": (1024, 100)}
+    assert eng.stats.launches_below_width == 0
+    # the clients' keys never reached the comb registry
+    assert len(eng._comb.registry) == 3
+    # all outside, all inside: one launch each, no split
+    assert eng.verify(envelopes) == want
+    assert eng.verify(votes) == [True] * 5
+    assert eng.stats.launches_by_kernel["xla"] == 2 \
+        and eng.stats.launches_by_kernel["comb"] == 2
+
+
+def test_graduating_onto_the_mesh_keeps_the_pinned_ring():
+    rings = Keyring.generate([1, 2, 3, 4], seed=b"mesh-keeps-ring")
+    ring = list(rings[1].public_keys.values())
+    prov = P256CryptoProvider(
+        rings[1], engine=JaxVerifyEngine(pad_sizes=(8,), ring=ring))
+    prov.configure_verify_mesh(4)
+    eng = prov.coalescer.engine
+    assert isinstance(eng, MeshVerifyEngine) and eng._ring == frozenset(ring)
+
+
+def test_a_launch_laid_out_below_the_mesh_width_is_counted():
+    import jax
+
+    eng = MeshVerifyEngine(devices=4, pad_sizes=(8,), scheme=toy_scheme)
+    items, expect = toy_items(6)
+    assert eng.verify(items) == expect
+    assert eng.stats.launches_below_width == 0
+    eng._place = lambda a: jax.device_put(a, jax.devices()[0])
+    assert eng.verify(items) == expect  # right verdicts, wrong layout
+    assert eng.stats.launches_below_width == 1
+    assert eng.stats.last_io_devices == (1, 1)
+    assert eng.mesh_snapshot()["launches_below_width"] == 1
+
+
+def test_mesh_launch_is_accounted_pack_place_device_and_by_device(tmp_path):
+    """While a profiler session is on, a mesh launch leaves, on its
+    thread: ``verify.pack``, ``verify.place`` and ``verify.device`` busy
+    spans, back to back, and a ``verify.lanes`` mark with its kernel and
+    the lanes used on each device; the account folds the mark into
+    ``lanes`` AND into its ``mesh`` block."""
+    import threading
+
+    import jax
+
+    from smartbft_tpu import obs
+    from smartbft_tpu.obs import recorder as recmod
+
+    eng = MeshVerifyEngine(devices=4, pad_sizes=(16,), scheme=toy_scheme)
+    items, expect = toy_items(6)
+    assert eng.verify(items) == expect  # compiled, and nothing recorded
+    out = {}
+
+    def launch():
+        recmod.set_thread_launch(7)
+        out["wide"] = eng.verify(items)
+        out["lone"] = eng.verify(items[:1])
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        obs.poll_profiler()
+        th = threading.Thread(target=launch, name="smartbft-verify-launch")
+        th.start()
+        th.join()
+        obs.poll_profiler()
+    finally:
+        jax.profiler.stop_trace()
+    obs.poll_profiler()
+    assert out == {"wide": expect, "lone": expect[:1]}
+    acc = obs.last_summary()
+    busy = acc["busy"]["smartbft-verify-launch"]
+    assert busy["verify.pack"]["calls"] == busy["verify.place"]["calls"] \
+        == busy["verify.device"]["calls"] == 2
+    assert acc["counters"]["launches"] == 2
+    assert acc["lanes"] == {"xla": {"launches": 2, "launched": 32,
+                                    "used": 7}}
+    assert acc["mesh"] == {
+        "launches": 2, "spanning": 1, "used": 7, "launched": 32,
+        "used_by_device": [3, 2, 1, 1], "launched_by_device": [8, 8, 8, 8]}
+    spans = sorted((e for e in obs.PROCESS.events()
+                    if e.kind in ("verify.pack", "verify.place",
+                                  "verify.device")), key=lambda e: e.t)
+    assert [e.kind for e in spans] == ["verify.pack", "verify.place",
+                                       "verify.device"] * 2
+    assert {e.launch for e in spans} == {7}
+
+
+def test_n16_committee_commits_through_a_four_device_mesh(tmp_path):
+    """`mesh4-n16-p256`'s shape on the CPU: 16 replicas (f = 5, quorum
+    11), one coalescer, ``verify_mesh_devices=4`` through the
+    Configuration knob; they commit and all 16 ledgers agree."""
+
+    def cfg(_s, i):
+        return dataclasses.replace(
+            sharded_config(i, depth=4), verify_mesh_devices=4,
+            verify_mesh_topology="1d")
+
+    async def run():
+        c = ShardedCluster(tmp_path, shards=1, n=16, depth=4, crypto="toy",
+                           config_fn=cfg)
+        await c.start()
+        try:
+            eng = c.coalescer.engine
+            assert isinstance(eng, MeshVerifyEngine) and eng.devices == 4
+            for j in range(8):
+                await c.submit(c.client_for_shard(0, j % 2), f"n16-{j}")
+            sh = c.shard_list[0]
+            await wait_for(lambda: sh.committed() >= 8, c.scheduler, 120.0)
+            await wait_for(
+                lambda: len({a.height() for a in sh.apps}) == 1,
+                c.scheduler, 60.0)
+            c.check_invariants()
+            ledgers = [[d.proposal.payload for d in a.ledger()]
+                       for a in sh.apps]
+            assert len(ledgers) == 16
+            assert all(led == ledgers[0] for led in ledgers)
+            mesh = c.coalescer.mesh_snapshot()
+            assert mesh["devices"] == 4 and mesh["downgrades"] == 0
+            assert mesh["launches"] >= 1
+            assert mesh["launches_below_width"] == 0
+            assert mesh["io_devices_last"] == [4, 4]
+        finally:
+            await c.stop()
+
+    asyncio.run(run())

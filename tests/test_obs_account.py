@@ -746,6 +746,83 @@ def test_reader_finds_nothing_in_an_empty_account(name):
     assert read(types.SimpleNamespace(account={})) is None
 
 
+#: the mesh deployment's readers (PR 32), on ACCOUNT with a mesh block
+MESH_READERS = {
+    "mesh_place_ms_per_launch": 1.5,
+    "mesh_fill_pct": 12.5,
+    "mesh_spanning_pct": 75.0,
+}
+MESH_ACCOUNT = dict(
+    ACCOUNT,
+    busy=dict(ACCOUNT["busy"], **{"smartbft-verify-launch": dict(
+        ACCOUNT["busy"]["smartbft-verify-launch"],
+        **{"verify.place": {"calls": 4, "self_s": 0.006, "dur_s": 0.006,
+                            "cpu_s": 0.001}})}),
+    mesh={"launches": 4, "spanning": 3, "used": 256, "launched": 2048,
+          "used_by_device": [64, 64, 64, 64],
+          "launched_by_device": [512, 512, 512, 512]})
+
+
+@pytest.mark.parametrize("name", sorted(MESH_READERS))
+def test_mesh_reader_on_a_hand_built_account(name):
+    run = types.SimpleNamespace(account=MESH_ACCOUNT)
+    assert _reader(name)(run) == pytest.approx(MESH_READERS[name])
+
+
+@pytest.mark.parametrize("name", sorted(MESH_READERS))
+def test_mesh_reader_finds_nothing_without_a_mesh(name):
+    """The parent's account (no ``mesh`` block, no ``verify.place``
+    span), a one-chip cell's (a block that saw no launch) and no account
+    at all give None, never a raise or a zero."""
+    read = _reader(name)
+    no_launch = dict(MESH_ACCOUNT, mesh={
+        "launches": 0, "spanning": 0, "used": 0, "launched": 0,
+        "used_by_device": [], "launched_by_device": []})
+    for account in (ACCOUNT, no_launch, {}):
+        assert read(types.SimpleNamespace(account=account)) is None
+
+
+def test_account_folds_mesh_launches_by_device():
+    """``verify.lanes`` marks that carry ``per_device`` (the mesh
+    engine's) feed ``lanes`` by kernel AND the ``mesh`` block; a
+    one-device engine's marks feed ``lanes`` alone; an interval with no
+    mesh launch reads zeros, not absent."""
+    def mark(t, kernel, lanes, used, per_device=None):
+        extra = {"kernel": kernel, "lanes": lanes, "used": used}
+        if per_device is not None:
+            extra["per_device"] = per_device
+        return SpanEvent(t, "verify.lanes", extra=extra)
+
+    acc = _account([
+        mark(1.0, "comb", 512, 23, [6, 6, 6, 5]),
+        mark(1.1, "comb", 512, 2, [1, 1, 0, 0]),
+        mark(1.2, "xla", 8, 3, [1, 1, 1, 0]),
+        mark(1.3, "comb", 128, 9),            # a one-device engine's
+        mark(9.0, "comb", 512, 40, [10] * 4),  # after the interval
+    ], t1=2.0)
+    assert acc["lanes"] == {
+        "comb": {"launches": 3, "launched": 1152, "used": 34},
+        "xla": {"launches": 1, "launched": 8, "used": 3}}
+    assert acc["mesh"] == {
+        "launches": 3, "spanning": 1, "used": 28, "launched": 1032,
+        "used_by_device": [8, 8, 7, 5],
+        "launched_by_device": [258, 258, 258, 258]}
+    assert _account([], t1=2.0)["mesh"]["launches"] == 0
+
+
+def test_the_mesh_readers_are_declared_for_the_mesh_cell_alone():
+    import json
+
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    declared = {m["name"]: m for m in bench["per_layer"]}
+    for name in MESH_READERS:
+        m = declared[name]
+        assert m["workloads"] == ["mesh16.saturated"]
+        assert m["layer"] == "verify plane"
+        assert m["source"] in ("program_span", "program_counter")
+
+
 def test_every_new_reader_is_declared_in_the_benchmark():
     import json
 
