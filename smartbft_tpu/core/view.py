@@ -761,9 +761,11 @@ class View:
         Flush policy: hold the batch until enough candidates are pending to
         possibly complete the quorum.  Eager flushing launched a partial
         wave (the first few arrivals) and then a second launch for the
-        rest; on accelerators where a launch costs ~100 ms of fixed
-        latency, one quorum-sized launch per decision halves the verify
-        latency on the critical path.  Liveness is unchanged: with too few
+        rest.  A launch is ~2.5 ms of fixed latency on a v5e (a rung-8
+        comb launch, host side included; PERF.md section 5) plus its round
+        through the coalescer, and only one is in flight at a time, so one
+        quorum-sized launch per decision still halves the verify latency
+        on the critical path.  Liveness is unchanged: with too few
         candidates we block on the next event exactly as before."""
         expected_digest = proposal_digest(proposal)
         valid: list[Signature] = []

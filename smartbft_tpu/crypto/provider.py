@@ -31,7 +31,7 @@ import os
 import random
 import threading
 import time
-from dataclasses import dataclass, field
+from dataclasses import asdict, dataclass, field
 from typing import Optional, Sequence
 
 import numpy as np
@@ -365,6 +365,48 @@ class FlushHoldStats:
         }
 
 
+#: consecutive quiet turns of the event loop after which the coalescer
+#: takes a batch's submitters to have gone quiet and closes it.  Replicas
+#: that share a loop submit a round's votes within one turn of each other
+#: (measured on the v5e host: PERF.md section 5), so the second quiet turn
+#: is the margin
+QUIET_TURNS = 2
+
+#: a turn is quiet only if no submit arrived in it AND it took no longer
+#: than this (seconds): the loop had nothing else to do.  An idle turn is
+#: the watch's own wake-up (~0.1 ms on the v5e host); a turn of milliseconds
+#: is a busy loop still working through the messages that make replicas
+#: submit, and closing under it cut a committee's vote wave into launches
+#: of one (n=16 on four chips: 2.4 x the launches, -6 % throughput)
+IDLE_TURN = 0.0005
+
+
+@dataclass
+class WindowStats:
+    """Why each batch left the coalescer, always on: ``quiet`` (its
+    submitters stopped arriving), ``window`` (a trickle that never paused
+    ran into the cap), ``full`` (``max_batch``), ``drain`` (it gathered
+    behind a launch in flight and left when that launch ended), ``flip``
+    (the eager flush of a view change), with the summed time from each
+    batch's first submit to its close.  Exported beside the hold's
+    accounting (``mesh_snapshot``'s ``window`` block)."""
+
+    quiet: int = 0
+    window: int = 0
+    full: int = 0
+    drain: int = 0
+    flip: int = 0
+    open_ms: float = 0.0
+
+    def note(self, closed_by: str, open_s: float) -> None:
+        setattr(self, closed_by, getattr(self, closed_by) + 1)
+        self.open_ms += 1e3 * open_s
+
+    def snapshot(self, window_s: float) -> dict:
+        return {"window_s": float(window_s), **asdict(self),
+                "open_ms": round(self.open_ms, 2)}
+
+
 class TagRateTracker:
     """Per-tag submit-cadence tracking: the occupancy signal behind
     flush gating (the PR 8 drain-rate-EWMA idiom, pointed at ARRIVALS).
@@ -505,7 +547,7 @@ class JaxVerifyEngine:
     and ``stats.launches_by_kernel`` records which kernel served.
     """
 
-    preferred_coalesce_window = 0.002  # batched engine: wait for fan-in
+    preferred_coalesce_window = 0.002  # the cap on a batch's wait for fan-in
 
     #: may a chunk be routed into a Pallas kernel?  An engine that places
     #: its lanes on a mesh and leaves the split to ``jit``
@@ -806,10 +848,14 @@ class AsyncBatchCoalescer:
     """Merges concurrent verify calls into shared kernel launches.
 
     The protocol core awaits ``submit(items)``; submissions that arrive
-    within ``window`` seconds (or until ``max_batch`` fills) are flushed as
-    one engine call on a worker thread.  This is the TPU analog of the
-    reference's per-signature goroutine fan-out — except the fan-*in* is
-    explicit, so one launch serves many sequences and replicas.
+    together are flushed as one engine call on a worker thread.  A batch
+    closes as soon as its submitters have gone quiet (no new arrival for
+    :data:`QUIET_TURNS` idle turns of the loop), when ``max_batch`` fills, or
+    when ``window`` seconds have passed since its first submit: the window
+    is the LONGEST a batch may wait for company, not how long it always
+    waits.  This is the TPU analog of the reference's per-signature
+    goroutine fan-out — except the fan-*in* is explicit, so one launch
+    serves many sequences and replicas.
     """
 
     def __init__(self, engine, window: float = 0.002, max_batch: int = 2048,
@@ -871,6 +917,8 @@ class AsyncBatchCoalescer:
         self.hold = float(hold) if hold else 0.0
         self._hold_explicit = hold is not None
         self.hold_stats = FlushHoldStats()
+        #: why and after how long each batch closed (always on)
+        self.window_stats = WindowStats()
         self._tag_rates = TagRateTracker(default_gap=max(window, 0.001))
         #: mesh graduation accounting (CryptoProvider.configure_verify_mesh
         #: writes these; they live on the coalescer because the coalescer
@@ -885,6 +933,12 @@ class AsyncBatchCoalescer:
         self._launch_seq = 0
         self._pending: list[tuple] = []
         self._futures: list[tuple[asyncio.Future, int, int, object]] = []
+        #: the open batch: its number (what a watch checks to see that the
+        #: batch is still its own) and the instant of its first submit, on
+        #: the monotonic clock and on the recorder's (None: recorder off)
+        self._batch_seq = 0
+        self._batch_opened = 0.0
+        self._batch_opened_rec: Optional[float] = None
         self._flush_scheduled = False
         self._launch_inflight = False
         self._lock = asyncio.Lock()
@@ -964,10 +1018,10 @@ class AsyncBatchCoalescer:
         )
         self.flip_warms += 1
         if self._pending and not self._launch_inflight:
-            # flush NOW even when a windowed flush is already parked in
-            # its sleep: the immediate task swaps the batch out and the
-            # stale sleeper later wakes to an empty (or fresher) batch —
-            # exactly the race _flush_after is already written to absorb.
+            # flush NOW even when a windowed flush is already watching
+            # the batch: the immediate task swaps the batch out and the
+            # watch, finding the batch no longer its own, ends without a
+            # flush.
             # Probe for the loop BEFORE building the coroutine: a no-loop
             # caller just arms the mode (the next submit flushes eagerly),
             # and an abandoned coroutine would warn "never awaited".
@@ -976,7 +1030,7 @@ class AsyncBatchCoalescer:
             except RuntimeError:
                 return
             create_logged_task(
-                self._flush_after(0.0), name="coalescer-flush-flip",
+                self._flush_after(0.0, "flip"), name="coalescer-flush-flip",
                 busy=(self.recorder, "verify.flush"),
             )
             self._flush_scheduled = True
@@ -1045,6 +1099,8 @@ class AsyncBatchCoalescer:
             # occupancy-aware flush gating decisions (ISSUE 11): every
             # hold the gate took, its cost, and its depth payoff
             "hold": self.hold_stats.snapshot(self.hold),
+            # why each batch closed, and how long batches stood open
+            "window": self.window_stats.snapshot(self.window),
         }
         snap = getattr(eng, "mesh_snapshot", None)
         if snap is not None:
@@ -1064,9 +1120,13 @@ class AsyncBatchCoalescer:
         fut: asyncio.Future = loop.create_future()
         rec = self.recorder
         t_enqueue = rec.now() if rec.enabled else None
-        self._tag_rates.note(tag, time.monotonic())
+        now = time.monotonic()
+        self._tag_rates.note(tag, now)
         async with self._lock:
             start = len(self._pending)
+            if not start:  # this submit opens a batch
+                self._batch_opened = now
+                self._batch_opened_rec = t_enqueue
             self._pending.extend(items)
             self._futures.append((fut, start, len(items), tag))
             # _flush_scheduled covers exactly the CURRENT batch: it resets
@@ -1081,18 +1141,22 @@ class AsyncBatchCoalescer:
                 pass
             elif len(self._pending) >= self.max_batch:
                 create_logged_task(
-                    self._flush_after(0.0), name="coalescer-flush-full",
+                    self._flush_after(0.0, "full"),
+                    name="coalescer-flush-full",
                     busy=(self.recorder, "verify.flush"),
                 )
                 self._flush_scheduled = True
             elif not self._flush_scheduled:
                 self._flush_scheduled = True
-                # flip-warm mode: the failover transient flushes eagerly
-                # (no coalescing window) so the new view's first waves
-                # launch at once
-                delay = 0.0 if self._flip_warm() else self.window
+                # the batch's first submit: watch it until its submitters
+                # have gone quiet, for at most ``window`` seconds.
+                # Flip-warm mode: the failover transient flushes eagerly
+                # (no watch at all) so the new view's first waves launch
+                # at once
+                flush = self._flush_after(0.0, "flip") if self._flip_warm() \
+                    else self._flush_after(self.window)
                 create_logged_task(
-                    self._flush_after(delay), name="coalescer-flush",
+                    flush, name="coalescer-flush",
                     busy=(self.recorder, "verify.flush"),
                 )
         if t_enqueue is None:
@@ -1169,9 +1233,47 @@ class AsyncBatchCoalescer:
                 rec.wait("verify.hold", t_hold,
                          extra={"depth_gain": gain, "expired": expired})
 
-    async def _flush_after(self, delay: float) -> None:
-        if delay:
-            await asyncio.sleep(delay)
+    async def _watch(self, window: float) -> Optional[str]:
+        """Wait for the open batch's company, a turn of the loop at a
+        time: until :data:`QUIET_TURNS` turns in a row were quiet (no
+        submit joined the batch and the loop did nothing else for longer
+        than :data:`IDLE_TURN`: everyone who was going to submit has), or
+        ``window`` seconds have passed since its first submit (a trickle
+        that never pauses is cut there, as it always was).  -> why the
+        batch closes, or None when another flush (``max_batch``, a view
+        flip) has taken it meanwhile.  It watches only what the coalescer
+        itself observes, its arrivals and the length of its own turns, so
+        it adapts to any deployment: one replica a process closes after
+        two idle turns, a committee on a busy loop rides the cap."""
+        batch = self._batch_seq
+        deadline = self._batch_opened + window
+        seen, quiet = len(self._pending), 0
+        woke = time.monotonic()
+        while True:
+            await asyncio.sleep(0)
+            now = time.monotonic()
+            idle, woke = now - woke <= IDLE_TURN, now
+            n = len(self._pending)
+            if self._batch_seq != batch or not n:
+                return None
+            if n > seen or not idle:
+                seen, quiet = n, 0
+            else:
+                quiet += 1
+                if quiet >= QUIET_TURNS:
+                    return "quiet"
+            if now >= deadline:
+                return "window"
+
+    async def _flush_after(self, window: float,
+                           closed_by: str = "window") -> None:
+        """Close the open batch and launch it: at once for ``window`` 0
+        (``closed_by`` says on whose account), else when :meth:`_watch`
+        says so."""
+        if window:
+            closed_by = await self._watch(window)
+            if closed_by is None:
+                return
         await self._maybe_hold()
         # swap under the lock, verify outside it — submissions arriving
         # during the kernel launch accumulate into the NEXT batch
@@ -1183,17 +1285,26 @@ class AsyncBatchCoalescer:
             pending, futures = self._pending, self._futures
             self._pending, self._futures = [], []
             self._flush_scheduled = False
+            opened, opened_rec = self._batch_opened, self._batch_opened_rec
             if pending:
                 self._launch_inflight = True
+                self._batch_seq += 1
         if not pending:
             return
+        self.window_stats.note(closed_by, time.monotonic() - opened)
         # attribution happens when the wave's composition is fixed, so a
         # failed launch still counts its shard mix
         self.shard_stats.note_wave(futures)
         self._launch_seq += 1
         launch_id = self._launch_seq
         rec = self.recorder
-        t_launch = rec.now() if rec.enabled else None
+        t_launch = None
+        if rec.enabled:
+            # a wait: the batch's first enqueue -> the batch swapped out
+            rec.wait("verify.window", opened_rec, launch=launch_id,
+                     extra={"closed_by": closed_by, "items": len(pending),
+                            "submitters": len(futures)})
+            t_launch = rec.now()
         try:
             results = await self._launch_wave(pending)
         except Exception as exc:
@@ -1224,7 +1335,8 @@ class AsyncBatchCoalescer:
             if self._pending and not self._flush_scheduled:
                 self._flush_scheduled = True
                 create_logged_task(
-                    self._flush_after(0.0), name="coalescer-flush-drain",
+                    self._flush_after(0.0, "drain"),
+                    name="coalescer-flush-drain",
                     busy=(self.recorder, "verify.flush"),
                 )
 

@@ -64,7 +64,7 @@ from .critpath import DECISION_SEGMENTS, decision_rows
 __all__ = ["assemble_account"]
 
 #: wait kinds recorded as such, taken as they are
-_WAIT_KINDS = ("verify.wait", "verify.hold", "wal.persist",
+_WAIT_KINDS = ("verify.wait", "verify.hold", "verify.window", "wal.persist",
                "request.verify", "proposal.verify")
 
 

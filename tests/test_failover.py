@@ -476,12 +476,28 @@ def test_coalescer_flip_warm_flushes_already_pending_wave():
     from smartbft_tpu.testing.engine_faults import always_valid_engine
 
     async def run():
-        co = AsyncBatchCoalescer(always_valid_engine(), window=30.0)
-        fut = asyncio.ensure_future(co.submit([("sig", 1, b"m")]))
-        await asyncio.sleep(0.05)       # parked in the 30 s window
-        assert not fut.done()
+        co = AsyncBatchCoalescer(always_valid_engine(), window=30.0,
+                                 max_batch=1 << 30)
+        futs = []
+
+        async def trickle():
+            # a submit every turn of the loop: the batch never goes quiet,
+            # so only its 30 s cap (or a flip) can close it
+            while True:
+                futs.append(asyncio.ensure_future(
+                    co.submit([("sig", 1, b"m")])))
+                await asyncio.sleep(0)
+
+        feeder = asyncio.ensure_future(trickle())
+        await asyncio.sleep(0.05)       # parked: growing under the cap
+        assert len(futs) > 2 and not any(f.done() for f in futs)
+        assert co.window_stats.snapshot(30.0)["quiet"] == 0
         co.note_view_flip()             # the flip flushes it NOW
-        assert await asyncio.wait_for(fut, timeout=5.0) == [True]
+        assert await asyncio.wait_for(futs[0], timeout=5.0) == [True]
+        feeder.cancel()
+        await asyncio.wait_for(asyncio.gather(*futs), timeout=5.0)
+        stats = co.window_stats
+        assert stats.flip >= 1 and stats.window == 0 and stats.quiet == 0
 
     asyncio.run(run())
 

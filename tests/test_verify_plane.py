@@ -226,10 +226,11 @@ def test_result_length_mismatch_counts_as_launch_failure_with_policy():
 # -- double-flush window (satellite) ------------------------------------------
 
 def test_double_flush_race_is_harmless_no_op():
-    """When max_batch fills while a window flush is already scheduled, two
-    _flush_after tasks race: the first swaps the batch out, the second must
-    be an empty-pending no-op — every future resolves exactly once with its
-    own verdicts, and the engine sees each item exactly once."""
+    """When max_batch fills while a window flush is already watching the
+    batch, two _flush_after tasks race: the first swaps the batch out, the
+    second must find the batch no longer its own and be a no-op — every
+    future resolves exactly once with its own verdicts, and the engine sees
+    each item exactly once."""
 
     class RecordingEngine:
         def __init__(self):
@@ -244,7 +245,7 @@ def test_double_flush_race_is_harmless_no_op():
 
     async def run():
         f1 = asyncio.get_running_loop().create_task(co.submit([("ok", 1)]))
-        await asyncio.sleep(0)  # window flush (0.05s) is now scheduled
+        await asyncio.sleep(0)  # the window flush is now watching it
         # this fill crosses max_batch and schedules a SECOND, immediate
         # flush while the first is still pending
         f2 = asyncio.get_running_loop().create_task(
@@ -252,7 +253,7 @@ def test_double_flush_race_is_harmless_no_op():
         )
         r1 = await asyncio.wait_for(f1, 5)
         r2 = await asyncio.wait_for(f2, 5)
-        # outlast the window timer so the late no-op flush also runs
+        # outlast the window so the watch has ended too
         await asyncio.sleep(0.1)
         return r1, r2
 
