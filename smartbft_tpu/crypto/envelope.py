@@ -9,6 +9,14 @@ over everything before the trailer::
     <client id> <request id> <payload>  u32(64) <creator>  u32(64) <r || s>
     |------------ signed ------------|  |------------- trailer ----------|
 
+An envelope may NAME the channel it is for: its payload then starts with
+a channel header (:func:`channel_header`: a fixed magic, a length byte,
+the channel's name), inside the signed part, so the creator's signature
+covers it.  An envelope that names no channel has the bytes it always
+had.  A replica of a named channel (``EnvelopeVerifier(channel=...)``)
+refuses every envelope that does not name it (``wrong_channel``), where
+it refuses a forged one: at the front door, at a forward, in a proposal.
+
 Replicas hold the channel's ENROLLED identities — a set of public keys.
 It stands for the MSP's cached certificate validation (a departure from
 Fabric, which validates a certificate chain per creator): an envelope
@@ -32,8 +40,9 @@ from ..codec import encode, wiremsg
 from ..obs.recorder import standby
 from . import p256
 
-__all__ = ["EnvelopeRejected", "EnvelopeVerifier", "TRAILER",
-           "creator_bytes", "sign_envelope", "split_envelope"]
+__all__ = ["CHANNEL_MAGIC", "EnvelopeRejected", "EnvelopeVerifier", "TRAILER",
+           "channel_header", "creator_bytes", "envelope_channel",
+           "sign_envelope", "split_envelope"]
 
 _KEY = 64  # X || Y
 _SIG = 64  # r || s
@@ -43,10 +52,15 @@ TRAILER = 2 * _LEN.size + _KEY + _SIG
 _KEY_PREFIX = _LEN.pack(_KEY)
 _SIG_PREFIX = _LEN.pack(_SIG)
 
+#: a payload that starts with these bytes names the envelope's channel:
+#: the magic, one length byte, the channel's name (UTF-8, 1-255 bytes),
+#: then the embedder's own payload
+CHANNEL_MAGIC = b"\x00tpubft.channel\x00"
+
 #: why an envelope was refused (the ``cause`` of :class:`EnvelopeRejected`,
 #: the keys of :attr:`EnvelopeVerifier.rejected`, the ``req.rejected``
 #: mark's ``cause``)
-CAUSES = ("malformed", "not_enrolled", "bad_signature")
+CAUSES = ("malformed", "not_enrolled", "bad_signature", "wrong_channel")
 
 
 @wiremsg
@@ -74,10 +88,47 @@ def creator_bytes(pub) -> bytes:
     return pub[0].to_bytes(32, "big") + pub[1].to_bytes(32, "big")
 
 
+def channel_header(channel: str) -> bytes:
+    """The bytes that name ``channel`` at the start of a payload."""
+    name = channel.encode()
+    if not 0 < len(name) < 256:
+        raise ValueError(f"a channel name is 1-255 bytes, got {channel!r}")
+    return CHANNEL_MAGIC + bytes((len(name),)) + name
+
+
+def envelope_channel(raw: bytes):
+    """The channel ``raw`` names (an envelope, its signed part, or an
+    unsigned request of the same layout), or None where it names none;
+    read by offset, nothing decoded.  ``EnvelopeRejected("malformed")``
+    where the bytes end inside a field or the header."""
+    try:
+        at = 4 + _LEN.unpack_from(raw, 0)[0]
+        at += 4 + _LEN.unpack_from(raw, at)[0]
+        size = _LEN.unpack_from(raw, at)[0]
+        at += 4
+        head = at + len(CHANNEL_MAGIC)
+        if raw[at:head] != CHANNEL_MAGIC:
+            return None
+        end = head + 1 + raw[head]
+        if end > at + size:
+            raise IndexError
+        return raw[head + 1:end].decode()
+    except (struct.error, IndexError, UnicodeDecodeError):
+        raise EnvelopeRejected("malformed", "fields or channel header cut "
+                               "short") from None
+
+
 def sign_envelope(private: int, public, client_id: str, request_id: str,
-                  payload: bytes = b"") -> bytes:
+                  payload: bytes = b"", *, channel=None) -> bytes:
     """Build and sign one envelope with the native signer
-    (``p256.sign_raw``)."""
+    (``p256.sign_raw``).  ``channel``: the channel it names, in a header
+    before ``payload`` and under the signature; None names none, and the
+    bytes are the ones an envelope always had."""
+    if channel is not None:
+        payload = channel_header(channel) + payload
+    elif payload.startswith(CHANNEL_MAGIC):
+        raise ValueError("the payload starts with the channel header's "
+                         "magic: name the channel with channel=")
     signed = encode(_Signed(client_id=client_id, request_id=request_id,
                             payload=payload))
     return b"".join((signed, _KEY_PREFIX, creator_bytes(public),
@@ -109,7 +160,11 @@ class EnvelopeVerifier:
 
     def __init__(self, enrolled: Iterable, *, engine,
                  submit: Optional[Callable[[list], Awaitable[list]]] = None,
-                 recorder=None):
+                 recorder=None, channel: Optional[str] = None):
+        #: the channel these replicas order for: every envelope has to
+        #: name it.  None: an unnamed channel, whose envelopes' payloads
+        #: are not looked into
+        self.channel = channel
         self._pub_of = {creator_bytes(pub): pub for pub in enrolled}
         if not self._pub_of:
             raise ValueError("an EnvelopeVerifier needs enrolled identities")
@@ -133,9 +188,15 @@ class EnvelopeVerifier:
 
     def item(self, raw: bytes) -> tuple:
         """The verify item of one envelope, or ``EnvelopeRejected``
-        (``malformed`` / ``not_enrolled``), counted."""
+        (``malformed`` / ``wrong_channel`` / ``not_enrolled``), counted."""
         try:
             signed, creator, signature = split_envelope(raw)
+            if self.channel is not None:
+                named = envelope_channel(signed)
+                if named != self.channel:
+                    raise EnvelopeRejected(
+                        "wrong_channel",
+                        f"names {named!r}, this is {self.channel!r}")
             pub = self._pub_of.get(creator)
             if pub is None:
                 raise EnvelopeRejected("not_enrolled",

@@ -931,6 +931,8 @@ class AsyncBatchCoalescer:
         #: breaker: ONE recorder serves every colocated shard.
         self.recorder = standby(node="verify")
         self._launch_seq = 0
+        #: the tags of the launch in flight's submitters (recorder on)
+        self._launch_tags: list = []
         self._pending: list[tuple] = []
         self._futures: list[tuple[asyncio.Future, int, int, object]] = []
         #: the open batch: its number (what a watch checks to see that the
@@ -1300,6 +1302,8 @@ class AsyncBatchCoalescer:
         rec = self.recorder
         t_launch = None
         if rec.enabled:
+            self._launch_tags = sorted(
+                {str(f[3]) for f in futures if f[3] is not None})
             # a wait: the batch's first enqueue -> the batch swapped out
             rec.wait("verify.window", opened_rec, launch=launch_id,
                      extra={"closed_by": closed_by, "items": len(pending),
@@ -1592,8 +1596,9 @@ class AsyncBatchCoalescer:
         engine = self.engine if engine is None else engine
         name_this_thread()
         if self.recorder.enabled:
-            # the engine's pack / device spans on this thread carry it
-            set_thread_launch(self._launch_seq)
+            # the engine's pack / device spans on this thread carry it,
+            # its lane marks the submitters' tags
+            set_thread_launch(self._launch_seq, self._launch_tags)
         if not self.dedupe:
             return self._engine_call(engine, pending)
         try:

@@ -373,12 +373,13 @@ class ReplicaApp(EnvelopeChecks, Application, Assembler, Comm, Signer,
         self.transport.recorder = self.recorder
         # enrolled client identities (spec "enrolled": hex X || Y each):
         # every request is then a signed envelope, verified where the
-        # in-process App verifies it (crypto.envelope)
+        # in-process App verifies it (crypto.envelope); spec "channel":
+        # the name each of them has to carry
         enrolled = [(int(h[:64], 16), int(h[64:], 16))
                     for h in spec.get("enrolled", ())]
         self.enroll(enrolled,
                     _request_crypto(self.id) if enrolled else None,
-                    self.recorder)
+                    self.recorder, channel=spec.get("channel"))
         # cluster health plane (ISSUE 14): every replica judges itself
         # against the declarative SLO spec on a periodic tick; cmd=health
         # serves the verdict, SocketCluster.cluster_health aggregates n

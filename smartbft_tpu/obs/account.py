@@ -40,6 +40,21 @@ cover the same span of time.
                        the same two ``by_device``; zeros and empty lists
                        where no launch was
 ``rejected``           cause -> client envelopes refused (``req.rejected``)
+``channels``           colocated groups behind ONE verify plane.
+                       ``per_channel``: the group's tag (a replica's
+                       recorder is ``s<tag>n<i>``, its submits to the
+                       coalescer carry the tag) -> ``decisions`` and
+                       ``requests`` (delivered by the replica that
+                       proposed them), ``verify_wait_ms`` (the median
+                       ``verify.wait`` of its replicas; None where none)
+                       over ``verify_waits``, ``rejected`` by cause.  The
+                       shared plane, from the ``verify.lanes`` marks that
+                       carry their submitters' tags: ``launches`` (waves
+                       of the coalescer), ``mixed_launches`` (of them,
+                       those that carried two or more groups' items),
+                       ``kernels``: kernel -> ``launches`` and ``used``
+                       lanes of those waves.  Empty where no recorder
+                       was a group's
 ``segments``           segment -> ms per decision (:func:`decision_rows`)
 ``decisions``          the rows themselves: ``view``, ``seq``, ``node``,
                        ``total_ms`` and one ms value per segment
@@ -57,6 +72,7 @@ cover the same span of time.
 
 from __future__ import annotations
 
+import statistics
 from typing import Optional, Sequence
 
 from .critpath import DECISION_SEGMENTS, decision_rows
@@ -83,6 +99,33 @@ def _fold_mesh_launch(mesh: dict, mark: dict) -> None:
     mesh["spanning"] += min(used) > 0
     mesh["used"] += mark["used"]
     mesh["launched"] += mark["lanes"]
+
+
+def _group_of(node: str) -> Optional[str]:
+    """The group tag in a replica recorder's label ``s<tag>n<i>`` (or
+    ``s<tag>g<gen>n<i>``), None for any other label."""
+    if not node.startswith("s"):
+        return None
+    digits = node[1:].split("n", 1)[0].split("g", 1)[0]
+    return digits if digits.isdigit() else None
+
+
+def _channels_block(per_channel: dict, waits_of: dict, launches: dict,
+                    kernels: dict) -> dict:
+    """``per_channel``: tag -> running counts; ``waits_of``: tag -> its
+    ``verify.wait`` values; ``launches``: wave id -> its tags."""
+    if not per_channel:
+        return {}
+    for tag, ch in per_channel.items():
+        waits = waits_of.get(tag, ())
+        ch["verify_waits"] = len(waits)
+        ch["verify_wait_ms"] = statistics.median(waits) if waits else None
+    return {
+        "per_channel": dict(sorted(per_channel.items())),
+        "launches": len(launches),
+        "mixed_launches": sum(len(t) >= 2 for t in launches.values()),
+        "kernels": kernels,
+    }
 
 
 def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
@@ -133,11 +176,32 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
     submits: dict = {}
     delivered = []
     fsync_ms: list = []
+    per_channel: dict = {}
+    channel_waits: dict = {}
+    tagged_launches: dict = {}
+    tagged_kernels: dict = {}
+
+    def channel(tag: str) -> dict:
+        ch = per_channel.get(tag)
+        if ch is None:
+            ch = per_channel[tag] = {"decisions": 0, "requests": 0,
+                                     "rejected": {}}
+        return ch
+
+    for r in recorders:  # a group is there even where it did nothing
+        tag = _group_of(getattr(r, "node", ""))
+        if tag is not None:
+            channel(tag)
     for e in inside:
         kind = e.kind
         if kind == "decision.deliver":
             if (e.extra or {}).get("proposer"):
                 counters["decisions"] += 1
+                tag = _group_of(e.node)
+                if tag is not None:
+                    ch = channel(tag)
+                    ch["decisions"] += 1
+                    ch["requests"] += e.extra.get("count", 0)
         elif kind == "batch.propose":
             counters["requests_proposed"] += (e.extra or {}).get("count", 0)
         elif kind == "verify.device":
@@ -160,9 +224,22 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
             per["used"] += x["used"]
             if "per_device" in x:
                 _fold_mesh_launch(mesh, x)
+            if "tags" in x:
+                tagged_launches[e.launch] = x["tags"]
+                per = tagged_kernels.setdefault(
+                    x["kernel"], {"launches": 0, "used": 0})
+                per["launches"] += 1
+                per["used"] += x["used"]
         elif kind == "req.rejected":
             cause = (e.extra or {}).get("cause", "?")
             rejected[cause] = rejected.get(cause, 0) + 1
+            tag = _group_of(e.node)
+            if tag is not None:
+                by = channel(tag)["rejected"]
+                by[cause] = by.get(cause, 0) + 1
+        elif kind == "verify.wait" and e.dur >= 0.0:
+            channel_waits.setdefault((e.extra or {}).get("tag"),
+                                     []).append(e.dur * 1e3)
         elif kind == "req.handover":
             counters["handovers"] += 1
         elif kind == "req.not_leader":
@@ -198,6 +275,8 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
         "lanes": lanes,
         "mesh": mesh,
         "rejected": rejected,
+        "channels": _channels_block(per_channel, channel_waits,
+                                    tagged_launches, tagged_kernels),
         "segments": {seg: [r[seg] for r in rows]
                      for seg in DECISION_SEGMENTS},
         "decisions": rows,

@@ -158,9 +158,10 @@ class _Open:
 
 class _ThreadState:
     """A thread's open busy spans and the verify launch it is serving
-    (the identifier the spans of one launch share)."""
+    (the identifier the spans of one launch share, and the tags of the
+    submitters whose items the launch carries)."""
 
-    __slots__ = ("ident", "name", "stack", "launch", "named", "gc")
+    __slots__ = ("ident", "name", "stack", "launch", "tags", "named", "gc")
 
     def __init__(self):
         self.ident = threading.get_ident()
@@ -171,6 +172,7 @@ class _ThreadState:
         self.name = None
         self.stack: list = []
         self.launch = -1
+        self.tags: Sequence = ()
         self.named = False
         self.gc = None  # (start, annotation) while a collection runs
 
@@ -239,10 +241,14 @@ def close_for_await() -> None:
         stack[-1].rec.end(stack[-1])
 
 
-def set_thread_launch(launch: int) -> None:
+def set_thread_launch(launch: int, tags: Sequence = ()) -> None:
     """Name the verify launch the calling thread is about to serve; the
-    engine's ``verify.pack`` / ``verify.device`` spans carry it."""
-    _state().launch = launch
+    engine's ``verify.pack`` / ``verify.device`` spans carry it.
+    ``tags``: the submitters (channels) whose items ride it, carried by
+    its ``verify.lanes`` marks."""
+    st = _state()
+    st.launch = launch
+    st.tags = tags
 
 
 def name_this_thread() -> None:
@@ -675,12 +681,17 @@ def note_lanes(kernel: str, lanes: int, used: int,
     the kernel that served it: a mark on the launch's thread, folded into
     the account's ``lanes`` block.  ``per_device``: a mesh launch's used
     lanes on each device (its lanes are split evenly), folded into the
-    account's ``mesh`` block too.  Off: one attribute read."""
+    account's ``mesh`` block too.  The mark carries the tags of the
+    launch's submitters (:func:`set_thread_launch`), folded into the
+    account's ``channels`` block.  Off: one attribute read."""
     if PROCESS.enabled:
+        st = _state()
         extra = {"kernel": kernel, "lanes": lanes, "used": used}
         if per_device is not None:
             extra["per_device"] = list(per_device)
-        PROCESS.record("verify.lanes", launch=_state().launch, extra=extra)
+        if st.tags:
+            extra["tags"] = list(st.tags)
+        PROCESS.record("verify.lanes", launch=st.launch, extra=extra)
 
 
 def _live_recorders() -> list:

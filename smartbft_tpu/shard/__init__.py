@@ -29,9 +29,10 @@ from .autoscale import OccupancyAutoscaler, run_autoscaler
 from .epoch import EpochJournal, ShardEpochError
 from .mux import CommittedEntry, DeliveryMux, ShardStreamViolation
 from .router import ShardRouter, jump_hash
-from .set import ShardHandle, ShardSet
+from .set import ChannelNotServed, ShardHandle, ShardSet
 
 __all__ = [
+    "ChannelNotServed",
     "CommittedEntry",
     "DeliveryMux",
     "EpochJournal",

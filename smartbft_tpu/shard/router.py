@@ -17,6 +17,10 @@ README "Sharded mode").  Two properties matter:
 Mir-BFT (Stathakopoulou et al., 2021) partitions the request space by
 client-id hash for the same reason: independent instances over disjoint
 request spaces multiply throughput without weakening per-group safety.
+
+A request that NAMES its group (a Fabric envelope's channel id) is not
+this router's to place: ``ShardSet.submit(..., channel=)`` looks the name
+up, and the router serves the requests that name none.
 """
 
 from __future__ import annotations

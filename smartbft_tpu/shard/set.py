@@ -7,7 +7,11 @@ totally-ordered chain — and the ShardSet owns everything that spans them:
 * the client-facing **front door**: ``submit`` routes by client id through
   a deterministic :class:`~smartbft_tpu.shard.router.ShardRouter` and
   forwards into the owning shard's request pool (per-shard backpressure
-  applies; ``occupancy`` exposes the combined surface);
+  applies; ``occupancy`` exposes the combined surface).  Where the groups
+  are NAMED CHANNELS (:meth:`ShardSet.name_channels`: a Fabric orderer's
+  channels, each a chain of its own), a request that names its channel
+  (``submit(..., channel=)``) goes to that channel's group whoever its
+  client is; the router keeps serving the requests that name none;
 * the **delivery multiplexer**: ``poll_committed`` drains each shard's
   newly committed decisions into one :class:`~smartbft_tpu.shard.mux.
   DeliveryMux` stream, enforcing per-shard exactly-once/gapless, and
@@ -76,7 +80,21 @@ from ..obs.recorder import close_for_await
 from ..utils import gchold
 from ..utils.tasks import create_logged_task
 
-__all__ = ["ShardHandle", "ShardSet"]
+__all__ = ["ChannelNotServed", "ShardHandle", "ShardSet"]
+
+
+class ChannelNotServed(LookupError):
+    """A request names a channel this host does not serve.  ``cause`` is
+    the structured reason (as ``EnvelopeRejected.cause`` is a replica's),
+    ``channel`` the name asked for, ``served`` the names there are."""
+
+    cause = "unknown_channel"
+
+    def __init__(self, channel, served):
+        self.channel = channel
+        self.served = tuple(sorted(served))
+        super().__init__(f"channel {channel!r} is not served here "
+                         f"(served: {list(self.served)})")
 
 
 class ShardHandle(abc.ABC):
@@ -88,6 +106,9 @@ class ShardHandle(abc.ABC):
     documents the protocol and provides the registration hook."""
 
     shard_id: int
+    #: the channel this group orders for, where the set's groups are
+    #: named channels (set by :meth:`ShardSet.name_channels`)
+    channel: Optional[str] = None
 
     @abc.abstractmethod
     async def start(self) -> None: ...
@@ -257,6 +278,9 @@ class ShardSet:
                 f"set has {len(shards)}"
             )
         self.coalescer = coalescer
+        #: channel name -> shard id; empty unless the groups are named
+        #: channels (:meth:`name_channels`)
+        self.channels: dict[str, int] = {}
         self.journal = journal
         self.drain_deadline = drain_deadline
         self.retention = retention
@@ -392,14 +416,40 @@ class ShardSet:
 
     # -- the front door ----------------------------------------------------
 
+    def name_channels(self, names: Sequence[str]) -> None:
+        """The groups are named channels from here on: shard ``k`` orders
+        for ``names[k]`` and a request that names a channel is placed by
+        that name, not by its client.  A channel is a chain of its own
+        with its own members, not a range of the client space: such a set
+        is not resharded."""
+        names = [str(n) for n in names]
+        if len(names) != len(self.shards) or len(set(names)) != len(names) \
+                or "" in names:
+            raise ValueError(
+                f"{len(self.shards)} shards need as many distinct channel "
+                f"names, got {names}")
+        if self._transition is not None:
+            raise ShardEpochError(
+                "a set in the middle of a reshard cannot turn into named "
+                "channels")
+        self.channels = {name: sid for sid, name in enumerate(names)}
+        for name, sid in self.channels.items():
+            self.shards[sid].channel = name
+
     def route(self, client_id) -> int:
         return self.router.route(client_id, epoch=self._epoch)
 
     async def submit(self, client_id, raw_request: bytes,
-                     *, request_key: Optional[str] = None) -> int:
+                     *, request_key: Optional[str] = None,
+                     channel: Optional[str] = None) -> int:
         """Route ``client_id``'s request to its owning shard (in the
         ACTIVE epoch) and forward into that shard's pool.  Returns the
         shard id it landed on.
+
+        ``channel``: the channel the request names.  It then goes to that
+        channel's group whoever the client is, and
+        :class:`ChannelNotServed` is raised where no group has that name
+        (a set without named channels serves none).
 
         Backpressure is PER SHARD and real: a full pool parks this
         submitter exactly as a single-group deployment would (Pool.submit
@@ -433,13 +483,15 @@ class ShardSet:
         rec = self.recorder
         span = rec.begin("front.submit") if rec.enabled else None
         try:
-            return await self._submit(client_id, raw_request, request_key)
+            return await self._submit(client_id, raw_request, request_key,
+                                      channel)
         finally:
             if span is not None:
                 rec.end(span)
 
     async def _submit(self, client_id, raw_request: bytes,
-                      request_key: Optional[str]) -> int:
+                      request_key: Optional[str],
+                      channel: Optional[str]) -> int:
         fresh = (self.latency.on_submitted(request_key)
                  if request_key is not None else False)
         try:
@@ -452,7 +504,12 @@ class ShardSet:
                     await self._wait_for_flip(tr)
                 finally:
                     tr.parked -= 1
-            sid = self.router.route(client_id, epoch=self._epoch)
+            if channel is None:
+                sid = self.router.route(client_id, epoch=self._epoch)
+            else:
+                sid = self.channels.get(channel)
+                if sid is None:
+                    raise ChannelNotServed(channel, self.channels)
             shard = self.shards.get(sid)
             if shard is None:
                 raise ShardEpochError(
@@ -745,6 +802,13 @@ class ShardSet:
         ShardEpochError here AND to every parked submitter, and leaves
         the set serving the OLD epoch.  Returns the transition summary
         also stored in ``reshard_stats['last']``."""
+        if self.channels:
+            raise ShardEpochError(
+                f"reshard to {new_shards} refused: this set serves the "
+                f"named channels {sorted(self.channels)}, each a chain of "
+                f"its own; a reshard moves ranges of the client space and "
+                f"has no meaning for them"
+            )
         if self._transition is not None:
             raise ShardEpochError(
                 f"reshard to {new_shards} refused: epoch "

@@ -70,11 +70,14 @@ class EnvelopeChecks:
 
     envelopes = None
 
-    def enroll(self, identities, crypto, recorder=None) -> None:
+    def enroll(self, identities, crypto, recorder=None, *,
+               channel=None) -> None:
         """Hold the channel's enrolled client identities (before the
         consensus instance is built: the core looks for the coroutines
         when it is): an EnvelopeVerifier over ``crypto``'s engine and its
-        awaited path into the shared coalescer.  No identities: no-op."""
+        awaited path into the shared coalescer.  ``channel``: the name
+        every envelope then has to carry (``crypto.envelope``).  No
+        identities: no-op."""
         if not identities:
             return
         if crypto is None or not hasattr(crypto, "verify_items_async"):
@@ -86,7 +89,8 @@ class EnvelopeChecks:
             raise ValueError("client envelopes are P-256 signed")
         self.envelopes = EnvelopeVerifier(
             identities, engine=crypto.engine,
-            submit=crypto.verify_items_async, recorder=recorder)
+            submit=crypto.verify_items_async, recorder=recorder,
+            channel=channel)
         self.verify_request_async = self._verify_request_async
         self.verify_proposal_async = self._verify_proposal_async
 

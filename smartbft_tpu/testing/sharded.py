@@ -28,10 +28,12 @@ Crypto modes:
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import Mapping
 from typing import Callable, Optional, Sequence
 
 from ..codec import decode, encode
 from ..config import Configuration
+from ..crypto.envelope import envelope_channel
 from ..crypto.provider import (
     AsyncBatchCoalescer,
     HostVerifyEngine,
@@ -366,9 +368,13 @@ class ShardedCluster:
         slo_spec=None,
         enrolled=None,
     ):
-        """``enrolled``: the enrolled client identities (P-256 public keys)
-        of every channel; with them each replica verifies every client
-        envelope (``crypto="p256"`` only; see ``crypto.envelope``).
+        """``enrolled``: the enrolled client identities (P-256 public
+        keys); with them each replica verifies every client envelope
+        (``crypto="p256"`` only; see ``crypto.envelope``).  A sequence is
+        ONE set held by every shard, the shards hash-routed as ever.  A
+        mapping ``channel name -> identities`` makes the shards NAMED
+        CHANNELS, in the mapping's order, each with its own enrolled set
+        (see :meth:`enroll`).
 
         ``crypto``: "trivial" | "p256" | "ed25519" | "toy" (see module
         docstring; "toy" is the real provider stack over the array-math
@@ -500,7 +506,10 @@ class ShardedCluster:
             # round-trips it); an explicit constructor arg still wins
             reshard_drain_deadline = self.base_config.reshard_drain_deadline
         self._crypto_for = crypto_for
+        #: the one enrolled set every shard holds, or each named
+        #: channel's own
         self._enrolled: Optional[list] = None
+        self._enrolled_on: dict[str, list] = {}
         #: incarnation count per shard id — a retired-then-recreated id is
         #: a NEW consensus group with its own network namespace + WAL dirs
         self._incarnations: dict[int, int] = {s: 1 for s in range(shards)}
@@ -555,17 +564,34 @@ class ShardedCluster:
             self.enroll(enrolled)
 
     def enroll(self, identities) -> None:
-        """Give every replica of every channel the enrolled client
-        identities (before :meth:`start`); shards born of a later reshard
-        get them too."""
-        self._enrolled = list(identities)
+        """Give the replicas their enrolled client identities (before
+        :meth:`start`).
+
+        A sequence of public keys is ONE set, held by every replica of
+        every shard (shards born of a later reshard get it too); the
+        shards stay hash-routed and their envelopes name no channel.
+
+        A mapping ``channel name -> identities`` (as many as there are
+        shards) names the shards, in its order: shard ``k`` is the channel
+        of the ``k``-th name, holds that channel's identities and no
+        other's, and refuses every envelope that does not name it.  The
+        front door then places an envelope by the channel it names
+        (:meth:`submit`), and the set is not resharded."""
+        if isinstance(identities, Mapping):
+            self.set.name_channels(list(identities))
+            self._enrolled_on = {name: list(ids)
+                                 for name, ids in identities.items()}
+        else:
+            self._enrolled = list(identities)
         for sh in self.shard_list:
             self._enroll_shard(sh)
 
     def _enroll_shard(self, shard: "AppShard") -> "AppShard":
-        if self._enrolled:
+        identities = self._enrolled_on.get(shard.channel, self._enrolled)
+        if identities:
             for app in shard.apps:
-                app.enroll(self._enrolled, app.crypto, app.recorder)
+                app.enroll(identities, app.crypto, app.recorder,
+                           channel=shard.channel)
         return shard
 
     def _vc_signal_source(self):
@@ -670,24 +696,45 @@ class ShardedCluster:
 
     async def submit(self, client_id: str, request_id: str,
                      payload: bytes = b"", *,
-                     envelope: Optional[bytes] = None) -> int:
-        """Encode a TestRequest and push it through the routed front door;
+                     envelope: Optional[bytes] = None,
+                     channel: Optional[str] = None) -> int:
+        """Encode a TestRequest and push it through the front door;
         returns the shard it landed on.  The request's committed-stream id
         rides along so the set's CommitLatencyTracker can stamp
         submit→commit latency for it.  ``envelope``: the client's own
         signed bytes for this ``(client_id, request_id)``
-        (``crypto.envelope.sign_envelope``), submitted as they are."""
-        req = envelope if envelope is not None else encode(TestRequest(
-            client_id=client_id, request_id=request_id, payload=payload
-        ))
+        (``crypto.envelope.sign_envelope``), submitted as they are.
+
+        ``channel``: the channel an unsigned request is for.  An
+        envelope's channel is READ FROM THE ENVELOPE where the shards are
+        named channels, so the door and the signed bytes cannot disagree
+        (a ``channel`` that contradicts them is a ``ValueError``); a
+        channel nobody serves raises ``shard.ChannelNotServed``.  A
+        request that names none is placed by its client id."""
+        if envelope is None:
+            req = encode(TestRequest(
+                client_id=client_id, request_id=request_id, payload=payload
+            ))
+        else:
+            req = envelope
+            if self.set.channels:
+                named = envelope_channel(envelope)
+                if channel is not None and channel != named:
+                    raise ValueError(
+                        f"submit(channel={channel!r}) for an envelope that "
+                        f"names {named!r}")
+                channel = named
         return await self.set.submit(
-            client_id, req, request_key=f"{client_id}:{request_id}"
+            client_id, req, request_key=f"{client_id}:{request_id}",
+            channel=channel,
         )
 
     def client_for_shard(self, sid: int, j: int = 0) -> str:
         """A deterministic client id that ROUTES to shard ``sid`` in the
         ACTIVE epoch — lets tests and benches place load evenly while
-        still going through the real router (no bypass).  Memoized per
+        still going through the real router (no bypass).  For hash-routed
+        sets only: a named channel is addressed by its name, whoever the
+        client is, and no client id "routes" to it.  Memoized per
         epoch (an epoch flip re-buckets the client space, so the cache is
         dropped at the first lookup after one): benches call this per
         submit, and re-scanning the id space would dominate the timed

@@ -411,7 +411,7 @@ def test_honest_envelopes_commit_once_and_forged_ones_reach_no_ledger(
             assert ledger_requests(a) == ledger_requests(apps[0])
         door = apps[0].envelopes
         assert door.rejected == {"malformed": 0, "not_enrolled": 2,
-                                 "bad_signature": 8}
+                                 "bad_signature": 8, "wrong_channel": 0}
         for a in apps[1:]:  # followers judged every block's envelopes
             assert a.envelopes.accepted == 24
             assert not any(a.envelopes.rejected.values())
@@ -491,7 +491,8 @@ def test_a_forwarded_forged_envelope_is_dropped_at_the_leader(tmp_path):
                        and all(ledger_requests(a) == [good] for a in apps),
                        scheduler, timeout=600.0)
         assert apps[0].envelopes.rejected == {
-            "malformed": 0, "not_enrolled": 1, "bad_signature": 4}
+            "malformed": 0, "not_enrolled": 1, "bad_signature": 4,
+            "wrong_channel": 0}
         assert leader.pool_occupancy().get("size", 0) == 0
         await stop_all(apps)
 
