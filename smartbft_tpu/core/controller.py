@@ -341,30 +341,39 @@ class Controller(RequestTimeoutHandler):
         control plane's is the embedder's own)."""
         info = self.request_inspector.request_id(request)
         check = self._check_request
-        if check is not None and not (forwarded or verified):
-            rec = self.recorder
-            t_enqueue = rec.now() if rec.enabled else None
-            close_for_await()
-            try:
-                await check(request)
-            finally:
-                if t_enqueue is not None:
-                    # a wait: the front door's enqueue -> this envelope's
-                    # verdict
-                    rec.wait("request.verify", t_enqueue, key=str(info))
+        admit = None
         try:
-            await self.request_pool.submit(request, forwarded=forwarded)
-        except Exception as e:
-            # a shed submit is ROUTINE past the admission knee — throttle
-            # like the forwarded-path warnings (per-request records on
-            # this hot path cost whole seconds per open-loop bench run)
-            self._shed_submits += 1
-            if self._shed_submits == 1 or self._shed_submits % 1000 == 0:
-                self.logger.infof(
-                    "Request %s was not submitted (%d sheds so far), error: %s",
-                    info, self._shed_submits, e,
-                )
-            raise
+            if check is not None and not (forwarded or verified):
+                rec = self.recorder
+                t_enqueue = rec.now() if rec.enabled else None
+                close_for_await()
+                try:
+                    await check(request)
+                finally:
+                    if t_enqueue is not None:
+                        # busy: the resumed submitter, from the verdict
+                        # until the pool has the request or refused it (a
+                        # pool that parks it closes the span there)
+                        admit = rec.begin("req.admit")
+                        # a wait: the front door's enqueue -> this
+                        # envelope's verdict
+                        rec.wait("request.verify", t_enqueue, key=str(info))
+            try:
+                await self.request_pool.submit(request, forwarded=forwarded)
+            except Exception as e:
+                # a shed submit is ROUTINE past the admission knee — throttle
+                # like the forwarded-path warnings (per-request records on
+                # this hot path cost whole seconds per open-loop bench run)
+                self._shed_submits += 1
+                if self._shed_submits == 1 or self._shed_submits % 1000 == 0:
+                    self.logger.infof(
+                        "Request %s was not submitted (%d sheds so far), "
+                        "error: %s", info, self._shed_submits, e,
+                    )
+                raise
+        finally:
+            if admit is not None:
+                rec.end(admit)
         self.logger.debugf("Request %s was submitted", info)
 
     async def handle_request(self, sender: int, req: bytes):

@@ -471,6 +471,7 @@ class ViewChanger:
         self._task = create_logged_task(
             self._run(frozenset(self._prior_tasks)),
             name=f"viewchanger-{self.self_id}", logger=self.logger,
+            busy=(self.recorder, "vc.run"),
         )
 
     def _set_view_metrics(self) -> None:

@@ -13,7 +13,7 @@ smartbft_tpu.obs.report`` renders a recorder dump as a text timeline +
 per-span-type percentile summary.
 """
 
-from .account import assemble_account  # noqa: F401
+from .account import assemble_account, assemble_timeline  # noqa: F401
 from .critpath import (  # noqa: F401
     DECISION_SEGMENTS,
     SEGMENTS,
@@ -53,6 +53,7 @@ __all__ = [
     "TraceRecorder",
     "assemble_account",
     "assemble_critical_path_block",
+    "assemble_timeline",
     "assemble_trace_block",
     "close_for_await",
     "decision_rows",

@@ -16,7 +16,41 @@ cover the same span of time.
 =====================  ====================================================
 ``interval``           ``t0``, ``t1`` (perf_counter), ``wall_s``, ``ticks``
 ``loop``               the loop thread: ``thread``, ``cpu_s`` (``sys_s`` of it
-                       in the kernel), ``busy_self_s``
+                       in the kernel), ``busy_self_s``.  Where the loop
+                       hook covered it: ``turns`` (handles the loop ran)
+                       and ``steps_wall_s`` (wall inside them); ``runs``,
+                       ``runs_wall_s``, ``steps_cpu_s``: count (of those
+                       that ended: a loop that never idles is in ONE), wall
+                       and thread-CPU of its runs of back-to-back handles (a
+                       run from its first handle's start to its last's
+                       end: the handles and the loop's pops between
+                       them); ``lock_wait_s`` = ``runs_wall_s`` -
+                       ``steps_cpu_s`` (inside a run and off the CPU:
+                       behind the interpreter lock, or the OS took the
+                       core); ``between_s`` = ``runs_wall_s`` -
+                       ``steps_wall_s`` (inside a run and between its
+                       handles: the loop's pops, its zero-timeout
+                       ``select`` calls, the hook's own bookkeeping);
+                       ``outside_s`` = ``cpu_s`` - ``steps_cpu_s`` (the
+                       thread's CPU outside any run: the blocking
+                       ``select`` and ``_run_once`` around it)
+``loop_steps``         ``covered`` (False where the loop could not be
+                       hooked, and then nothing else).  ``owners``: the 12
+                       owners of handles with the most self time, each
+                       ``name`` (a task's coroutine ``__qualname__``, a
+                       callback's), ``kind``, ``calls``, ``self_s``;
+                       ``other``: the rest summed; ``intervals`` and
+                       ``busy_s``: count and seconds of the loop's merged
+                       busy intervals inside the interval
+``launch``             the thread(s) that ran verify launches: per kind
+                       (``verify.pack`` / ``verify.place`` /
+                       ``verify.device``) ``dur_s``, ``cpu_s``,
+                       ``off_cpu_s`` = their difference (the kernel, or a
+                       wait for the interpreter lock); ``launches``
+``timeline``           the interval split by two facts (:func:`assemble_timeline`):
+                       ``both_s``, ``loop_only_s``, ``launch_only_s``,
+                       ``neither_s`` (sum ``wall_s``), ``neither_fsync_s``;
+                       only where the loop hook covered the loop
 ``busy``               thread -> kind -> ``calls``, ``self_s``, ``dur_s``,
                        ``cpu_s`` (thread CPU, where the span reads it);
                        kind ``gc`` is the interpreter's collections
@@ -77,11 +111,84 @@ from typing import Optional, Sequence
 
 from .critpath import DECISION_SEGMENTS, decision_rows
 
-__all__ = ["assemble_account"]
+__all__ = ["assemble_account", "assemble_timeline"]
 
 #: wait kinds recorded as such, taken as they are
 _WAIT_KINDS = ("verify.wait", "verify.hold", "verify.window", "wal.persist",
                "request.verify", "proposal.verify")
+
+
+#: busy spans of the thread that runs a verify launch
+LAUNCH_KINDS = ("verify.pack", "verify.place", "verify.device")
+#: owners the ``loop_steps`` block lists by name
+TOP_OWNERS = 12
+
+
+def _union(intervals, t0: float, t1: float) -> list:
+    """``(start, end)`` intervals clipped to ``[t0, t1]`` -> disjoint,
+    sorted."""
+    merged: list = []
+    for a, b in sorted((max(a, t0), min(b, t1)) for a, b in intervals):
+        if b <= a:
+            continue
+        if merged and a <= merged[-1][1]:
+            if b > merged[-1][1]:
+                merged[-1][1] = b
+        else:
+            merged.append([a, b])
+    return merged
+
+
+def _overlap_s(u: list, v: list) -> float:
+    """Seconds in which two disjoint, sorted interval lists both hold."""
+    total, i, j = 0.0, 0, 0
+    while i < len(u) and j < len(v):
+        total += max(0.0, min(u[i][1], v[j][1]) - max(u[i][0], v[j][0]))
+        if u[i][1] < v[j][1]:
+            i += 1
+        else:
+            j += 1
+    return total
+
+
+def assemble_timeline(loop: Sequence, launch: Sequence, fsync: Sequence,
+                      *, t0: float, t1: float) -> dict:
+    """Split ``[t0, t1]`` by two facts at every instant: the loop thread
+    is inside a handle (``loop``) or not, the launch's thread is inside a
+    span of a verify launch (``launch``) or not; ``fsync``: when an fsync
+    wave was out.  Each a list of ``(start, end)`` on one clock, in any
+    order, overlapping or not.  ``launch_only_s``: the loop had nothing to
+    run while a launch was out; ``neither_s`` is the remainder, so the
+    four sum to the wall."""
+    wall = max(t1 - t0, 0.0)
+    on_loop, on_launch = _union(loop, t0, t1), _union(launch, t0, t1)
+    both = _overlap_s(on_loop, on_launch)
+    loop_only = sum(b - a for a, b in on_loop) - both
+    launch_only = sum(b - a for a, b in on_launch) - both
+    waves = _union(fsync, t0, t1)
+    either = _union([*on_loop, *on_launch], t0, t1)
+    return {
+        "both_s": both, "loop_only_s": loop_only,
+        "launch_only_s": launch_only,
+        "neither_s": wall - both - loop_only - launch_only,
+        "neither_fsync_s": sum(b - a for a, b in waves)
+        - _overlap_s(waves, either),
+    }
+
+
+def _loop_steps_block(steps: dict, handles: list) -> dict:
+    """``handles``: the loop's busy intervals, merged and clipped."""
+    ranked = sorted(steps["owners"].items(), key=lambda kv: -kv[1][1])
+    rest = ranked[TOP_OWNERS:]
+    return {
+        "covered": True,
+        "owners": [{"name": name, "kind": kind, "calls": v[0], "self_s": v[1]}
+                   for (kind, name), v in ranked[:TOP_OWNERS]],
+        "other": {"calls": sum(v[0] for _, v in rest),
+                  "self_s": sum(v[1] for _, v in rest)},
+        "intervals": len(handles),
+        "busy_s": sum(b - a for a, b in handles),
+    }
 
 
 def _fold_mesh_launch(mesh: dict, mark: dict) -> None:
@@ -133,9 +240,13 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
                      loop_sys_s: float = 0.0, ticks: int = 0,
                      refused: Optional[dict] = None,
                      collections: Sequence = (), frozen: int = 0,
-                     thresholds: Sequence = ()) -> dict:
+                     thresholds: Sequence = (),
+                     loop_steps: Optional[dict] = None) -> dict:
     """See the module docstring.  ``busy``: thread -> kind -> ``[calls,
     self_s, dur_s, cpu_s]``, the running sums at the off edge;
+    ``loop_steps``: the loop hook's own sums (``loophook.LoopHook.block``;
+    its ``kinds`` are added to the loop thread's busy sums here), None
+    where it declined;
     ``collections``: ``(thread, end, seconds, generation)`` per garbage
     collection while on; ``frozen`` and ``thresholds``: the collector's
     freeze count and thresholds at the off edge."""
@@ -256,15 +367,46 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
         total.append((e.t - t_submit) * 1e3)
     waits["pool.wait"] = pool_wait
     waits["req.total"] = total
+    launch: dict = {"launches": counters["launches"]}
+    for kind in LAUNCH_KINDS:
+        sums = [v for per in busy.values() for k, v in per.items()
+                if k == kind]
+        if sums:
+            dur, cpu = sum(v[2] for v in sums), sum(v[3] for v in sums)
+            launch[kind] = {"dur_s": dur, "cpu_s": cpu,
+                            "off_cpu_s": dur - cpu}
+    loop = {"thread": loop_thread, "cpu_s": loop_cpu_s, "sys_s": loop_sys_s}
+    hooked: dict = {"loop_steps": {"covered": False}}
+    if loop_steps is not None:
+        sums = busy.setdefault(loop_thread, {})
+        for kind, add in loop_steps["kinds"].items():
+            acc = sums.setdefault(kind, [0, 0.0, 0.0, 0.0])
+            for i, v in enumerate(add):
+                acc[i] += v
+        loop.update(
+            turns=loop_steps["turns"], steps_wall_s=loop_steps["wall_s"],
+            runs=loop_steps["runs"], runs_wall_s=loop_steps["runs_wall_s"],
+            steps_cpu_s=loop_steps["cpu_s"],
+            lock_wait_s=loop_steps["runs_wall_s"] - loop_steps["cpu_s"],
+            between_s=loop_steps["runs_wall_s"] - loop_steps["wall_s"],
+            outside_s=loop_cpu_s - loop_steps["cpu_s"])
+        flat = loop_steps["intervals"]
+        handles = _union(zip(flat[::2], flat[1::2]), t0, t1)
+        hooked["loop_steps"] = _loop_steps_block(loop_steps, handles)
+        # spans that straddle an edge are clipped, not dropped
+        hooked["timeline"] = assemble_timeline(
+            handles,
+            [(e.t - e.dur, e.t) for e in events
+             if e.kind in LAUNCH_KINDS and e.self_s >= 0.0],
+            [(e.t - e.dur, e.t) for e in events
+             if e.kind == "wal.fsync" and e.self_s >= 0.0],
+            t0=t0, t1=t1)
+    loop["busy_self_s"] = sum(v[1] for v in busy.get(loop_thread, {}).values())
     return {
         "interval": {"t0": t0, "t1": t1, "wall_s": t1 - t0, "ticks": ticks},
-        "loop": {
-            "thread": loop_thread,
-            "cpu_s": loop_cpu_s,
-            "sys_s": loop_sys_s,
-            "busy_self_s": sum(v[1] for v in
-                               busy.get(loop_thread, {}).values()),
-        },
+        "loop": loop,
+        **hooked,
+        "launch": launch,
         "busy": {th: {k: {"calls": v[0], "self_s": v[1], "dur_s": v[2],
                           "cpu_s": v[3]}
                       for k, v in sorted(kinds.items())}

@@ -41,6 +41,13 @@ folds every switched recorder into one plain dict, :func:`last_summary`:
 the interval, the loop thread's CPU, busy self time and calls by thread
 and kind, counts taken at the same sites, per-decision segment lists and
 wait-span lists.  Nothing here imports JAX unless the process already has.
+
+**The loop hook.**  Between the on and the off edge, and only then,
+:mod:`~smartbft_tpu.obs.loophook` wraps ``asyncio.events.Handle._run``:
+every handle the loop thread runs is the outermost busy span of its
+thread, of kind ``loop.program`` / ``loop.embedder`` / ``loop.callback``
+by its owner, so the loop thread's account has no unnamed remainder but
+what the loop does outside its handles.
 """
 
 from __future__ import annotations
@@ -161,7 +168,8 @@ class _ThreadState:
     (the identifier the spans of one launch share, and the tags of the
     submitters whose items the launch carries)."""
 
-    __slots__ = ("ident", "name", "stack", "launch", "tags", "named", "gc")
+    __slots__ = ("ident", "name", "stack", "launch", "tags", "named", "gc",
+                 "outer")
 
     def __init__(self):
         self.ident = threading.get_ident()
@@ -175,6 +183,9 @@ class _ThreadState:
         self.tags: Sequence = ()
         self.named = False
         self.gc = None  # (start, annotation) while a collection runs
+        #: the loop hook's open handle (``loophook``): what a span with no
+        #: parent on the stack adds its time to, None outside a handle
+        self.outer = None
 
 
 _tls = threading.local()
@@ -409,8 +420,9 @@ class TraceRecorder:
         stack.pop()
         dur = t1 - sp.t0
         self_s = dur - sp.child
-        if stack:
-            stack[-1].child += dur
+        parent = stack[-1] if stack else st.outer
+        if parent is not None:
+            parent.child += dur
         with _accounts_lock:
             acc = _accounts.setdefault(st.name, {}).get(sp.kind)
             if acc is None:
@@ -587,6 +599,9 @@ class _Switch:
     cpu_on = cpu_last = (0.0, 0.0)
     ticks = 0
     loop_thread = ""
+    #: the loop hook while on (``loophook.LoopHook``); None where the
+    #: running loop could not be hooked
+    hook = None
     summary: Optional[dict] = None
 
 
@@ -718,6 +733,8 @@ def poll_profiler() -> None:
             _switch_on()
         sw.t_last, sw.cpu_last = time.perf_counter(), _thread_cpu()
         sw.ticks += 1
+        if sw.hook is not None:
+            sw.hook.tick()
     elif sw.on:
         _switch_off()
 
@@ -743,8 +760,9 @@ def _gc_span(phase: str, info: dict) -> None:
         (t0, ann), st.gc = st.gc, None
         t1 = time.perf_counter()
         ann.__exit__(None, None, None)
-        if st.stack:
-            st.stack[-1].child += t1 - t0
+        parent = st.stack[-1] if st.stack else st.outer
+        if parent is not None:
+            parent.child += t1 - t0
         _gc_log.append((st, t1, t1 - t0, info["generation"]))
 
 
@@ -769,6 +787,9 @@ def _switch_on() -> None:
         if not rec.forced:
             rec._arm(sw.annotate)
     gc.callbacks.append(_gc_span)
+    from .loophook import install
+
+    sw.hook = install(sw.annotate)
     sw.cpu_on = _thread_cpu()
     sw.t_on = time.perf_counter()
 
@@ -776,6 +797,10 @@ def _switch_on() -> None:
 def _switch_off() -> None:
     sw = _switch
     sw.on = False
+    # first, whatever the fold below does: the loop is left as found
+    hook, sw.hook = sw.hook, None
+    if hook is not None:
+        hook.remove()
     if _gc_span in gc.callbacks:
         gc.callbacks.remove(_gc_span)
     recorders = [r for r in _live_recorders()
@@ -794,6 +819,7 @@ def _switch_off() -> None:
         loop_sys_s=sw.cpu_last[1] - sw.cpu_on[1],
         loop_thread=sw.loop_thread, ticks=sw.ticks,
         refused=refused,
+        loop_steps=hook.block() if hook is not None else None,
         collections=[(_name_of(st), t, dur, gen)
                      for st, t, dur, gen in list(_gc_log)],
         frozen=gc.get_freeze_count(), thresholds=gc.get_threshold())

@@ -553,6 +553,51 @@ def test_without_enrolled_identities_the_path_is_the_old_one(tmp_path,
         assert 6 + 3 * decisions <= waits <= 6 + 3 * decisions + votes
 
 
+def test_the_resumed_submitter_is_one_busy_span_a_request(tmp_path):
+    """``req.admit`` (ISSUE 37): the awaited request path after the
+    verdict, from the resume until the pool has the request.  Once an
+    honest envelope, on the loop thread, after that envelope's
+    ``request.verify`` wait (whose record is inside it); a refused
+    envelope's ends as the refusal is raised; and it never spans an await:
+    nothing is refused by the stack discipline, also where the pool is
+    full and parks the submitter."""
+    from smartbft_tpu.obs import recorder as recmod
+
+    async def run():
+        ch = Channel(8, 9)
+        apps, scheduler, _co = make_cluster(tmp_path, ch.enrolled,
+                                            recorders=True)
+        for a in apps:
+            await a.start()
+        leader = apps[0].consensus
+        leader.controller.request_pool._opts.queue_size = 2  # some park
+        sends = [asyncio.ensure_future(
+            leader.submit_request(ch.honest(i, "r0"))) for i in range(6)]
+        with pytest.raises(EnvelopeRejected):
+            await leader.submit_request(ch.forged(6, "f", "bit_of_r"))
+        await wait_for(lambda: all(len(ledger_requests(a)) >= 6
+                                   for a in apps), scheduler, timeout=600.0)
+        await asyncio.gather(*sends)
+        events = apps[0].recorder.events()
+        await stop_all(apps)
+        return events
+
+    before = dict(recmod._refused)
+    events = asyncio.run(run())
+    assert recmod._refused == before
+    admits = [e for e in events if e.kind == "req.admit"]
+    verdicts = [e for e in events if e.kind == "request.verify"]
+    assert len(admits) == len(verdicts) == 7  # six honest, one forged
+    assert any(e.kind == "req.pool" for e in events)  # some did park
+    assert all(e.self_s >= 0.0 and e.dur < 0.05 for e in admits)
+    assert len({e.thread for e in admits}) == 1
+    # each holds its own verdict's record: they end in pairs, no other
+    # request's in between
+    order = [e.kind for e in events
+             if e.kind in ("req.admit", "request.verify")]
+    assert order == ["request.verify", "req.admit"] * 7
+
+
 def test_the_socket_replica_app_refuses_a_forged_envelope(tmp_path):
     """The second embedder, through the same implementation: a socket
     replica given enrolled identities refuses a forged envelope in

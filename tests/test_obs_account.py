@@ -15,7 +15,14 @@
   loop thread's line and on lines of the threads that ran launches and
   fsync waves;
 * every new ``chipbench/layer_metrics`` reader on a hand-built account,
-  and returning None, not raising, on an empty one.
+  and returning None, not raising, on an empty one;
+* the loop hook (ISSUE 37): there only while a session runs, and the
+  original ``Handle._run`` back after it, also when the off edge raises;
+  handles named by owner (``loop.program`` / ``loop.embedder`` /
+  ``loop.callback``, a ``busy=`` task keeping its kind); busy intervals,
+  lock wait and CPU outside handles on synthetic clocks;
+  ``assemble_timeline`` on synthetic lists; the ``launch`` block; the
+  eleven readers on the account of the profiled run.
 """
 
 import asyncio
@@ -650,6 +657,343 @@ def test_xplane_holds_program_spans_on_their_threads_lines(traced_run):
     assert all(p.end_ns <= d.start_ns for p, d in zip(pack, dev))
 
 
+# -- the loop hook (ISSUE 37) ------------------------------------------------------
+
+
+def test_loop_hook_is_there_only_while_the_profiler_is_on(tmp_path,
+                                                          monkeypatch):
+    """``Handle._run`` is wrapped at the on edge and is the ORIGINAL
+    object again after the off edge, also when the off edge raises; a
+    session whose on edge comes outside a running loop hooks nothing."""
+    from asyncio import events
+
+    from smartbft_tpu.obs import account as accmod
+
+    original = events.Handle.__dict__["_run"]
+    seen = []
+
+    async def session():
+        obs.poll_profiler()
+        seen.append(events.Handle.__dict__["_run"])
+        for _ in range(3):  # whole handles between the two ticks
+            await asyncio.sleep(0)
+        obs.poll_profiler()
+
+    _profile(tmp_path / "a", lambda: asyncio.run(session()))
+    assert seen[0] is not original and recmod._switch.hook is not None
+    obs.poll_profiler()
+    assert events.Handle.__dict__["_run"] is original
+    assert recmod._switch.hook is None
+    acc = obs.last_summary()
+    assert acc["loop_steps"]["covered"] and "timeline" in acc
+    assert acc["loop"]["turns"] >= 1
+
+    def boom(*a, **kw):
+        raise RuntimeError("the fold failed")
+
+    monkeypatch.setattr(accmod, "assemble_account", boom)
+    _profile(tmp_path / "b", lambda: asyncio.run(session()))
+    with pytest.raises(RuntimeError):
+        obs.poll_profiler()
+    assert events.Handle.__dict__["_run"] is original
+    monkeypatch.undo()
+
+    # no running loop at the on edge: declined, and the account says so
+    _profile(tmp_path / "c", obs.poll_profiler)
+    obs.poll_profiler()
+    acc = obs.last_summary()
+    assert acc["loop_steps"] == {"covered": False}
+    assert "timeline" not in acc and "turns" not in acc["loop"]
+    assert events.Handle.__dict__["_run"] is original
+
+
+def _spin(seconds: float) -> None:
+    end = time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        pass
+
+
+def test_loop_hook_names_every_handle_by_its_owner(tmp_path):
+    """A program task without ``busy=`` is ``loop.program``; one with it
+    keeps its kind and adds nothing to ``loop.program``; a foreign task's
+    step gives ``loop.embedder`` its SELF time only, the program span
+    opened inside it taken out; a plain callback is ``loop.callback``."""
+    from smartbft_tpu.utils.clock import Scheduler, WallClockDriver
+    from smartbft_tpu.utils.tasks import create_logged_task
+
+    rec = TraceRecorder(node="n1", enabled=False)
+    out = {}
+
+    async def kept():  # a program task with a kind of its own
+        for _ in range(3):
+            await asyncio.sleep(0)
+            _spin(0.001)
+
+    async def foreign():  # the embedder's, calling into the program
+        await asyncio.sleep(0)
+        t0 = time.perf_counter()
+        _spin(0.001)
+        span = rec.begin("deliver")
+        _spin(0.002)
+        out["deliver"] = rec.end(span)
+        out["step_s"] = time.perf_counter() - t0
+
+    def plain_callback():
+        _spin(0.001)
+
+    async def session():
+        driver = WallClockDriver(Scheduler(), tick_interval=0.002)
+        driver.start()  # the program's, no kind: it polls the profiler
+        while not rec.enabled:
+            await asyncio.sleep(0.001)
+        task = create_logged_task(kept(), name="k", busy=(rec, "view.run"))
+        asyncio.get_running_loop().call_soon(plain_callback)
+        await asyncio.gather(task, asyncio.ensure_future(foreign()))
+        await asyncio.sleep(0.01)  # a tick after the last of them
+        await driver.stop()
+
+    _profile(tmp_path, lambda: asyncio.run(session()))
+    obs.poll_profiler()
+    acc = obs.last_summary()
+    busy = acc["busy"][acc["loop"]["thread"]]
+    owners = {o["name"]: o for o in acc["loop_steps"]["owners"]}
+    assert acc["refused"] == {}
+    # the driver: a step a tick, under its coroutine's name
+    assert owners["WallClockDriver._run"]["kind"] == "loop.program"
+    assert owners["WallClockDriver._run"]["calls"] >= 2
+    # ``busy=``: the spans keep the calls, the hook adds the rest of each
+    # step to the SAME kind and nothing to ``loop.program``
+    k = next(n for n in owners if n.endswith("kept"))
+    assert owners[k]["kind"] == "view.run" and owners[k]["calls"] == 4
+    assert busy["view.run"]["calls"] == 4
+    assert busy["view.run"]["self_s"] >= 0.003
+    assert busy["loop.program"]["self_s"] < 0.003
+    # the embedder's step: its own 1 ms, not the 2 ms of ``deliver``
+    f = next(n for n in owners if n.endswith("foreign"))
+    assert owners[f]["kind"] == "loop.embedder"
+    assert busy["deliver"]["self_s"] == pytest.approx(out["deliver"].self_s)
+    assert 0.001 <= owners[f]["self_s"] \
+        <= out["step_s"] - out["deliver"].dur + 0.0005
+    cb = next(n for n in owners if n.endswith("plain_callback"))
+    assert owners[cb]["kind"] == "loop.callback"
+    assert owners[cb]["calls"] == 1 and owners[cb]["self_s"] >= 0.001
+    assert busy["loop.callback"]["calls"] >= 1
+    # the loop thread's CPU closes, and what the spans do not name is small
+    loop = acc["loop"]
+    assert loop["steps_cpu_s"] + loop["outside_s"] == \
+        pytest.approx(loop["cpu_s"])
+    assert loop["busy_self_s"] == pytest.approx(loop["steps_wall_s"],
+                                                rel=0.02)
+    t = acc["timeline"]
+    assert t["both_s"] + t["loop_only_s"] + t["launch_only_s"] \
+        + t["neither_s"] == pytest.approx(acc["interval"]["wall_s"])
+    assert t["launch_only_s"] == t["both_s"] == 0.0
+
+
+class _Annotation:
+    """Stands for ``TraceAnnotation``: counts what was entered and left."""
+
+    entered = left = 0
+
+    def __init__(self, name):
+        assert name == "tpubft.loop.busy"
+
+    def __enter__(self):
+        _Annotation.entered += 1
+
+    def __exit__(self, *exc):
+        _Annotation.left += 1
+
+
+def _hooked_handles(handles, *, loop_cpu_s):
+    """Run ``(gap before, wall, CPU)`` handles through the hook on a pair
+    of synthetic clocks -> the account."""
+    from asyncio import events
+
+    from smartbft_tpu.obs import loophook
+
+    wall, cpu = Clock(100.0), Clock(7.0)
+
+    def work(w, c):
+        wall.t += w
+        cpu.t += c
+
+    async def main():
+        hook = loophook.install(_Annotation, clock=wall, cpu_clock=cpu)
+        try:
+            for gap, w, c in handles:
+                wall.t += gap
+                events.Handle._run(events.Handle(
+                    work, (w, c), asyncio.get_running_loop()))
+            hook.tick()
+            wall.t += 1.0  # after the last tick: dropped
+            events.Handle._run(events.Handle(
+                work, (0.5, 0.5), asyncio.get_running_loop()))
+        finally:
+            hook.remove()
+        return hook.block()
+
+    steps = asyncio.run(main())
+    return assemble_account([], {}, t0=100.0, t1=wall.t - 1.5,
+                            loop_cpu_s=loop_cpu_s, loop_thread="MainThread",
+                            loop_steps=steps)
+
+
+def test_busy_intervals_merge_under_50_us_and_not_over():
+    _Annotation.entered = _Annotation.left = 0
+    acc = _hooked_handles([(0.0, 1e-3, 1e-3), (40e-6, 2e-3, 2e-3),
+                           (60e-6, 1e-3, 1e-3), (49e-6, 1e-3, 1e-3)],
+                          loop_cpu_s=5e-3)
+    steps = acc["loop_steps"]
+    assert acc["loop"]["turns"] == 4  # the one after the last tick is out
+    assert steps["intervals"] == 2
+    assert steps["busy_s"] == pytest.approx(5e-3 + 40e-6 + 49e-6)
+    (work,) = steps["owners"]
+    assert work["kind"] == "loop.callback" and work["calls"] == 4
+    assert work["self_s"] == pytest.approx(5e-3)
+    # nothing waits in the loop's ready queue: an annotation a handle
+    assert _Annotation.entered == _Annotation.left == 5
+
+
+def test_lock_wait_and_outside_on_a_synthetic_pair_of_clocks():
+    acc = _hooked_handles([(0.0, 4e-3, 1e-3), (1e-3, 2e-3, 2e-3)],
+                          loop_cpu_s=3.5e-3)
+    loop = acc["loop"]
+    assert loop["steps_wall_s"] == loop["runs_wall_s"] == pytest.approx(6e-3)
+    assert loop["steps_cpu_s"] == pytest.approx(3e-3) and loop["runs"] == 2
+    # inside a handle and off the CPU; on the CPU and outside any handle
+    assert loop["lock_wait_s"] == pytest.approx(3e-3)
+    assert loop["outside_s"] == pytest.approx(0.5e-3)
+    assert loop["between_s"] == pytest.approx(0.0)  # a handle a run here
+    assert loop["busy_self_s"] == pytest.approx(6e-3)  # the hook's kinds
+
+
+@pytest.mark.parametrize("loop,launch,fsync,want", [
+    # disjoint
+    ([(1.0, 2.0)], [(3.0, 4.0)], [(5.0, 6.0)],
+     dict(both_s=0.0, loop_only_s=1.0, launch_only_s=1.0, neither_s=8.0,
+          neither_fsync_s=1.0)),
+    # nested: the launch inside a handle, the fsync inside the launch
+    ([(1.0, 9.0)], [(2.0, 4.0)], [(2.5, 3.0)],
+     dict(both_s=2.0, loop_only_s=6.0, launch_only_s=0.0, neither_s=2.0,
+          neither_fsync_s=0.0)),
+    # overlapping, out of order, over both edges, two launches that touch
+    ([(6.0, 8.0), (-1.0, 2.0), (1.5, 3.0)], [(2.0, 5.0), (5.0, 7.0),
+                                            (9.5, 12.0)],
+     [(4.0, 9.75)],
+     dict(both_s=2.0, loop_only_s=3.0, launch_only_s=3.5, neither_s=1.5,
+          neither_fsync_s=1.5)),
+    # empty
+    ([], [], [], dict(both_s=0.0, loop_only_s=0.0, launch_only_s=0.0,
+                      neither_s=10.0, neither_fsync_s=0.0)),
+], ids=["disjoint", "nested", "overlapping", "empty"])
+def test_timeline_splits_the_interval_and_sums_to_the_wall(loop, launch,
+                                                           fsync, want):
+    got = obs.assemble_timeline(loop, launch, fsync, t0=0.0, t1=10.0)
+    assert got == pytest.approx(want)
+    assert got["both_s"] + got["loop_only_s"] + got["launch_only_s"] \
+        + got["neither_s"] == 10.0
+
+
+def test_account_has_the_launch_block_and_clips_the_timeline():
+    """``launch``: the three launch kinds over every thread, wall minus
+    thread CPU; the timeline takes launch and fsync spans from the ring,
+    clipped at the interval's edges, not dropped."""
+    busy = {"smartbft-verify-launch": {
+        "verify.pack": [2, 0.5, 0.5, 0.2],
+        "verify.device": [2, 2.0, 2.0, 0.1]},
+        "MainThread": {"deliver": [1, 0.25, 0.25, 0.0]}}
+    events = [
+        SpanEvent(1.5, "verify.pack", dur=0.5, self_s=0.5,
+                  thread="smartbft-verify-launch"),
+        SpanEvent(2.5, "verify.device", dur=1.0, self_s=1.0,
+                  thread="smartbft-verify-launch"),
+        SpanEvent(4.5, "verify.device", dur=1.0, self_s=1.0,
+                  thread="smartbft-verify-launch"),
+        SpanEvent(3.75, "wal.fsync", dur=0.5, self_s=0.5, thread="x"),
+    ]
+    acc = assemble_account(
+        [_Ring(events)], busy, t0=0.0, t1=4.0, loop_cpu_s=1.0,
+        loop_thread="MainThread",
+        loop_steps={"turns": 2, "wall_s": 1.0, "runs": 1, "runs_wall_s": 1.0,
+                    "cpu_s": 0.75, "owners": {}, "kinds": {},
+                    "intervals": [2.0, 3.0]})
+    # the launch that ended after the interval left the sums and is
+    # clipped into the timeline
+    assert acc["launch"] == {
+        "launches": 1,
+        "verify.pack": {"dur_s": 0.5, "cpu_s": 0.2, "off_cpu_s": 0.3},
+        "verify.device": {"dur_s": 1.0, "cpu_s": 0.1, "off_cpu_s": 0.9}}
+    assert acc["timeline"] == pytest.approx(dict(
+        both_s=0.5, loop_only_s=0.5, launch_only_s=1.5, neither_s=1.5,
+        neither_fsync_s=0.25))
+    # without the hook's sums: the launch block alone
+    bare = _account(events, t1=4.0, busy=busy)
+    assert bare["launch"]["launches"] == 1 and "timeline" not in bare
+    assert bare["loop_steps"] == {"covered": False}
+
+
+def test_profiled_run_closes_the_loops_account(traced_run):
+    """A whole run under the profiler (ISSUE 37): every handle the loop
+    ran is named, so the busy self time on the loop thread is the wall
+    inside handles; its CPU closes; the timeline sums to the wall; the
+    request path of a cluster without envelopes has no ``req.admit``."""
+    acc = traced_run["account"]
+    loop, steps = acc["loop"], acc["loop_steps"]
+    assert steps["covered"] and steps["intervals"] > 0
+    assert loop["turns"] > 100 and acc["refused"] == {}
+    assert loop["busy_self_s"] == pytest.approx(loop["steps_wall_s"],
+                                                rel=0.02)
+    assert loop["steps_cpu_s"] + loop["outside_s"] == \
+        pytest.approx(loop["cpu_s"])
+    assert loop["steps_wall_s"] <= loop["runs_wall_s"]
+    assert 0 < loop["runs"] <= loop["turns"]
+    assert -0.02 <= loop["lock_wait_s"] < loop["runs_wall_s"]
+    busy = acc["busy"][loop["thread"]]
+    assert busy["loop.embedder"]["calls"] > 0  # the test's own ``turn``
+    assert busy["loop.callback"]["calls"] > 0
+    assert busy["loop.program"]["calls"] > 0
+    assert "req.admit" not in busy
+    assert busy["vc.run"]["calls"] > 0  # the view changer's steps, named
+    names = {o["name"]: o["kind"] for o in steps["owners"]}
+    assert names.get("View._run", "view.run") == "view.run"
+    assert any(kind == "loop.embedder" for kind in names.values())
+    t = acc["timeline"]
+    assert t["both_s"] + t["loop_only_s"] + t["launch_only_s"] \
+        + t["neither_s"] == pytest.approx(acc["interval"]["wall_s"])
+    assert min(t.values()) >= -1e-9
+    assert t["both_s"] + t["launch_only_s"] > 0  # launches were out
+    launch = acc["launch"]
+    assert launch["launches"] == acc["counters"]["launches"]
+    assert launch["verify.device"]["off_cpu_s"] == pytest.approx(
+        launch["verify.device"]["dur_s"] - launch["verify.device"]["cpu_s"])
+
+
+def test_xplane_holds_the_loops_busy_time_without_holes(traced_run):
+    """``tpubft.loop.busy`` is on the loop thread's line, and every
+    program span of that line lies inside one of them."""
+    from chipbench.trace import load_xplane
+
+    events = [e for e in load_xplane(traced_run["xplane"])
+              if e.name.startswith("tpubft.")]
+    busy = sorted((e for e in events if e.name == "tpubft.loop.busy"),
+                  key=lambda e: e.start_ns)
+    assert busy and len({e.line for e in busy}) == 1
+    line = busy[0].line
+    assert {e.line for e in events if e.name == "tpubft.view.run"} == {line}
+    inside = [e for e in events
+              if e.line == line and e.name != "tpubft.loop.busy"
+              and e.start_ns >= busy[1].start_ns
+              and e.end_ns <= busy[-2].end_ns]
+    assert len(inside) > 50
+    starts = [b.start_ns for b in busy]
+    import bisect
+
+    for e in inside:
+        b = busy[bisect.bisect_right(starts, e.start_ns) - 1]
+        assert b.start_ns <= e.start_ns and e.end_ns <= b.end_ns + 1, e
+
+
 # -- the readers -----------------------------------------------------------------
 
 READERS = {
@@ -780,6 +1124,43 @@ def test_mesh_reader_finds_nothing_without_a_mesh(name):
         "used_by_device": [], "launched_by_device": []})
     for account in (ACCOUNT, no_launch, {}):
         assert read(types.SimpleNamespace(account=account)) is None
+
+
+#: the readers of the loop hook's blocks (PR 37); their values on a
+#: hand-made account are ``chipbench/tests/test_chipbench_loop_readers.py``'s
+LOOP_READERS = (
+    "loop_turns_per_decision", "loop_embedder_pct",
+    "request_path_us_per_req", "loop_lock_wait_pct",
+    "loop_outside_handles_pct", "launch_lock_wait_ms_per_launch",
+    "verify_device_kernel_pct", "tl_both_pct", "tl_launch_only_pct",
+    "tl_loop_only_pct", "tl_neither_pct")
+
+
+@pytest.mark.parametrize("name", LOOP_READERS)
+def test_loop_reader_on_a_traced_runs_account(traced_run, name):
+    """Each reads a number off the account of a real profiled run (with a
+    stand-in for the device trace's kernel seconds), and nothing off the
+    parent's shape of account."""
+    import math
+
+    acc = traced_run["account"]
+    trace = types.SimpleNamespace(busy_s=1e-4)
+    value = _reader(name)(types.SimpleNamespace(account=acc, trace=trace))
+    # (the CPU clock is read outside the wall clock's bracket, so an idle
+    # loop's lock wait may read a hair under zero)
+    assert value is not None and math.isfinite(value)
+    assert value >= (-1.0 if name == "loop_lock_wait_pct" else 0.0)
+    if name.endswith("_pct") and name != "verify_device_kernel_pct":
+        assert value <= 100.0
+    assert _reader(name)(types.SimpleNamespace(account=ACCOUNT,
+                                               trace=trace)) is None
+
+
+def test_the_timeline_readers_sum_to_a_hundred(traced_run):
+    run = types.SimpleNamespace(account=traced_run["account"])
+    assert sum(_reader(f"tl_{part}_pct")(run) for part in
+               ("both", "launch_only", "loop_only", "neither")) == \
+        pytest.approx(100.0)
 
 
 def test_account_folds_mesh_launches_by_device():
