@@ -28,9 +28,11 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import queue
 import random
 import threading
 import time
+import weakref
 from dataclasses import asdict, dataclass, field
 from typing import Optional, Sequence
 
@@ -498,6 +500,9 @@ class VerifyFaultStats:
     probe_attempts: int = 0
     probe_successes: int = 0
     abandoned_late_arrivals: int = 0
+    #: resident launch threads the coalescer started: one for its loop,
+    #: and one more after each abandoned launch (:class:`_LaunchThread`)
+    launch_threads_started: int = 0
 
 
 class HostVerifyEngine:
@@ -844,6 +849,92 @@ def prewarm_verify_engine(engine, scheme=None,
         prewarm(item[:-1] + (next(iter(ring)),), None)
 
 
+def _hand_back(loop, fut: asyncio.Future, call) -> None:
+    """Run ``call()`` on the calling (worker) thread and settle ``fut``
+    on ``loop`` with its result or exception, whenever that is —
+    possibly long after every awaiter gave up; a loop closed meanwhile
+    gets nothing."""
+    try:
+        res = call()
+    except BaseException as exc:  # noqa: BLE001 — ferried to the loop
+        setter, payload = fut.set_exception, exc
+    else:
+        setter, payload = fut.set_result, res
+
+    def resolve() -> None:
+        if not fut.done():
+            setter(payload)
+
+    try:
+        loop.call_soon_threadsafe(resolve)
+    except RuntimeError:
+        pass  # loop closed while the launch was in flight
+
+
+class _Launch:
+    """One live wave's engine call handed to the launch thread.  With
+    the recorder on at the hand-in, ``launch`` is its id and ``t_in`` /
+    ``t_out`` the stamps of ``verify.handin`` (the loop's hand-in -> the
+    call's first line on the thread) and ``verify.handback`` (the call's
+    return -> the awaiting coroutine resumed on the loop)."""
+
+    __slots__ = ("engine", "pending", "fut", "loop", "worker", "launch",
+                 "t_in", "t_out")
+
+    def __init__(self, engine, pending: list, loop, worker):
+        self.engine, self.pending = engine, pending
+        self.loop, self.worker = loop, worker
+        self.fut: asyncio.Future = loop.create_future()
+        self.launch = -1
+        self.t_in: Optional[float] = None
+        self.t_out: Optional[float] = None
+
+
+class _LaunchThread:
+    """The coalescer's resident launch thread: ONE daemon thread, blocked
+    on a queue, runs every live wave's engine call in turn, so a launch
+    pays neither a thread's start nor JAX's first dispatch on a new
+    thread.  It serves one loop and ends once that loop has closed, or
+    after the call it is in when :meth:`retire` (an abandoned launch)
+    queues its end."""
+
+    #: how often an idle thread looks whether its loop has closed
+    IDLE_CHECK_S = 0.5
+
+    def __init__(self, loop, serve):
+        self.loop = loop
+        #: ``_Launch -> None``, raises nothing; held weakly, so that an
+        #: idle thread keeps no coalescer (nor the deployment its
+        #: recorder reaches) alive after the loop has gone
+        self._serve = weakref.WeakMethod(serve)
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()
+        threading.Thread(target=self._run, name="smartbft-verify-launch",
+                         daemon=True).start()
+
+    def put(self, job: _Launch) -> None:
+        self._jobs.put(job)
+
+    def retire(self) -> None:
+        self._jobs.put(None)
+
+    def _run(self) -> None:
+        # named at the OS before its first annotation (a hand-in's), or
+        # the profiler's line of the thread keeps a nameless one
+        name_this_thread()
+        while True:
+            try:
+                job = self._jobs.get(timeout=self.IDLE_CHECK_S)
+            except queue.Empty:
+                if self.loop.is_closed():
+                    return
+                continue
+            serve = self._serve()
+            if job is None or serve is None:
+                return
+            serve(job)
+            serve = job = None  # idle, the thread holds nothing of it
+
+
 class AsyncBatchCoalescer:
     """Merges concurrent verify calls into shared kernel launches.
 
@@ -948,6 +1039,9 @@ class AsyncBatchCoalescer:
         self._breaker_is_open = False
         self._consecutive_failures = 0
         self._probe_task: Optional[asyncio.Task] = None
+        #: the resident thread live waves' engine calls run on (started
+        #: by the first launch that needs it)
+        self._launcher: Optional[_LaunchThread] = None
         #: a known-well-formed item from the last wave, re-verified by the
         #: breaker probe as the device-health canary
         self._canary: Optional[tuple] = None
@@ -1075,6 +1169,7 @@ class AsyncBatchCoalescer:
             "probe_attempts": s.probe_attempts,
             "probe_successes": s.probe_successes,
             "abandoned_late_arrivals": s.abandoned_late_arrivals,
+            "launch_threads_started": s.launch_threads_started,
             # ISSUE 15: view-flip warm transients (eager windowless
             # flushing) and the occupancy holds they bypassed
             "flip_warms": self.flip_warms,
@@ -1406,31 +1501,61 @@ class AsyncBatchCoalescer:
             f"no fallback engine is configured: {last_exc!r}"
         ) from last_exc
 
-    def _spawn_engine_call(self, engine, pending: list) -> asyncio.Future:
-        """Run one engine call on a dedicated DAEMON thread; the returned
-        future resolves with the result/exception whenever the thread
-        finishes — possibly long after every awaiter gave up."""
+    def _hand_in(self, engine, pending: list) -> _Launch:
+        """Queue one engine call for the resident launch thread, which
+        is started on first use, and anew on another loop or after an
+        abandoned launch (:meth:`_abandon`)."""
+        loop = asyncio.get_running_loop()
+        worker = self._launcher
+        if worker is None or worker.loop is not loop:
+            if worker is not None:
+                worker.retire()
+            self.fault_stats.launch_threads_started += 1
+            worker = self._launcher = _LaunchThread(loop, self._serve_launch)
+        job = _Launch(engine, pending, loop, worker)
+        rec = self.recorder
+        if rec.enabled:
+            job.launch, job.t_in = self._launch_seq, rec.now()
+        worker.put(job)
+        return job
+
+    def _serve_launch(self, job: _Launch) -> None:
+        """The launch thread's side of one hand-in."""
+        rec = self.recorder
+        if job.t_in is not None:
+            rec.wait("verify.handin", job.t_in, launch=job.launch,
+                     extra={"threads_started":
+                            self.fault_stats.launch_threads_started})
+
+        def call() -> list[bool]:
+            try:
+                return self._verify_batch(job.pending, job.engine)
+            finally:
+                if job.t_in is not None:
+                    job.t_out = rec.now()
+
+        _hand_back(job.loop, job.fut, call)
+
+    def _abandon(self, job: _Launch) -> None:
+        """A launch past its deadline: its thread is left to finish the
+        call it is in and then ends (an orphan, one per abandoned
+        launch), and the next launch starts a fresh resident thread, so
+        a hung device cannot wedge the flush pipeline."""
+        if self._launcher is job.worker:
+            self._launcher = None
+        job.worker.retire()
+        self._discard_late(job.fut)
+
+    def _spawn_probe(self, item) -> asyncio.Future:
+        """Run the breaker probe's engine call on a DAEMON thread of its
+        own (off the hot path; a parked probe is re-awaited, not
+        re-spawned)."""
         loop = asyncio.get_running_loop()
         fut: asyncio.Future = loop.create_future()
-
-        def resolve(setter, payload) -> None:
-            if not fut.done():
-                setter(payload)
-
-        def run() -> None:
-            try:
-                res = self._verify_batch(pending, engine)
-            except BaseException as exc:  # noqa: BLE001 — ferried to the loop
-                setter, payload = fut.set_exception, exc
-            else:
-                setter, payload = fut.set_result, res
-            try:
-                loop.call_soon_threadsafe(resolve, setter, payload)
-            except RuntimeError:
-                pass  # loop closed while the launch was in flight
-
         threading.Thread(
-            target=run, name="smartbft-verify-launch", daemon=True
+            target=_hand_back, name="smartbft-verify-launch", daemon=True,
+            args=(loop, fut,
+                  lambda: self._verify_batch([item], self.engine)),
         ).start()
         return fut
 
@@ -1450,22 +1575,25 @@ class AsyncBatchCoalescer:
 
     async def _call_engine_with_deadline(self, engine, pending: list,
                                          timeout: Optional[float]):
-        """Run one engine call on a worker thread under the launch
-        deadline.  On expiry the launch is ABANDONED: the (daemon) thread
-        keeps running, its late result is discarded on arrival, and the
-        caller gets LaunchTimeout — a stuck device can no longer wedge the
-        flush pipeline."""
+        """Run one engine call on the resident launch thread under the
+        launch deadline.  On expiry the launch is ABANDONED
+        (:meth:`_abandon`): its late result is discarded on arrival, and
+        the caller gets LaunchTimeout."""
         if timeout is None:
             return await asyncio.to_thread(self._verify_batch, pending, engine)
-        fut = self._spawn_engine_call(engine, pending)
+        job = self._hand_in(engine, pending)
         try:
-            return await asyncio.wait_for(asyncio.shield(fut), timeout)
+            results = await asyncio.wait_for(asyncio.shield(job.fut), timeout)
         except asyncio.TimeoutError:
-            self._discard_late(fut)
+            self._abandon(job)
             raise LaunchTimeout(
                 f"verify launch exceeded its {timeout:.3f}s deadline; "
                 "wave abandoned"
             ) from None
+        if job.t_out is not None:
+            self.recorder.wait("verify.handback", job.t_out,
+                               launch=job.launch)
+        return results
 
     def _note_launch_failure(self, exc: Exception) -> None:
         self._consecutive_failures += 1
@@ -1562,7 +1690,7 @@ class AsyncBatchCoalescer:
                         "verify-plane probe completed late with %r", exc
                     )
                 if fut is None:
-                    fut = self._spawn_engine_call(self.engine, [item])
+                    fut = self._spawn_probe(item)
                 try:
                     await asyncio.wait_for(
                         asyncio.shield(fut), pol.launch_timeout

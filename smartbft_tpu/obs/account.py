@@ -46,7 +46,12 @@ cover the same span of time.
                        (``verify.pack`` / ``verify.place`` /
                        ``verify.device``) ``dur_s``, ``cpu_s``,
                        ``off_cpu_s`` = their difference (the kernel, or a
-                       wait for the interpreter lock); ``launches``
+                       wait for the interpreter lock); ``launches``;
+                       ``threads_started``: the resident launch threads
+                       each coalescer had started by its last
+                       ``verify.handin`` of the interval (their always-on
+                       count, ``VerifyFaultStats.launch_threads_started``),
+                       summed; absent where no hand-in was recorded
 ``timeline``           the interval split by two facts (:func:`assemble_timeline`):
                        ``both_s``, ``loop_only_s``, ``launch_only_s``,
                        ``neither_s`` (sum ``wall_s``), ``neither_fsync_s``;
@@ -95,7 +100,11 @@ cover the same span of time.
 ``waits``              wait kind -> ms per wait (``request.verify``: the
                        front door's enqueue -> verdict of one envelope;
                        ``proposal.verify``: a follower's pre-prepare in
-                       hand -> all its envelopes judged); ``pool.wait`` and
+                       hand -> all its envelopes judged; ``verify.handin``:
+                       a launch handed in on the loop -> its engine call
+                       begun on the launch thread; ``verify.handback``:
+                       that call returned -> its awaiter resumed on the
+                       loop); ``pool.wait`` and
                        ``req.total`` per request delivered by its proposer
 ``durations``          ``wal.fsync`` -> ms per fsync (a busy span on the
                        executor thread that ran the wave)
@@ -115,7 +124,8 @@ __all__ = ["assemble_account", "assemble_timeline"]
 
 #: wait kinds recorded as such, taken as they are
 _WAIT_KINDS = ("verify.wait", "verify.hold", "verify.window", "wal.persist",
-               "request.verify", "proposal.verify")
+               "request.verify", "proposal.verify", "verify.handin",
+               "verify.handback")
 
 
 #: busy spans of the thread that runs a verify launch
@@ -250,7 +260,15 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
     ``collections``: ``(thread, end, seconds, generation)`` per garbage
     collection while on; ``frozen`` and ``thresholds``: the collector's
     freeze count and thresholds at the off edge."""
-    events = [e for r in recorders for e in r.events()]
+    events: list = []
+    started = []  # a coalescer's launch threads, by its last hand-in
+    for r in recorders:
+        mine = r.events()
+        events += mine
+        counts = [e.extra["threads_started"] for e in mine
+                  if e.kind == "verify.handin" and e.t <= t1]
+        if counts:
+            started.append(max(counts))
     busy = {th: {k: list(v) for k, v in kinds.items()}
             for th, kinds in busy.items()}
     inside = []
@@ -368,6 +386,8 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
     waits["pool.wait"] = pool_wait
     waits["req.total"] = total
     launch: dict = {"launches": counters["launches"]}
+    if started:
+        launch["threads_started"] = sum(started)
     for kind in LAUNCH_KINDS:
         sums = [v for per in busy.values() for k, v in per.items()
                 if k == kind]

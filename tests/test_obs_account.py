@@ -933,6 +933,39 @@ def test_account_has_the_launch_block_and_clips_the_timeline():
     assert bare["loop_steps"] == {"covered": False}
 
 
+def test_account_sums_each_coalescers_last_threads_started():
+    """``launch.threads_started`` (PR 38): the count each coalescer's
+    last ``verify.handin`` of the interval carried, summed over the
+    coalescers; a hand-in after the interval does not count, and an
+    account with no hand-in (an earlier program's) has no such key."""
+    def handin(t, n):
+        return SpanEvent(t, "verify.handin", "verify", dur=0.001,
+                         extra={"threads_started": n})
+
+    a = _Ring([handin(1.0, 1), handin(2.0, 2), handin(5.0, 3)])
+    b = _Ring([handin(1.5, 1),
+               SpanEvent(1.6, "verify.handback", "verify", dur=0.002)])
+    acc = assemble_account([a, b], {}, t0=0.0, t1=4.0, loop_cpu_s=1.0,
+                           loop_thread="MainThread")
+    assert acc["launch"]["threads_started"] == 3
+    assert acc["waits"]["verify.handin"] == pytest.approx([1.0, 1.0, 1.0])
+    assert acc["waits"]["verify.handback"] == pytest.approx([2.0])
+    assert "threads_started" not in _account([], t1=4.0)["launch"]
+
+
+def test_profiled_run_hands_every_launch_to_one_resident_thread(traced_run):
+    """In a real profiled cluster the live waves ran on the ONE launch
+    thread its coalescer started before the trace, and both hand-offs
+    were timed."""
+    acc = traced_run["account"]
+    assert acc["launch"]["threads_started"] == 1
+    assert acc["waits"]["verify.handin"] and acc["waits"]["verify.handback"]
+    value = _reader("launch_handoff_ms")(types.SimpleNamespace(account=acc))
+    assert value is not None and 0.0 < value < 1e3
+    assert _reader("launch_handoff_ms")(
+        types.SimpleNamespace(account=ACCOUNT)) is None
+
+
 def test_profiled_run_closes_the_loops_account(traced_run):
     """A whole run under the profiler (ISSUE 37): every handle the loop
     ran is named, so the busy self time on the loop thread is the wall

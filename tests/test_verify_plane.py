@@ -8,7 +8,10 @@ and subsequent submissions still flush.
 """
 
 import asyncio
+import gc
+import threading
 import time
+import weakref
 
 import pytest
 
@@ -342,6 +345,139 @@ def test_trivial_coalesced_crypto_round_trip():
         )
 
     assert asyncio.run(run()) == [b"aux"]
+
+
+# -- the resident launch thread (PR 38) ---------------------------------------
+
+class ThreadNotingEngine(FaultyEngine):
+    """An always-valid FaultyEngine that notes the thread of every call."""
+
+    def __init__(self):
+        super().__init__(always_valid_engine())
+        self.threads: list = []
+
+    def verify(self, items) -> list[bool]:
+        self.threads.append(threading.current_thread())
+        return super().verify(items)
+
+
+def join_all(threads, timeout: float = 5.0) -> None:
+    deadline = time.monotonic() + timeout
+    for t in threads:
+        t.join(max(deadline - time.monotonic(), 0.0))
+    assert not [t for t in threads if t.is_alive()]
+
+
+def test_live_waves_run_on_one_resident_launch_thread():
+    """Twenty waves, one thread: no launch starts a thread of its own,
+    and the thread ends once the loop that owned it has closed."""
+    engine = ThreadNotingEngine()
+    co = AsyncBatchCoalescer(engine, window=0.001,
+                             policy=tight_policy(launch_timeout=5.0))
+
+    async def run():
+        for i in range(20):
+            assert await co.submit([(f"w{i}",)]) == [True]
+
+    asyncio.run(run())
+    assert len(engine.threads) == 20 and len(set(engine.threads)) == 1
+    assert engine.threads[0].name == "smartbft-verify-launch"
+    assert co.fault_stats.launch_threads_started == 1
+    assert co.fault_snapshot()["launch_threads_started"] == 1
+    # idle and not yet gone, the thread holds its coalescer (and what
+    # that reaches: a whole deployment) no longer
+    gone = weakref.ref(co)
+    del co
+    gc.collect()
+    assert gone() is None
+    join_all(engine.threads)
+
+
+def test_abandoned_launch_orphans_its_thread_and_the_next_starts_afresh():
+    """PR 3's contract on the resident thread: a hang past the deadline
+    fails the wave over as ever; the hung thread is an orphan that counts
+    its late arrival and then ends, and the next wave runs on a NEW
+    resident thread."""
+    engine = ThreadNotingEngine()
+    co = AsyncBatchCoalescer(
+        engine, window=0.001,
+        policy=tight_policy(launch_retries=0, breaker_threshold=5),
+        fallback_engine=always_valid_engine(),
+    )
+
+    async def run():
+        engine.hang()
+        assert await asyncio.wait_for(co.submit([("a",)]), 10) == [True]
+        assert co.fault_stats.launch_timeouts == 1
+        assert co.fault_stats.host_fallback_batches == 1
+        assert not co.breaker_open
+        (orphan,) = engine.threads
+        assert orphan.is_alive()
+        engine.heal()
+        await wait_until(lambda: co.fault_stats.abandoned_late_arrivals == 1)
+        await wait_until(lambda: not orphan.is_alive())
+        assert await co.submit([("b",)]) == [True]
+        assert engine.threads[-1] is not orphan
+        assert co.fault_stats.launch_threads_started == 2
+        assert co.fault_stats.abandoned_late_arrivals == 1
+
+    try:
+        asyncio.run(run())
+    finally:
+        engine.heal()
+    join_all(engine.threads)
+
+
+def test_no_launch_thread_outlives_its_loop_and_its_orphans_call():
+    """The loop closes while an abandoned launch is still parked in the
+    device: once that call returns, nothing of the plane is left
+    running."""
+    engine = ThreadNotingEngine()
+    co = AsyncBatchCoalescer(
+        engine, window=0.001,
+        policy=tight_policy(launch_retries=0, breaker_threshold=5),
+        fallback_engine=always_valid_engine(),
+    )
+
+    async def run():
+        assert await co.submit([("a",)]) == [True]
+        engine.hang()
+        assert await asyncio.wait_for(co.submit([("b",)]), 10) == [True]
+
+    try:
+        asyncio.run(run())
+        assert engine.threads[-1].is_alive()  # parked past its loop
+    finally:
+        engine.heal()
+    assert len(set(engine.threads)) == 1
+    join_all(engine.threads)
+
+
+def test_handoff_waits_carry_the_launch_only_while_the_recorder_is_on():
+    from smartbft_tpu.obs.recorder import TraceRecorder
+
+    co = AsyncBatchCoalescer(always_valid_engine(), window=0.001,
+                             policy=tight_policy(launch_timeout=5.0))
+
+    async def waves(tag):
+        for i in range(3):
+            assert await co.submit([(f"{tag}{i}",)]) == [True]
+
+    asyncio.run(waves("off"))
+    assert co.recorder.recorded == 0
+    rec = TraceRecorder(node="verify")
+    co.attach_recorder(rec)
+    asyncio.run(waves("on"))  # a new loop: a new resident thread
+    by_kind = {}
+    for e in rec.events():
+        by_kind.setdefault(e.kind, []).append(e)
+    launches = [e.launch for e in by_kind["verify.launch"]]
+    assert launches == [4, 5, 6]
+    for kind in ("verify.handin", "verify.handback"):
+        assert [e.launch for e in by_kind[kind]] == launches
+        assert all(e.dur >= 0.0 for e in by_kind[kind])
+    assert [e.extra for e in by_kind["verify.handin"]] == \
+        [{"threads_started": 2}] * 3
 
 
 # -- tier-1-speed bench row pin (satellite: CI/tooling) -----------------------
