@@ -1,12 +1,16 @@
 """Signed client envelopes: the request a Fabric-style channel orders.
 
 An envelope is the embedder's request (client id, request id, payload in
-the canonical codec) followed by a fixed trailer: the creator's P-256
-public point (``X || Y``, 64 bytes big-endian, as the certificate in a
-Fabric envelope would carry it) and a 64-byte ``r || s`` ECDSA signature
-over everything before the trailer::
+the canonical codec) followed by a trailer: the creator's public key, as
+the certificate in a Fabric envelope would carry it, and a 64-byte
+signature over everything before the trailer, each after its length.
+The channel's scheme fixes both: a P-256 creator is its point ``X || Y``
+(64 bytes big-endian) and the signature ECDSA's ``r || s``; an Ed25519
+creator is its 32-byte key and the signature RFC 8032's ``R || S``
+(PureEdDSA over the signed bytes)::
 
     <client id> <request id> <payload>  u32(64) <creator>  u32(64) <r || s>
+    <client id> <request id> <payload>  u32(32) <creator>  u32(64) <R || S>
     |------------ signed ------------|  |------------- trailer ----------|
 
 An envelope may NAME the channel it is for: its payload then starts with
@@ -38,18 +42,19 @@ from typing import Awaitable, Callable, Iterable, Optional, Sequence
 
 from ..codec import encode, wiremsg
 from ..obs.recorder import standby
-from . import p256
+from . import ed25519, p256
 
 __all__ = ["CHANNEL_MAGIC", "EnvelopeRejected", "EnvelopeVerifier", "TRAILER",
            "channel_header", "creator_bytes", "envelope_channel",
            "sign_envelope", "split_envelope"]
 
-_KEY = 64  # X || Y
-_SIG = 64  # r || s
+#: a creator's length in an envelope, by the channel's scheme
+_KEY = {p256: 64, ed25519: 32}
+_SIG = 64  # r || s, R || S
 _LEN = struct.Struct(">I")
-#: bytes after the signed part: two length-prefixed 64-byte fields
-TRAILER = 2 * _LEN.size + _KEY + _SIG
-_KEY_PREFIX = _LEN.pack(_KEY)
+#: bytes after the signed part of a P-256 envelope: two length-prefixed
+#: 64-byte fields
+TRAILER = 2 * _LEN.size + _KEY[p256] + _SIG
 _SIG_PREFIX = _LEN.pack(_SIG)
 
 #: a payload that starts with these bytes names the envelope's channel:
@@ -83,9 +88,21 @@ class EnvelopeRejected(ValueError):
         self.cause = cause
 
 
-def creator_bytes(pub) -> bytes:
-    """A P-256 public point as the 64 bytes an envelope carries."""
-    return pub[0].to_bytes(32, "big") + pub[1].to_bytes(32, "big")
+def _key_len(scheme) -> int:
+    try:
+        return _KEY[scheme]
+    except KeyError:
+        raise ValueError(f"no client envelopes for scheme "
+                         f"{getattr(scheme, '__name__', scheme)}") from None
+
+
+def creator_bytes(pub, scheme=p256) -> bytes:
+    """A public key as the bytes an envelope carries: a P-256 point's 64,
+    an Ed25519 key's own 32."""
+    if scheme is p256:
+        return pub[0].to_bytes(32, "big") + pub[1].to_bytes(32, "big")
+    _key_len(scheme)
+    return bytes(pub)
 
 
 def channel_header(channel: str) -> bytes:
@@ -118,10 +135,11 @@ def envelope_channel(raw: bytes):
                                "short") from None
 
 
-def sign_envelope(private: int, public, client_id: str, request_id: str,
-                  payload: bytes = b"", *, channel=None) -> bytes:
-    """Build and sign one envelope with the native signer
-    (``p256.sign_raw``).  ``channel``: the channel it names, in a header
+def sign_envelope(private, public, client_id: str, request_id: str,
+                  payload: bytes = b"", *, channel=None,
+                  scheme=p256) -> bytes:
+    """Build and sign one envelope with the scheme's native signer
+    (``sign_raw``).  ``channel``: the channel it names, in a header
     before ``payload`` and under the signature; None names none, and the
     bytes are the ones an envelope always had."""
     if channel is not None:
@@ -131,18 +149,21 @@ def sign_envelope(private: int, public, client_id: str, request_id: str,
                          "magic: name the channel with channel=")
     signed = encode(_Signed(client_id=client_id, request_id=request_id,
                             payload=payload))
-    return b"".join((signed, _KEY_PREFIX, creator_bytes(public),
-                     _SIG_PREFIX, p256.sign_raw(private, signed)))
+    creator = creator_bytes(public, scheme)
+    return b"".join((signed, _LEN.pack(len(creator)), creator,
+                     _SIG_PREFIX, scheme.sign_raw(private, signed)))
 
 
-def split_envelope(raw: bytes) -> tuple[bytes, bytes, bytes]:
+def split_envelope(raw: bytes, scheme=p256) -> tuple[bytes, bytes, bytes]:
     """-> (signed bytes, creator, signature); raises
-    ``EnvelopeRejected("malformed")`` unless the trailer is there."""
-    cut = len(raw) - TRAILER
-    if (cut < 0 or raw[cut:cut + 4] != _KEY_PREFIX
-            or raw[cut + 4 + _KEY:cut + 8 + _KEY] != _SIG_PREFIX):
+    ``EnvelopeRejected("malformed")`` unless the scheme's trailer is
+    there."""
+    key = _key_len(scheme)
+    cut = len(raw) - (2 * _LEN.size + key + _SIG)
+    if (cut < 0 or raw[cut:cut + 4] != _LEN.pack(key)
+            or raw[cut + 4 + key:cut + 8 + key] != _SIG_PREFIX):
         raise EnvelopeRejected("malformed", "no creator / signature trailer")
-    return raw[:cut], raw[cut + 4:cut + 4 + _KEY], raw[cut + 8 + _KEY:]
+    return raw[:cut], raw[cut + 4:cut + 4 + key], raw[cut + 8 + key:]
 
 
 class EnvelopeVerifier:
@@ -154,18 +175,24 @@ class EnvelopeVerifier:
     shared coalescer (``CryptoProvider.verify_items_async``), behind the
     two coroutines the protocol core awaits.  Client keys are handed to
     the engine as they come, never registered with it: on a TPU they ride
-    the arbitrary-key kernel (``JaxVerifyEngine.pin_ring``)."""
-
-    scheme = p256
+    the arbitrary-key kernel (``JaxVerifyEngine.pin_ring``).  ``scheme``:
+    the channel's, P-256 or Ed25519; an Ed25519 key is decoded here, once
+    (``ed25519.PublicKey``), and its items carry the point to the
+    engine."""
 
     def __init__(self, enrolled: Iterable, *, engine,
                  submit: Optional[Callable[[list], Awaitable[list]]] = None,
-                 recorder=None, channel: Optional[str] = None):
+                 recorder=None, channel: Optional[str] = None,
+                 scheme=p256):
         #: the channel these replicas order for: every envelope has to
         #: name it.  None: an unnamed channel, whose envelopes' payloads
         #: are not looked into
         self.channel = channel
-        self._pub_of = {creator_bytes(pub): pub for pub in enrolled}
+        _key_len(scheme)
+        self.scheme = scheme
+        hold = ed25519.PublicKey if scheme is ed25519 else (lambda pub: pub)
+        self._pub_of = {creator_bytes(pub, scheme): hold(pub)
+                        for pub in enrolled}
         if not self._pub_of:
             raise ValueError("an EnvelopeVerifier needs enrolled identities")
         self.engine = engine
@@ -181,7 +208,7 @@ class EnvelopeVerifier:
     def body(self, raw: bytes) -> bytes:
         """The signed part (what the embedder decodes as its request)."""
         try:
-            return split_envelope(raw)[0]
+            return split_envelope(raw, self.scheme)[0]
         except EnvelopeRejected as e:
             self._note(e.cause)
             raise
@@ -190,7 +217,7 @@ class EnvelopeVerifier:
         """The verify item of one envelope, or ``EnvelopeRejected``
         (``malformed`` / ``wrong_channel`` / ``not_enrolled``), counted."""
         try:
-            signed, creator, signature = split_envelope(raw)
+            signed, creator, signature = split_envelope(raw, self.scheme)
             if self.channel is not None:
                 named = envelope_channel(signed)
                 if named != self.channel:
@@ -204,7 +231,7 @@ class EnvelopeVerifier:
         except EnvelopeRejected as e:
             self._note(e.cause)
             raise
-        return p256.make_item(signed, signature, pub)
+        return self.scheme.make_item(signed, signature, pub)
 
     def items(self, raws: Sequence[bytes]) -> list:
         rec = self.recorder

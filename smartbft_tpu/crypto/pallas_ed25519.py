@@ -1,4 +1,6 @@
-"""Static-key comb-table Pallas kernel for Ed25519 verification.
+"""Ed25519 verification as Pallas kernels: a static-key comb-table kernel
+for the orderers' ring (:func:`eddsa_verify_comb`) and an arbitrary-key
+kernel for every other key (:func:`ed25519_verify`, section at the end).
 
 The twisted-Edwards analogue of :mod:`pallas_comb` — replacing the same
 reference hot path (one goroutine per commit-signature verify,
@@ -22,6 +24,17 @@ BASELINE.md configs[3].  The cofactorless verification equation
 
 Host-side marshalling (SHA-512, point decompression, the s < L range
 check) mirrors the existing XLA kernel path (:mod:`ed25519`).
+
+The arbitrary-key kernel checks the same cofactorless equation for keys
+that have no table (a channel's client identities: thousands).  One
+accumulator walks h's 4-bit windows over a per-lane table of j·(-A) built
+in the kernel (-A decoded on the host once per key), and during the last
+eight windows each doubling also adds B's comb entry for S (the same
+one-hot MXU selects).  R is never decoded: the kernel encodes its result
+(one inversion by exponentiation, then y and x's sign bit) and compares
+that with R's 32 bytes, as OpenSSL does, so a non-canonical or off-curve
+R fails the comparison.  The host hashes, and checks lengths and
+``S < L`` on whole arrays (:func:`prep_inputs`).
 """
 
 from __future__ import annotations
@@ -50,8 +63,8 @@ from .pallas_comb import (
     _comb_digits,
     _maybe_unpack,
 )
-from .pallas_ecdsa import LIMB_BITS, NL, _ccol, _eq, _Fld, _grp, _grp1, \
-    _is_zero, _limbs, _select, _sub_borrow
+from .pallas_ecdsa import LIMB_BITS, NL, _ccol, _digits_w, _eq, _Fld, _grp, \
+    _grp1, _is_zero, _limbs, _select, _sub_borrow
 
 R_MONT = 1 << (LIMB_BITS * NL)
 
@@ -144,9 +157,10 @@ def _ed_add(fp, d2, p, q):
     return jnp.stack([x3, y3, z3, t3], axis=-3)
 
 
-def _ed_dbl(fp, p):
+def _ed_dbl(fp, p, with_t: bool = True):
     """dbl-2008-hwcd with both halves negated (a = -1); mirrors
-    ed.point_double.  T input unused."""
+    ed.point_double.  T input unused.  ``with_t=False``: T out is left
+    zero, a multiplication saved, for a doubling only a doubling follows."""
     x, y, z = p[..., 0, :, :], p[..., 1, :, :], p[..., 2, :, :]
     xy = fp.add(x, y)
     a, b, zz, s = _grp1(fp.sqr, [x, y, z, xy])
@@ -154,6 +168,9 @@ def _ed_dbl(fp, p):
     g, e1 = _grp(fp.sub, [(b, a), (s, a)])
     e = fp.sub(e1, b)
     ff = fp.sub(c, g)
+    if not with_t:
+        x3, y3, z3 = _grp(fp.mul, [(e, ff), (g, h), (ff, g)])
+        return jnp.stack([x3, y3, z3, jnp.zeros_like(x3)], axis=-3)
     x3, y3, z3, t3 = _grp(fp.mul, [(e, ff), (g, h), (ff, g), (e, h)])
     return jnp.stack([x3, y3, z3, t3], axis=-3)
 
@@ -276,6 +293,223 @@ def eddsa_verify_comb(s, h, rx, ry, ok, kidx, btab, qtab, tile: int = 128,
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(*args, ok, kidx, btab, qtab)
     return out[0, :bsz]
+
+
+# ---------------------------------------------------------------------------
+# arbitrary-key kernel: any key, one per lane
+# ---------------------------------------------------------------------------
+
+#: h's window: WIN-bit digits, MSB first, over a per-lane table of the
+#: 2^WIN multiples of -A built in the kernel (h < L < 2^253)
+WIN = 4
+NWIN = 256 // WIN
+#: the last NTAIL windows' doublings are the STRIDE doublings of B's comb
+NTAIL = STRIDE // WIN
+#: lanes of one grid step.  As P-256's arbitrary-key kernel: the batch
+#: fills the VPU's 128 lanes, and at 128 the live set (the 16-entry
+#: table of -A, 512 KiB, and the 7-wide stacked additions that build it)
+#: stays well inside the 16 MiB of scoped VMEM
+TILE = 128
+
+
+def _sqr_n(fp, x, n: int):
+    if n <= 2:
+        for _ in range(n):
+            x = fp.sqr(x)
+        return x
+    return lax.fori_loop(0, n, lambda _, v: fp.sqr(v), x)
+
+
+def _inv_p(fp, z):
+    """1/z = z^(p-2) in the Montgomery domain: ref10's addition chain,
+    254 squarings and 11 multiplications."""
+    z2 = fp.sqr(z)
+    z9 = fp.mul(_sqr_n(fp, z2, 2), z)
+    z11 = fp.mul(z9, z2)
+    z5 = fp.mul(fp.sqr(z11), z9)               # z^(2^5 - 1)
+    z10 = fp.mul(_sqr_n(fp, z5, 5), z5)
+    z20 = fp.mul(_sqr_n(fp, z10, 10), z10)
+    z40 = fp.mul(_sqr_n(fp, z20, 20), z20)
+    z50 = fp.mul(_sqr_n(fp, z40, 10), z10)
+    z100 = fp.mul(_sqr_n(fp, z50, 50), z50)
+    z200 = fp.mul(_sqr_n(fp, z100, 100), z100)
+    z250 = fp.mul(_sqr_n(fp, z200, 50), z50)
+    return fp.mul(_sqr_n(fp, z250, 5), z11)    # z^(2^255 - 21)
+
+
+def _neg_a_table(fp, d2, ident, na):
+    """(2^WIN, 4, NL, B): j·(-A) for j < 16, in four stacked levels."""
+    two = _ed_dbl(fp, na)
+    l2 = _ed_add(fp, d2, jnp.stack([two, two]), jnp.stack([na, two]))
+    three, four = l2[0], l2[1]
+    l3 = _ed_add(fp, d2, jnp.stack([four] * 4),
+                 jnp.stack([na, two, three, four]))
+    low = [na, two, three, four, l3[0], l3[1], l3[2]]
+    eight = l3[3]
+    l4 = _ed_add(fp, d2, jnp.stack([eight] * 7), jnp.stack(low))
+    return jnp.stack([ident, *low, eight, *(l4[i] for i in range(7))])
+
+
+def _verify_kernel(s_ref, h_ref, r_ref, ax_ref, ay_ref, ok_ref, btab_ref,
+                   out_ref, idx_scratch):
+    """[S]B + [h](-A), encoded, against R's 32 bytes.  One shared
+    accumulator: NWIN windows of h (WIN doublings, one table add each);
+    in the last NTAIL, every doubling also adds B's comb entry for s."""
+    s, h, r = s_ref[:], h_ref[:], r_ref[:]
+    nb = s.shape[-1]
+    fp = _Fld(_P_ED, _P_NPRIME_ED, nb)
+    one_p = _ccol(_P_ONE_ED, nb)
+    p_r2 = _ccol(_P_R2_ED, nb)
+    d2 = _ccol(_D2_MONT_ED, nb)
+    zero = jnp.zeros((NL, nb), jnp.uint32)
+    ident = jnp.stack([zero, one_p, one_p, zero], axis=-3)
+
+    # rows [0, STRIDE): B's comb columns of s; then h's windows
+    for k, v in enumerate(_comb_digits(s, nb)):
+        idx_scratch[k, :] = v
+    for k, v in enumerate(_digits_w(h, NWIN, WIN)):
+        idx_scratch[STRIDE + k, :] = v.astype(jnp.int32)
+
+    # -A, decoded and negated on the host once per key
+    xm, ym = _grp(fp.mul, [(ax_ref[:], p_r2), (ay_ref[:], p_r2)])
+    na = jnp.stack([xm, ym, one_p, fp.mul(xm, ym)], axis=-3)
+    table = _neg_a_table(fp, d2, ident, na)
+
+    def window(i, acc):
+        d = idx_scratch[pl.ds(STRIDE + i, 1), :][0]
+        sel = jnp.zeros((4, NL, nb), jnp.uint32)
+        for k in range(1 << WIN):  # masked accumulation: no per-lane gather
+            sel = sel + table[k] * (d == k).astype(jnp.uint32)[None, None, :]
+        return _ed_add(fp, d2, acc, sel)
+
+    def head(i, acc):
+        acc = lax.fori_loop(0, WIN - 1,
+                            lambda _, a: _ed_dbl(fp, a, with_t=False), acc)
+        return window(i, _ed_dbl(fp, acc))
+
+    btab = btab_ref[:]
+    iota_t = lax.broadcasted_iota(jnp.int32, (TSIZE, nb), 0)
+
+    def comb_step(k, acc):
+        acc = _ed_dbl(fp, acc)
+        sd = idx_scratch[pl.ds(k, 1), :][0]
+        oh = (iota_t == sd[None, :]).astype(jnp.bfloat16)
+        sel = jnp.dot(btab, oh, preferred_element_type=jnp.float32)
+        return _ed_add(fp, d2, acc, _sel_ed(sel, one_p))
+
+    def tail(j, acc):
+        acc = lax.fori_loop(WIN * j, WIN * (j + 1), comb_step, acc)
+        return window(NWIN - NTAIL + j, acc)
+
+    acc = lax.fori_loop(0, NWIN - NTAIL, head, ident)
+    acc = lax.fori_loop(0, NTAIL, tail, acc)
+
+    # encode (RFC 8032 5.1.2): y = Y/Z fully reduced, x's low bit on top;
+    # R's bytes must be that encoding (a y >= p in R never is)
+    z = acc[2]
+    zi = _inv_p(fp, z)
+    xa, ya = _grp(fp.mul, [(acc[0], zi), (acc[1], zi)])
+    one_raw = _ccol(_limbs(1), nb)
+    xs, ys = _grp(fp.mul, [(xa, one_raw), (ya, one_raw)])
+    top = r[NL - 1]
+    r_y = jnp.concatenate([r[:NL - 1], (top & jnp.uint32(0x7FFF))[None]])
+    same_sign = jnp.uint32(1) - ((xs[0] & jnp.uint32(1)) ^ (top >> 15))
+    # Z = 0 never comes of valid inputs; a padding lane's (0, 0) "point"
+    # could drive it there, and 0/0 encodes as y = 0
+    not_zero = jnp.uint32(1) - _is_zero(z)
+    out_ref[:] = (_eq(ys, r_y) * same_sign * not_zero
+                  * ok_ref[0, :])[None, :]
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def ed25519_verify(s, h, r, ax, ay, ok, tile: int = TILE,
+                   interpret: bool = False):
+    """Batched Ed25519 verify for arbitrary keys, one fused Pallas kernel.
+
+    ``s``, ``h``, ``r``, ``ax``, ``ay``: (B, 16) uint32 little-endian
+    16-bit limbs of S, h = SHA-512(R || A || M) mod L, R's 32 bytes as they
+    are, and -A's affine coordinates; ``ok``: (B,) uint32 host checks
+    (:func:`prep_inputs`).  Returns the (B,) uint32 validity mask of the
+    cofactorless check [S]B + [h](-A) == R; padded lanes (ok = 0) fail."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    if tile % 128 and not interpret:
+        raise ValueError(f"tile must be a multiple of 128 lanes, got {tile}")
+    bsz = s.shape[0]
+    pad = (-bsz) % tile
+    if pad:
+        s, h, r, ax, ay = (jnp.pad(jnp.asarray(a), ((0, pad), (0, 0)))
+                           for a in (s, h, r, ax, ay))
+        ok = jnp.pad(jnp.asarray(ok), (0, pad))
+    total = s.shape[0]
+    args = [jnp.transpose(jnp.asarray(a)).astype(jnp.uint32)
+            for a in (s, h, r, ax, ay)]
+    ok = jnp.asarray(ok, jnp.uint32).reshape(1, total)
+    btab = jnp.asarray(b_table(), jnp.bfloat16)
+
+    spec = pl.BlockSpec((NL, tile), lambda i: (0, i))
+    lane_spec = pl.BlockSpec((1, tile), lambda i: (0, i))
+    out = pl.pallas_call(
+        _verify_kernel,
+        out_shape=jax.ShapeDtypeStruct((1, total), jnp.uint32),
+        grid=(total // tile,),
+        in_specs=[spec] * 5 + [lane_spec,
+                               pl.BlockSpec((ROWS, TSIZE), lambda i: (0, 0))],
+        out_specs=lane_spec,
+        scratch_shapes=[pltpu.VMEM((STRIDE + NWIN, tile), jnp.int32)],
+        interpret=interpret,
+    )(*args, ok, btab)
+    return out[0, :bsz]
+
+
+_L_WORDS = tuple((L >> (64 * i)) & ((1 << 64) - 1) for i in range(4))
+_NO_SIG = bytes(64)
+_NO_KEY = np.zeros(2 * NL, np.uint32)
+
+
+#: a key handed in as plain bytes (a probe, a direct caller), decoded once
+_held = functools.lru_cache(maxsize=1024)(ed.PublicKey)
+
+
+def key_limbs(pub):
+    """-A of a public key as (32,) uint32 limbs, x then y, or None where
+    it does not decode: what an :class:`ed25519.PublicKey` carries from
+    its enrollment, else decoded here (memoised)."""
+    if not isinstance(pub, ed.PublicKey):
+        pub = _held(bytes(pub))
+    return pub.neg_limbs
+
+
+def prep_inputs(items) -> tuple:
+    """``(msg, sig, pub)`` items -> ((s, h, r, ax, ay), ok, refused): the
+    arbitrary-key kernel's inputs and the lanes the host refused, by
+    cause.  Per lane only SHA-512(R || A || M) mod L is Python; the
+    lengths and s < L (RFC 8032 5.1.7) are checked on whole arrays, R is
+    left to the kernel, A's point is the key's own."""
+    n = len(items)
+    keys = [key_limbs(pub) for _, _, pub in items]
+    well = np.fromiter((len(sig) == 64 and key is not None
+                        for (_, sig, _), key in zip(items, keys)), bool, n)
+    raw = np.frombuffer(b"".join(sig if w else _NO_SIG for (_, sig, _), w
+                                 in zip(items, well)), np.uint8).reshape(n, 64)
+    words = raw[:, 32:].view("<u8")
+    below, level = np.zeros(n, bool), np.ones(n, bool)
+    for i in (3, 2, 1, 0):  # s < L, from the top word down
+        below |= level & (words[:, i] < _L_WORDS[i])
+        level &= words[:, i] == _L_WORDS[i]
+    ok = well & below
+    digests = b"".join(
+        (int.from_bytes(hashlib.sha512(sig[:32] + pub + msg).digest(),
+                        "little") % L).to_bytes(32, "little")
+        if good else bytes(32)
+        for (msg, sig, pub), good in zip(items, ok))
+    h = np.frombuffer(digests, "<u2").reshape(n, NL).astype(np.uint32)
+    limbs = raw.view("<u2").astype(np.uint32)
+    a = np.stack([_NO_KEY if key is None else key for key in keys])
+    refused = {"s_not_reduced": int(np.count_nonzero(well & ~below)),
+               "malformed": int(n - np.count_nonzero(well))}
+    return ((limbs[:, NL:], h, limbs[:, :NL], a[:, :NL], a[:, NL:]),
+            ok.astype(np.uint32), refused)
 
 
 # ---------------------------------------------------------------------------

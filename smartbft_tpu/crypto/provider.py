@@ -93,6 +93,10 @@ class Keyring:
 #: what can serve a verify launch: the static-key comb Pallas kernel, the
 #: generic (arbitrary-key) Pallas kernel, the XLA kernel, or host code
 KERNELS = ("comb", "pallas", "xla", "host")
+#: why the host refused a lane before any kernel saw it: a scalar that is
+#: not reduced (S >= L, RFC 8032 5.1.7), or a signature / key that is not
+#: of its length or does not decode
+HOST_REFUSALS = ("s_not_reduced", "malformed")
 
 
 @dataclass
@@ -119,9 +123,18 @@ class VerifyStats:
         default_factory=lambda: dict.fromkeys(KERNELS, 0))
     used_by_kernel: dict = field(
         default_factory=lambda: dict.fromkeys(KERNELS, 0))
+    #: lanes the host refused before the device saw them, by cause
+    #: (:data:`HOST_REFUSALS`): a kernel whose marshalling checks lengths
+    #: and ranges on whole arrays (Ed25519's arbitrary-key kernel) counts
+    #: them here; those lanes launch as padding and verify False
+    host_refused: dict = field(
+        default_factory=lambda: dict.fromkeys(HOST_REFUSALS, 0))
 
     def record(self, n_sigs: int, n_slots: int, seconds: float,
-               kernel: str = "host") -> None:
+               kernel: str = "host", refused: Optional[dict] = None) -> None:
+        if refused:
+            for cause, n in refused.items():
+                self.host_refused[cause] += n
         self.launches += 1
         self.launches_by_kernel[kernel] += 1
         self.lanes_by_kernel[kernel] += n_slots
@@ -545,9 +558,10 @@ class JaxVerifyEngine:
 
     Which kernel serves a chunk is decided by what the engine can observe,
     never by a failure: on a TPU the static-key comb kernel takes every
-    chunk whose signer keys are registrable and the generic Pallas kernel
-    (P-256) the rest; on other backends, and for schemes without a Pallas
-    kernel, the XLA kernel does.  A kernel that fails raises — wrapped in
+    chunk whose signer keys are registrable and the arbitrary-key Pallas
+    kernel (P-256's, Ed25519's) the rest; on other backends, and for
+    schemes without a Pallas kernel, the XLA kernel does.  A kernel that
+    fails raises — wrapped in
     :class:`KernelCompileError` when it was that shape's first launch —
     and ``stats.launches_by_kernel`` records which kernel served.
     """
@@ -600,6 +614,14 @@ class JaxVerifyEngine:
         self._comb, self._pallas_kernel = \
             self._pallas_kernels(scheme) if self.supports_pallas \
             else (None, None)
+        #: the arbitrary-key Pallas kernel's own marshalling, where it is
+        #: not the scheme's ``verify_inputs``: Ed25519's leaves R to the
+        #: kernel and reports the lanes it refused
+        self._pallas_prep = None
+        if scheme is ed25519 and self._pallas_kernel is not None:
+            from .pallas_ed25519 import prep_inputs
+
+            self._pallas_prep = prep_inputs
         #: (kernel, shape) pairs that have launched once, i.e. compiled
         self._launched: set = set()
         self._lock = threading.Lock()
@@ -621,11 +643,9 @@ class JaxVerifyEngine:
 
             return CombVerifier(), pallas_ecdsa.ecdsa_verify
         if scheme is ed25519:
-            # ed25519 has no generic pallas kernel — the comb path IS the
-            # fused kernel; unregistrable keys ride the XLA kernel
-            from .pallas_ed25519 import Ed25519CombVerifier
+            from .pallas_ed25519 import Ed25519CombVerifier, ed25519_verify
 
-            return Ed25519CombVerifier(), None
+            return Ed25519CombVerifier(), ed25519_verify
         return None, None
 
     def _use_pallas(self) -> bool:
@@ -774,7 +794,8 @@ class JaxVerifyEngine:
 
     def _launch(self, items, size: int, generic: bool = False):
         """One padded chunk through the kernel this engine's backend and
-        the chunk's keys select -> (kernel name, host mask).  ``generic``:
+        the chunk's keys select -> (kernel name, host mask, the lanes the
+        host refused by cause or None).  ``generic``:
         the chunk's keys are outside the pinned ring, so the comb kernel
         is not asked."""
         if self._pallas_on is None:
@@ -785,35 +806,42 @@ class JaxVerifyEngine:
             mask = self._guarded_launch(
                 "comb", size, lambda: self._comb.verify(items, size))
             if mask is not None:
-                return "comb", mask
+                return "comb", mask, None
         n = len(items)
+        name, kernel = ("pallas", self._pallas_kernel) \
+            if self._pallas_on and self._pallas_kernel is not None \
+            else ("xla", self._kernel)
+        refused = None
         with launch_span("verify.pack"):
+            if name == "pallas" and self._pallas_prep is not None:
+                with launch_span("verify.prep", lanes=n):
+                    arrays, ok, refused = self._pallas_prep(items)
+                arrays = (*arrays, ok)
+            else:
+                arrays = self.scheme.verify_inputs(items)
             padded = [
                 self._place(np.concatenate(
                     [a, np.zeros((size - n,) + a.shape[1:], a.dtype)]
                 ))
-                for a in self.scheme.verify_inputs(items)
+                for a in arrays
             ]
-        name, kernel = ("pallas", self._pallas_kernel) \
-            if self._pallas_on and self._pallas_kernel is not None \
-            else ("xla", self._kernel)
 
         def launch():
             with launch_span("verify.device"):
                 return np.asarray(kernel(*padded))
 
-        return name, self._guarded_launch(name, size, launch)
+        return name, self._guarded_launch(name, size, launch), refused
 
     def _verify_chunk(self, items, generic: bool = False) -> list[bool]:
         n = len(items)
         size = self._rung(self.request_pad_sizes, n) if generic \
             else self._pad_to(n)
         t0 = time.perf_counter()
-        kernel, mask = self._launch(items, size, generic)
+        kernel, mask, refused = self._launch(items, size, generic)
         dt = time.perf_counter() - t0
         with self._lock:
-            self.stats.record(n, size, dt, kernel)
-        note_lanes(kernel, size, n)
+            self.stats.record(n, size, dt, kernel, refused)
+        note_lanes(kernel, size, n, refused=refused)
         return [bool(v) for v in mask[:n]]
 
 

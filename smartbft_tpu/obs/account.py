@@ -71,7 +71,12 @@ cover the same span of time.
                        (forwards dropped where they came: that replica did
                        not lead, ``req.not_leader``)
 ``lanes``              kernel -> ``launches``, ``launched`` (lanes, padding
-                       included) and ``used`` (``verify.lanes`` marks)
+                       included) and ``used`` (``verify.lanes`` marks);
+                       ``host_refused``: cause -> lanes of them the host
+                       refused before the device, where a mark said
+``prep``               the host marshalling of Ed25519's arbitrary-key
+                       launches (``verify.prep`` spans that ended in the
+                       interval): ``calls``, ``lanes`` prepared, ``self_s``
 ``mesh``               the launches laid out over a device mesh (the
                        ``verify.lanes`` marks that carry ``per_device``):
                        ``launches``, ``spanning`` (of them, those that used
@@ -299,6 +304,7 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
                 "not_leader_forwards": 0}
     waits: dict = {k: [] for k in _WAIT_KINDS}
     lanes: dict = {}
+    prep = {"calls": 0, "lanes": 0, "self_s": 0.0}
     mesh = {"launches": 0, "spanning": 0, "used": 0, "launched": 0,
             "used_by_device": [], "launched_by_device": []}
     rejected: dict = {}
@@ -351,6 +357,10 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
             per["launches"] += 1
             per["launched"] += x["lanes"]
             per["used"] += x["used"]
+            if "refused" in x:
+                by = per.setdefault("host_refused", {})
+                for cause, n in x["refused"].items():
+                    by[cause] = by.get(cause, 0) + n
             if "per_device" in x:
                 _fold_mesh_launch(mesh, x)
             if "tags" in x:
@@ -359,6 +369,10 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
                     x["kernel"], {"launches": 0, "used": 0})
                 per["launches"] += 1
                 per["used"] += x["used"]
+        elif kind == "verify.prep" and e.self_s >= 0.0:
+            prep["calls"] += 1
+            prep["lanes"] += e.extra["lanes"]
+            prep["self_s"] += e.self_s
         elif kind == "req.rejected":
             cause = (e.extra or {}).get("cause", "?")
             rejected[cause] = rejected.get(cause, 0) + 1
@@ -435,6 +449,7 @@ def assemble_account(recorders: Sequence, busy: dict, *, t0: float,
                       "thresholds": list(thresholds)},
         "counters": counters,
         "lanes": lanes,
+        "prep": prep,
         "mesh": mesh,
         "rejected": rejected,
         "channels": _channels_block(per_channel, channel_waits,

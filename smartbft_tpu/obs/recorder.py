@@ -665,14 +665,15 @@ class busy_steps(collections.abc.Coroutine):
 
 
 class _LaunchSpan:
-    __slots__ = ("kind", "span")
+    __slots__ = ("kind", "extra", "span")
 
-    def __init__(self, kind: str):
+    def __init__(self, kind: str, extra: Optional[dict]):
         self.kind = kind
+        self.extra = extra
 
     def __enter__(self):
         self.span = PROCESS.begin(self.kind, launch=_state().launch,
-                                  cpu=True)
+                                  extra=self.extra, cpu=True)
 
     def __exit__(self, *exc):
         PROCESS.end(self.span)
@@ -681,29 +682,37 @@ class _LaunchSpan:
 _NO_SPAN = contextlib.nullcontext()
 
 
-def launch_span(kind: str):
+def launch_span(kind: str, **extra):
     """``with launch_span("verify.pack"):`` — a busy span of the verify
     engine on the thread that runs the launch, carrying that thread's
     launch id (:func:`set_thread_launch`) and its CPU time, so wall minus
-    CPU says how long the launch stood blocked.  Off: a shared no-op,
-    nothing allocated."""
-    return _LaunchSpan(kind) if PROCESS.enabled else _NO_SPAN
+    CPU says how long the launch stood blocked; ``extra``: the span's own
+    fields (``verify.prep``'s ``lanes``).  Off: a shared no-op, nothing
+    allocated."""
+    return _LaunchSpan(kind, extra or None) if PROCESS.enabled \
+        else _NO_SPAN
 
 
 def note_lanes(kernel: str, lanes: int, used: int,
-               per_device: Optional[Sequence[int]] = None) -> None:
+               per_device: Optional[Sequence[int]] = None,
+               refused: Optional[dict] = None) -> None:
     """A verify launch's lanes (padding included) and the lanes used, by
     the kernel that served it: a mark on the launch's thread, folded into
     the account's ``lanes`` block.  ``per_device``: a mesh launch's used
     lanes on each device (its lanes are split evenly), folded into the
-    account's ``mesh`` block too.  The mark carries the tags of the
-    launch's submitters (:func:`set_thread_launch`), folded into the
-    account's ``channels`` block.  Off: one attribute read."""
+    account's ``mesh`` block too.  ``refused``: of the used lanes, those
+    the host refused before the device, by cause (``VerifyStats.
+    host_refused``), folded into the kernel's ``lanes`` entry.  The mark
+    carries the tags of the launch's submitters (:func:`set_thread_launch`),
+    folded into the account's ``channels`` block.  Off: one attribute
+    read."""
     if PROCESS.enabled:
         st = _state()
         extra = {"kernel": kernel, "lanes": lanes, "used": used}
         if per_device is not None:
             extra["per_device"] = list(per_device)
+        if refused is not None:
+            extra["refused"] = dict(refused)
         if st.tags:
             extra["tags"] = list(st.tags)
         PROCESS.record("verify.lanes", launch=st.launch, extra=extra)

@@ -85,12 +85,10 @@ class EnvelopeChecks:
                              "with a request path (CryptoProvider)")
         from ..crypto.envelope import EnvelopeVerifier
 
-        if crypto.scheme is not EnvelopeVerifier.scheme:
-            raise ValueError("client envelopes are P-256 signed")
         self.envelopes = EnvelopeVerifier(
             identities, engine=crypto.engine,
             submit=crypto.verify_items_async, recorder=recorder,
-            channel=channel)
+            channel=channel, scheme=crypto.scheme)
         self.verify_request_async = self._verify_request_async
         self.verify_proposal_async = self._verify_proposal_async
 
@@ -258,8 +256,9 @@ class App(EnvelopeChecks, Application, Assembler, Comm, Signer, Verifier,
         recorder=None,
         enrolled=None,
     ):
-        """``enrolled``: the channel's enrolled client identities (P-256
-        public keys).  With them every request is a signed envelope
+        """``enrolled``: the channel's enrolled client identities (public
+        keys of ``crypto``'s scheme: P-256 points or Ed25519 keys).  With
+        them every request is a signed envelope
         (``crypto.envelope``) and is verified on ``crypto``'s engine;
         without, requests are unsigned and unchecked as before."""
         self.id = node_id
@@ -636,9 +635,12 @@ class App(EnvelopeChecks, Application, Assembler, Comm, Signer, Verifier,
         request then goes out as a signed envelope (what a channel with
         enrolled identities orders; its control plane signs too)."""
         if signer is not None:
+            from ..crypto import p256
             from ..crypto.envelope import sign_envelope
 
-            req = sign_envelope(*signer, client_id, request_id, payload)
+            scheme = p256 if self.envelopes is None else self.envelopes.scheme
+            req = sign_envelope(*signer, client_id, request_id, payload,
+                                scheme=scheme)
         else:
             req = encode(TestRequest(client_id=client_id, request_id=request_id, payload=payload))
         await self.consensus.submit_request(req, internal=internal)

@@ -368,9 +368,10 @@ class ShardedCluster:
         slo_spec=None,
         enrolled=None,
     ):
-        """``enrolled``: the enrolled client identities (P-256 public
-        keys); with them each replica verifies every client envelope
-        (``crypto="p256"`` only; see ``crypto.envelope``).  A sequence is
+        """``enrolled``: the enrolled client identities (public keys of
+        ``crypto``'s scheme: P-256 points for ``"p256"``, 32-byte keys for
+        ``"ed25519"``); with them each replica verifies every client
+        envelope (see ``crypto.envelope``).  A sequence is
         ONE set held by every shard, the shards hash-routed as ever.  A
         mapping ``channel name -> identities`` makes the shards NAMED
         CHANNELS, in the mapping's order, each with its own enrolled set

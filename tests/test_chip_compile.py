@@ -135,3 +135,16 @@ def test_comb_ed25519_compiles_for_v5e(one_chip):
 def test_generic_pallas_p256_compiles_for_v5e(one_chip):
     limbs = ((WAVE_LANES, p256.NLIMBS), jnp.uint32)
     _compiles(pallas_ecdsa.ecdsa_verify, one_chip, [limbs] * 5, tile=128)
+
+
+def test_arbitrary_key_ed25519_compiles_for_v5e(one_chip):
+    """The envelope rung (a block of 500 -> 512 lanes): five limb operands
+    and the host mask; the module keeps the name its device reader
+    matches (``ed25519_us_per_sig``: ``jit_ed25519_verify`` exactly)."""
+    lanes = 512
+    shapes = [((lanes, pallas_ed25519.NL), jnp.uint32)] * 5 \
+        + [((lanes,), jnp.uint32)]
+    text = _compiles(pallas_ed25519.ed25519_verify, one_chip, shapes,
+                     tile=pallas_ed25519.TILE)
+    module = text.split("\n", 1)[0]
+    assert module.split()[1].rstrip(",") == "jit_ed25519_verify", module
